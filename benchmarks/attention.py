@@ -8,7 +8,7 @@ L-independence of N_max (= q_block) vs the L-dependent idle prediction.
 """
 from __future__ import annotations
 
-from repro.core import (GranularitySpec, extract_nmax, get_hardware,
+from repro.core import (GranularitySpec, extract_nmax, PRESETS,
                         m_attn, n_idle_attn)
 from repro.core.arch import ArchConfig, AttentionSpec, FFNSpec
 from repro.core.simulate import attention_core_cost
@@ -28,7 +28,7 @@ L_SWEEP = (256, 512, 1024, 2048, 4096, 8192, 16384, 32768)
 def run(hw_names=("tpu_v5e", "h20")) -> None:
     gran = GranularitySpec.for_backend()
     for hw_name in hw_names:
-        hw = get_hardware(hw_name)
+        hw = PRESETS[hw_name]
         for ell in L_SWEEP:
             pairs = []
             for n in n_sweep(512):

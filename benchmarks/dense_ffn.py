@@ -11,7 +11,7 @@ Rows:
 from __future__ import annotations
 
 from repro.configs import get_config
-from repro.core import (GranularitySpec, TPU_V5E, extract_nmax, get_hardware,
+from repro.core import (GranularitySpec, TPU_V5E, extract_nmax, PRESETS,
                         n_idle_dense)
 from repro.core.arch import ArchConfig, AttentionSpec, FFNSpec
 from repro.core.simulate import dense_ffn_cost
@@ -29,7 +29,7 @@ BATCHES = (1, 2, 4, 8, 16, 32)
 
 def run(hw_names=("tpu_v5e", "h20")) -> None:
     for hw_name in hw_names:
-        hw = get_hardware(hw_name)
+        hw = PRESETS[hw_name]
         for b in BATCHES:
             pairs = []
             for n in n_sweep(2048):

@@ -27,6 +27,7 @@ import jax
 from repro.configs import get_config
 from repro.core import GranularitySpec, TPU_V5E
 from repro.core.simulate import decode_forward_cost
+from repro.launch.compile_cache import enable_compile_cache
 from repro.loadgen import generate_trace, pinned_spec, replay_trace
 from repro.loadgen.stats import itls, percentile, ttft
 from repro.models import init_model
@@ -127,6 +128,7 @@ def csv_rows(payload: dict) -> list:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_serving.json",
                     help="scorecard path (repo root by convention)")

@@ -6,7 +6,7 @@ Sec. 6 proposes as 'a deployment lookup').
 from __future__ import annotations
 
 from repro.configs import ARCH_IDS, get_config
-from repro.core import (GranularitySpec, get_hardware, predict_dense,
+from repro.core import (GranularitySpec, PRESETS, predict_dense,
                         predict_model, predict_moe_balanced,
                         predict_moe_skewed)
 
@@ -23,7 +23,7 @@ def run(hw_names=("h20", "a800", "h800", "tpu_v5e")) -> None:
     g256 = GranularitySpec.for_backend(n_experts=256)
     # --- the paper's own Table 24 rows ------------------------------------
     for hw_name in ("h20", "a800", "h800"):
-        hw = get_hardware(hw_name)
+        hw = PRESETS[hw_name]
         for b in (1, 4, 8):
             _emit_row(f"lookup/paper/dense@{hw_name}/b{b}",
                       predict_dense(hw, g256, b))
@@ -33,7 +33,7 @@ def run(hw_names=("h20", "a800", "h800", "tpu_v5e")) -> None:
         _emit_row(f"lookup/paper/moe_skew@{hw_name}/k8",
                   predict_moe_skewed(hw, g256, 8, 512))
     # --- beyond paper: the 10 assigned archs on TPU v5e -------------------
-    hw = get_hardware("tpu_v5e")
+    hw = PRESETS["tpu_v5e"]
     for arch in ARCH_IDS:
         cfg = get_config(arch)
         g = GranularitySpec.for_backend(cfg.ffn.n_experts)

@@ -10,7 +10,7 @@ rules).  Also reports the limiting module — the paper's Sec. 5.2
 from __future__ import annotations
 
 from repro.configs import get_config
-from repro.core import (GranularitySpec, extract_nmax, get_hardware,
+from repro.core import (GranularitySpec, extract_nmax, PRESETS,
                         latency_curve, predict_model)
 
 from benchmarks.common import curve_from_pairs, emit, n_sweep
@@ -23,7 +23,7 @@ def run(hw_names=("tpu_v5e", "h20")) -> None:
     g_moe = GranularitySpec.for_backend(n_experts=moe_cfg.ffn.n_experts)
 
     for hw_name in hw_names:
-        hw = get_hardware(hw_name)
+        hw = PRESETS[hw_name]
         # --- dense: batch sweep at L in {128..512} (paper G.2) -----------
         for ell in (128, 256, 512):
             for b in (1, 2, 4, 8):

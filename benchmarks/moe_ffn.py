@@ -12,7 +12,7 @@ term), skewed M_moe.
 from __future__ import annotations
 
 from repro.core import (GranularitySpec, balanced_moe_baseline_n,
-                        extract_nmax, get_hardware, m_moe, moe_tau,
+                        extract_nmax, PRESETS, m_moe, moe_tau,
                         n_idle_moe)
 from repro.core.arch import ArchConfig, AttentionSpec, FFNSpec
 from repro.core.simulate import moe_ffn_cost
@@ -34,7 +34,7 @@ def module_cfg(k: int) -> ArchConfig:
 def run(hw_names=("tpu_v5e", "h20")) -> None:
     gran = GranularitySpec.for_backend(n_experts=E)
     for hw_name in hw_names:
-        hw = get_hardware(hw_name)
+        hw = PRESETS[hw_name]
         for routing in ("balanced", "skewed"):
             for k in K_SWEEP:
                 cfg = module_cfg(k)

@@ -12,10 +12,11 @@ the logical work:
   - one_long:  one long slot, the rest short (the straggler pattern that
                scalar-length kernels pay worst-case kv work for).
 
-For each point: wall time of one kernel call (interpret mode on CPU —
-relative, not absolute), executed vs grid kv tiles (the per-row skip
-win), and query-row utilization inside the q_block tile (the M_attn
-slack the NFP principle prices; rows = slots * q_block physically).
+For each point: wall time of one kernel call (compiled on a TPU; in the
+Pallas interpreter on CPU, where it is relative, not absolute), executed
+vs grid kv tiles (the per-row skip win), and query-row utilization inside
+the q_block tile (the M_attn slack the NFP principle prices; rows =
+slots * q_block physically).
 
 Run:  PYTHONPATH=src python -m benchmarks.ragged_decode [--widths 1,2,4,8,16]
 """
@@ -49,13 +50,12 @@ def slot_mixes(s_max: int, b: int):
 
 
 def _time_call(q, kc, vc, lens, iters: int = 3) -> float:
-    out = decode_attention_ragged(q, kc, vc, lens, interpret=True)
+    out = decode_attention_ragged(q, kc, vc, lens)
     out.block_until_ready()                       # compile + warm
     ts = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        decode_attention_ragged(q, kc, vc, lens,
-                                interpret=True).block_until_ready()
+        decode_attention_ragged(q, kc, vc, lens).block_until_ready()
         ts.append(time.perf_counter() - t0)
     return float(np.median(ts)) * 1e6
 

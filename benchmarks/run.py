@@ -20,6 +20,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (attention, calibration, cpu_wallclock,
                             dense_ffn, lookup, model_nfp, moe_ffn,
                             roofline, sensitivity, serving_throughput)

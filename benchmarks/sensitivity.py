@@ -8,7 +8,7 @@ may shift by one sampled step.
 from __future__ import annotations
 
 from repro.core import (GranularitySpec, balanced_moe_baseline_n,
-                        get_hardware, sensitivity_sweep)
+                        PRESETS, sensitivity_sweep)
 from repro.core.simulate import (attention_core_cost, dense_ffn_cost,
                                  moe_ffn_cost)
 
@@ -27,7 +27,7 @@ def _fmt(sweep):
 def run(hw_names=("tpu_v5e",)) -> None:
     gran = GranularitySpec.for_backend(n_experts=E)
     for hw_name in hw_names:
-        hw = get_hardware(hw_name)
+        hw = PRESETS[hw_name]
         for b in (1, 4, 16):
             pairs = [(n, dense_ffn_cost(DENSE_CFG, b, n).time(hw))
                      for n in n_sweep(1024)]

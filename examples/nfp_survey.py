@@ -4,14 +4,14 @@ all 10 assigned architectures x hardware targets x batch x context.
 Run: PYTHONPATH=src python examples/nfp_survey.py
 """
 from repro.configs import ARCH_IDS, get_config
-from repro.core import (GranularitySpec, get_hardware, predict_model)
+from repro.core import (GranularitySpec, PRESETS, predict_model)
 
 
 def main():
     print(f"{'arch':26s} {'hw':8s} {'b':>3s} {'L':>6s} "
           f"{'N_max':>6s} {'idle':>8s} {'over':>6s}  limiting")
     for hw_name in ("tpu_v5e", "h20", "h800"):
-        hw = get_hardware(hw_name)
+        hw = PRESETS[hw_name]
         for arch in ARCH_IDS:
             cfg = get_config(arch)
             g = GranularitySpec.for_backend(cfg.ffn.n_experts)

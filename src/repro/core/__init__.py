@@ -1,7 +1,7 @@
 """repro.core — the paper's contribution: Near-Free Parallelism (NFP).
 
 Public API:
-  hardware:    HardwareSpec, TPU_V5E, H20/A800/H800, get_hardware
+  hardware:    HardwareSpec, TPU_V5E, H20/A800/H800, PRESETS, get_hardware
   arch:        ArchConfig, AttentionSpec, FFNSpec, SSMSpec, ShapeSpec
   granularity: GranularitySpec, select_q_block, select_token_block, ...
   nfp:         idle-compute baselines + NFP principle predictors
@@ -15,8 +15,8 @@ from repro.core.granularity import (GranularitySpec, attn_padded_q, cdiv,
                                     m_attn, m_moe, moe_padded_tokens,
                                     moe_tau, round_up, select_q_block,
                                     select_scan_chunk, select_token_block)
-from repro.core.hardware import (BYTES_BF16, H20, H800, A800, TPU_V5E,
-                                 HardwareSpec, get_hardware)
+from repro.core.hardware import (BYTES_BF16, H20, H800, A800, PRESETS,
+                                 TPU_V5E, HardwareSpec, get_hardware)
 from repro.core.measure import (LatencyCurve, balanced_moe_baseline_n,
                                 extract_nmax, sensitivity_sweep,
                                 staircase_boundaries, sweep_callable,

@@ -11,10 +11,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Sequence, Tuple
 
-try:                                    # hoisted: _block runs inside the
-    import jax as _jax                  # timed loop, a per-call import
-except Exception:                       # there is measurable overhead
-    _jax = None
+import jax
 
 DEFAULT_EPS = 0.2
 
@@ -117,13 +114,9 @@ def time_callable(fn: Callable[[], object], warmup: int = 3, rounds: int = 5,
 
 
 def _block(result) -> None:
-    """block_until_ready for jax outputs; no-op otherwise."""
-    if _jax is None:
-        return
-    try:
-        _jax.block_until_ready(result)
-    except Exception:
-        pass
+    """block_until_ready for jax outputs (other leaves pass through).  A
+    failed sync raises: swallowing it would time the enqueue."""
+    jax.block_until_ready(result)
 
 
 def sweep_callable(make_fn: Callable[[int], Callable[[], object]],

@@ -28,7 +28,6 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.arch import FFNSpec
@@ -117,13 +116,13 @@ def ep_moe_ffn(params: Dict, f: FFNSpec, x: Array, mesh: Mesh, *,
         out = jnp.zeros((t_loc, d), jnp.float32).at[tok_of_pair].add(contrib)
         return out.astype(xs.dtype)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(axis, None, None),
                   (P(axis, None, None) if swiglu else P()),
                   P(axis, None, None)),
         out_specs=P(axis, None),
-        check_rep=False)
+        check_vma=False)
     out = mapped(x, router,
                  w_up, w_gate if swiglu else jnp.zeros(()), w_down)
 

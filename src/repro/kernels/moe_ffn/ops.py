@@ -10,7 +10,7 @@ vLLM's own ``numel + E*(block-1)`` (Table 5), rounded to a block multiple.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +55,7 @@ def align_block_size(expert_of_sorted: jnp.ndarray, group_sizes: jnp.ndarray,
                                              "token_block_override",
                                              "n_tokens"))
 def grouped_ffn(x_sorted, params: Dict, group_sizes, activation: str = "swiglu",
-                interpret: bool = True, token_block_override=None,
+                interpret: Optional[bool] = None, token_block_override=None,
                 n_tokens: int = 0):
     """x_sorted: (M = T*k, d) token rows grouped by expert; group_sizes: (E,).
 
@@ -63,7 +63,12 @@ def grouped_ffn(x_sorted, params: Dict, group_sizes, activation: str = "swiglu",
     quantized to token_block rows per expert (the M_moe staircase); the
     block-size branch keys on the TOKEN count T (vLLM Table 8), passed as
     n_tokens (defaults to M when unknown).
+
+    interpret=None compiles the kernel on TPU and runs the Pallas
+    interpreter elsewhere; pass True/False to force either.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     m, d = x_sorted.shape
     e = group_sizes.shape[0]
     f = params["w_up"].shape[-1]

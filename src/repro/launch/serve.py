@@ -31,6 +31,7 @@ from repro.autotune import (BudgetController, calibrate_engine, load_table,
 from repro.checkpoint import latest_step, restore
 from repro.configs import get_config
 from repro.core import get_hardware
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_model
 from repro.serving import (DecodeEngine, DiffusionBlockDecoder,
                            MTPDecoder, PagedKVConfig, ServingLoop,
@@ -215,6 +216,7 @@ def _trace_replay(args, cfg, params) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm_3b")
     ap.add_argument("--tiny", action="store_true")
@@ -224,10 +226,14 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--max-len", type=int, default=512)
     ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--hardware", default="tpu_v5e")
+    ap.add_argument("--hardware", default=None,
+                    help="hardware spec preset for the NFP budget; on a TPU "
+                         "the attached chip's spec is used and a different "
+                         "name is an error (default: tpu_v5e off-TPU)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--use-kernel", action="store_true",
-                    help="Pallas decode kernel (interpret on CPU)")
+                    help="Pallas kernels: compiled on a TPU, run in the "
+                         "Pallas interpreter elsewhere (CPU tests)")
     ap.add_argument("--requests", type=int, default=0,
                     help="multi-request mode: serve N concurrent requests "
                          "through the budget-aware scheduler")

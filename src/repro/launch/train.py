@@ -13,10 +13,13 @@ is handled by the launcher's restore-and-resume path (dist.elastic).
 from __future__ import annotations
 
 import argparse
+import functools
 import time
+from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.configs import get_config
@@ -24,12 +27,48 @@ from repro.data import DataConfig, make_pipeline
 from repro.dist.elastic import StepWatchdog, elastic_mesh, run_with_restarts
 from repro.dist.sharding import (batch_pspec, opt_pspecs, param_pspecs,
                                  shardings_from_pspecs)
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.training import AdamWConfig, init_opt_state, make_train_step
 
 
+def init_train_state(key, cfg) -> Dict:
+    params = init_model(key, cfg)
+    return {"params": params, "opt": init_opt_state(params)}
+
+
+def sharded_train(cfg, mesh: Mesh, opt_cfg: AdamWConfig, *,
+                  global_batch: int, n_micro: int, policy: str = "auto",
+                  ) -> Tuple[Callable, Callable]:
+    """Jitted ``(init, step)`` over ``mesh``.
+
+    ``init(key)`` creates params and optimizer state under their
+    shardings, so no chip ever holds the whole state (at stablelm_3b
+    width the f32 Adam state alone is ~22 GB).  ``step(state, batch)``
+    returns ``(state, metrics)`` and donates the state it replaces."""
+    init = functools.partial(init_train_state, cfg=cfg)
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
+    p_ps = param_pspecs(shapes["params"], mesh, policy=policy)
+    state_sh = {"params": shardings_from_pspecs(p_ps, mesh),
+                "opt": shardings_from_pspecs(
+                    opt_pspecs(shapes["opt"], p_ps, mesh), mesh)}
+    batch_sh = shardings_from_pspecs(
+        {"tokens": batch_pspec(mesh, global_batch)}, mesh)
+    train_step = make_train_step(cfg, opt_cfg, n_micro=n_micro)
+
+    def step(state, batch):
+        params, opt, metrics = train_step(state["params"], state["opt"],
+                                          batch)
+        return {"params": params, "opt": opt}, metrics
+
+    return (jax.jit(init, out_shardings=state_sh),
+            jax.jit(step, in_shardings=(state_sh, batch_sh),
+                    out_shardings=(state_sh, None), donate_argnums=0))
+
+
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm_3b")
     ap.add_argument("--tiny", action="store_true",
@@ -55,25 +94,15 @@ def main() -> None:
     cfg = get_config(args.arch, reduced=args.tiny)
     n_dev = jax.device_count()
     shape, axes = elastic_mesh(n_dev)
-    mesh = (jax.make_mesh(shape, axes) if n_dev > 1
-            else make_debug_mesh(1, 1))
+    mesh = make_mesh(shape, axes)
     print(f"mesh {dict(zip(axes, shape)) if n_dev > 1 else '1-device'}  "
           f"arch {cfg.name}")
 
-    params = init_model(jax.random.PRNGKey(0), cfg)
-    opt = init_opt_state(params)
     opt_cfg = AdamWConfig(lr=args.lr, warmup_steps=min(20, args.steps // 5),
                           total_steps=args.steps)
-    p_ps = param_pspecs(params, mesh, policy=args.sharding_policy)
-    o_ps = opt_pspecs(opt, p_ps, mesh)
-    b_ps = {"tokens": batch_pspec(mesh, args.global_batch)}
-    step_fn = jax.jit(
-        make_train_step(cfg, opt_cfg, n_micro=args.n_micro),
-        in_shardings=(shardings_from_pspecs(p_ps, mesh),
-                      shardings_from_pspecs(o_ps, mesh),
-                      shardings_from_pspecs(b_ps, mesh)),
-        out_shardings=(shardings_from_pspecs(p_ps, mesh),
-                       shardings_from_pspecs(o_ps, mesh), None))
+    init_fn, step_fn = sharded_train(
+        cfg, mesh, opt_cfg, global_batch=args.global_batch,
+        n_micro=args.n_micro, policy=args.sharding_policy)
 
     data = make_pipeline(
         DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
@@ -83,7 +112,7 @@ def main() -> None:
     ckpt = AsyncCheckpointer(args.ckpt_dir, keep=3)
     watchdog = StepWatchdog(deadline_s=600.0)
 
-    state = {"params": params, "opt": opt}
+    state = init_fn(jax.random.PRNGKey(0))
     start = 0
     if latest_step(args.ckpt_dir) is not None:
         restored, meta = restore(args.ckpt_dir, state)
@@ -94,8 +123,8 @@ def main() -> None:
     def one_step(step: int) -> None:
         t0 = time.time()
         batch = {k: jnp.asarray(v) for k, v in next(data).items()}
-        state["params"], state["opt"], metrics = step_fn(
-            state["params"], state["opt"], batch)
+        new_state, metrics = step_fn(state, batch)
+        state.update(new_state)
         dt = time.time() - t0
         watchdog.observe(dt)
         if step % 10 == 0:

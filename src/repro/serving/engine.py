@@ -21,7 +21,7 @@ import numpy as np
 
 from repro.core.arch import LAYER_ATTN, ArchConfig
 from repro.core.granularity import GranularitySpec
-from repro.core.hardware import TPU_V5E, HardwareSpec
+from repro.core.hardware import HardwareSpec, get_hardware
 from repro.core.nfp import parallelism_budget
 from repro.models.transformer import (forward, init_cache, init_paged_cache,
                                       make_segments)
@@ -104,7 +104,8 @@ class DecodeEngine:
     params: Dict
     batch: int
     max_len: int
-    hardware: HardwareSpec = TPU_V5E
+    # None: the attached chip's spec on a TPU, the v5e target elsewhere
+    hardware: Optional[HardwareSpec] = None
     use_kernel: bool = False
     cache: Optional[Dict] = None
     # committed positions of the single-request drivers.  A HOST int on
@@ -116,6 +117,8 @@ class DecodeEngine:
     paged: Optional[PagedKVConfig] = None
 
     def __post_init__(self):
+        if self.hardware is None:
+            self.hardware = get_hardware()
         self.manager: Optional[BlockManager] = None
         if self.paged is not None:
             if self.cfg.encoder is not None or any(

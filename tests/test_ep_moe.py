@@ -49,7 +49,8 @@ print('RESULT::' + json.dumps(res))
 def ep_results():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, timeout=480,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-2000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT::")][0]
     return json.loads(line[len("RESULT::"):])

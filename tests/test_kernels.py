@@ -130,7 +130,9 @@ def test_grouped_ffn_skewed_routing():
 # mamba selective scan
 # ===========================================================================
 
-SCAN_CASES = [(2, 16, 64, 16), (1, 7, 32, 8), (2, 33, 128, 16), (1, 1, 64, 16)]
+# the last two span several d_inner tiles (512 and 128 channels)
+SCAN_CASES = [(2, 16, 64, 16), (1, 7, 32, 8), (2, 33, 128, 16), (1, 1, 64, 16),
+              (2, 20, 1536, 16), (1, 5, 640, 8)]
 
 
 @pytest.mark.parametrize("case", SCAN_CASES)

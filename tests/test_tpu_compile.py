@@ -1,0 +1,158 @@
+"""The main-path kernels and the serving step compile for a TPU v5e.
+
+Nothing runs: each case lowers and compiles for a described ``v5e:2x2``
+chip, which refuses what interpret mode cannot show (scoped-VMEM
+overflow, unaligned blocks, a program larger than the chip's memory).
+The topology is described inside a fixture, so importing this file loads
+no TPU library, and every case skips together where none can be
+described.  The persistent compile cache is off around the compiles: an
+entry compiled for a described chip cannot be read back without one.
+"""
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.decode_attention.ops import (decode_attention_paged,
+                                                decode_attention_ragged)
+from repro.kernels.mamba_scan.ops import selective_scan
+from repro.kernels.moe_ffn.ops import grouped_ffn
+from repro.models import init_model
+from repro.models.transformer import init_paged_cache
+from repro.serving.engine import _decode_paged_fn
+
+HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it cannot describe a v5e here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+ATTN = {
+    "stablelm_3b": get_config("stablelm_3b").attention,
+    "granite_moe_3b_a800m": get_config("granite_moe_3b_a800m").attention,
+}
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN))
+@pytest.mark.parametrize("n", [1, 8])
+def test_ragged_attention_compiles(one_chip, arch, n):
+    a = ATTN[arch]
+    b, s = 4, 4096
+    args = (_spec(one_chip, (b, n, a.n_heads, a.head_dim), jnp.bfloat16),
+            _spec(one_chip, (b, s, a.n_kv_heads, a.head_dim), jnp.bfloat16),
+            _spec(one_chip, (b, s, a.n_kv_heads, a.head_dim), jnp.bfloat16),
+            _spec(one_chip, (b,), jnp.int32))
+    jax.jit(lambda *x: decode_attention_ragged(*x, interpret=False)
+            ).lower(*args).compile()
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN))
+@pytest.mark.parametrize("block", [16, 128])
+def test_paged_attention_compiles(one_chip, arch, block):
+    a = ATTN[arch]
+    b, s, n = 4, 4096, 8
+    n_phys = b * s // block + 1
+    args = (_spec(one_chip, (b, n, a.n_heads, a.head_dim), jnp.bfloat16),
+            _spec(one_chip, (n_phys, block, a.n_kv_heads, a.head_dim),
+                  jnp.bfloat16),
+            _spec(one_chip, (n_phys, block, a.n_kv_heads, a.head_dim),
+                  jnp.bfloat16),
+            _spec(one_chip, (b,), jnp.int32),
+            _spec(one_chip, (b, s // block), jnp.int32))
+    jax.jit(lambda *x: decode_attention_paged(*x, interpret=False)
+            ).lower(*args).compile()
+
+
+@pytest.mark.parametrize("tokens", [4, 32])
+def test_grouped_ffn_compiles(one_chip, tokens):
+    f = get_config("granite_moe_3b_a800m").ffn
+    d = get_config("granite_moe_3b_a800m").d_model
+    e, m = f.n_experts, tokens * f.top_k
+    params = {"w_gate": _spec(one_chip, (e, d, f.d_ff), jnp.bfloat16),
+              "w_up": _spec(one_chip, (e, d, f.d_ff), jnp.bfloat16),
+              "w_down": _spec(one_chip, (e, f.d_ff, d), jnp.bfloat16)}
+    jax.jit(lambda x, p, g: grouped_ffn(x, p, g, f.activation,
+                                        interpret=False, n_tokens=tokens)
+            ).lower(_spec(one_chip, (m, d), jnp.bfloat16), params,
+                    _spec(one_chip, (e,), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("seq", [1, 16, 128])
+def test_selective_scan_compiles(one_chip, seq):
+    """falcon_mamba_7b: a whole-d_inner block (8192 channels) overflows
+    the 16 MiB scoped-VMEM limit; the kernel tiles channels."""
+    cfg = get_config("falcon_mamba_7b")
+    b, di, ds = 4, cfg.ssm.d_inner(cfg.d_model), cfg.ssm.d_state
+    f32 = jnp.float32
+    args = (_spec(one_chip, (b, seq, di), f32),
+            _spec(one_chip, (b, seq, di), f32),
+            _spec(one_chip, (b, seq, ds), f32),
+            _spec(one_chip, (b, seq, ds), f32),
+            _spec(one_chip, (di, ds), f32),
+            _spec(one_chip, (b, di, ds), f32))
+    jax.jit(lambda *x: selective_scan(*x, interpret=False)
+            ).lower(*args).compile()
+
+
+def test_decode_step_fits_one_chip(one_chip, monkeypatch):
+    """stablelm_3b at published widths, the paged engine chip_smoke.py
+    serves (4 slots x 2048 positions, block 128), widest decode forward:
+    arguments + output + temporaries fit one chip's 16 GiB."""
+    # the kernel ops pick the compiled kernel from the backend; this
+    # process's backend is the CPU, the program is for the TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("stablelm_3b")
+    slots, max_len, block, width = 4, 2048, 128, 16
+    n_phys = slots * max_len // block + 1
+
+    def place(tree):
+        return jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+
+    params = place(jax.eval_shape(functools.partial(init_model, cfg=cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: init_paged_cache(cfg, n_phys, block)))
+    compiled = _decode_paged_fn.lower(
+        params, cfg, _spec(one_chip, (slots, width), jnp.int32), cache,
+        _spec(one_chip, (slots,), jnp.int32),
+        _spec(one_chip, (slots, max_len // block), jnp.int32),
+        use_kernel=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, (mem.argument_size_in_bytes,
+                               mem.output_size_in_bytes,
+                               mem.temp_size_in_bytes)
