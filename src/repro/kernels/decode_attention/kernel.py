@@ -180,6 +180,7 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, n_pad, dh), q.dtype),
         interpret=interpret,
+        name="decode_attention_ragged",
     )(cache_lens, q, k, v)
 
 
@@ -262,4 +263,5 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, n_pad, dh), q.dtype),
         interpret=interpret,
+        name="decode_attention_paged",
     )(cache_lens, block_tables, q, k_pool, v_pool)

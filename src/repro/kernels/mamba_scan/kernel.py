@@ -99,4 +99,5 @@ def mamba_scan_pallas(x, dt, b_in, c_in, a, h0, *, chunk: int,
         ],
         scratch_shapes=[pltpu.VMEM((dtile, ds), jnp.float32)],
         interpret=interpret,
+        name="mamba_scan",
     )(x, dt, b_in, c_in, a, h0)

@@ -105,4 +105,5 @@ def moe_ffn_pallas(x_padded, w_gate, w_up, w_down, block_expert, block_valid,
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, d), x_padded.dtype),
         interpret=interpret,
+        name="moe_ffn",
     )(block_expert, block_valid, *operands)

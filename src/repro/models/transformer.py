@@ -198,24 +198,29 @@ def _attn_layer(lp, cfg: ArchConfig, x: Array, positions, cache, cache_len,
                 mode: str, use_kernel: bool, routing_override,
                 memory: Optional[Array], swa_ring: bool = False,
                 block_tables=None):
-    h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
-    if mode == "decode":
-        att, new_cache = attention_decode(lp["attn"], cfg.attention, h, cache,
-                                          cache_len, cfg.rope_theta,
-                                          use_kernel, swa_ring,
-                                          block_tables=block_tables)
-    else:
-        att, new_cache = attention_full(lp["attn"], cfg.attention, h,
-                                        positions, cfg.rope_theta,
-                                        build_cache=cache, cache_len=0)
-    x = x + att
-    if memory is not None and "cross" in lp:
-        hc = rmsnorm(lp["ln_cross"], x, cfg.norm_eps)
-        ck, cv = encode_cross_kv(lp["cross"], cfg.attention, memory)
-        x = x + cross_attention(lp["cross"], cfg.attention, hc, ck, cv)
-    h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
-    ff, aux = _ffn_apply(lp, cfg, h2, routing_override)
-    return x + ff, new_cache, aux
+    # named scopes are path components of every op's ``tf_op`` in a
+    # profiler trace: device time splits by layer part with no flag
+    with jax.named_scope("attention"):
+        h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
+        if mode == "decode":
+            att, new_cache = attention_decode(lp["attn"], cfg.attention, h,
+                                              cache, cache_len, cfg.rope_theta,
+                                              use_kernel, swa_ring,
+                                              block_tables=block_tables)
+        else:
+            att, new_cache = attention_full(lp["attn"], cfg.attention, h,
+                                            positions, cfg.rope_theta,
+                                            build_cache=cache, cache_len=0)
+        x = x + att
+        if memory is not None and "cross" in lp:
+            hc = rmsnorm(lp["ln_cross"], x, cfg.norm_eps)
+            ck, cv = encode_cross_kv(lp["cross"], cfg.attention, memory)
+            x = x + cross_attention(lp["cross"], cfg.attention, hc, ck, cv)
+    with jax.named_scope("ffn"):
+        h2 = rmsnorm(lp["ln2"], x, cfg.norm_eps)
+        ff, aux = _ffn_apply(lp, cfg, h2, routing_override)
+        x = x + ff
+    return x, new_cache, aux
 
 
 def _ssm_layer(lp, cfg: ArchConfig, x: Array, state, use_kernel: bool):
@@ -361,6 +366,9 @@ def forward(params, cfg: ArchConfig, inputs: Dict, *, mode: str = "train",
         frac = (1.0 if remat is True else
                 0.0 if remat is False else float(remat))
 
+        # each scan over the stacked layers runs under "layers": its own
+        # slicing and updating of stacked weights and cache is the part of
+        # that scope outside "attention" and "ffn"
         if cache is None:
             # scan without cache: feed layer params only
             def body_nc(x, lp, _body=body):
@@ -371,19 +379,22 @@ def forward(params, cfg: ArchConfig, inputs: Dict, *, mode: str = "train",
             if n_re > 0:
                 sp_re = (jax.tree.map(lambda a: a[:n_re], sp)
                          if n_re < count else sp)
-                x, a1 = jax.lax.scan(jax.checkpoint(body_nc), x, sp_re)
+                with jax.named_scope("layers"):
+                    x, a1 = jax.lax.scan(jax.checkpoint(body_nc), x, sp_re)
                 aux_parts.append(a1)
             if n_re < count:
                 sp_pl = (jax.tree.map(lambda a: a[n_re:], sp)
                          if n_re > 0 else sp)
-                x, a2 = jax.lax.scan(body_nc, x, sp_pl)
+                with jax.named_scope("layers"):
+                    x, a2 = jax.lax.scan(body_nc, x, sp_pl)
                 aux_parts.append(a2)
             auxs = jnp.concatenate([jnp.atleast_1d(a) for a in aux_parts])
             new_segments.append(None)
         else:
             if frac > 0:
                 body = jax.checkpoint(body)
-            x, (ncs, auxs) = jax.lax.scan(body, x, (sp, seg_cache))
+            with jax.named_scope("layers"):
+                x, (ncs, auxs) = jax.lax.scan(body, x, (sp, seg_cache))
             new_segments.append(ncs)
         aux_total = aux_total + jnp.sum(auxs)
 

@@ -30,6 +30,7 @@ from typing import Dict, List, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.engine import DecodeEngine, greedy_tokens
 
@@ -237,28 +238,31 @@ class SlotAdapter:
             if n_draft > 0:
                 want[s] = n_draft
         drafts: Dict[int, np.ndarray] = {}
-        for s, d in (self.propose_rows(want) if want else {}).items():
-            d = np.asarray(d, np.int64)[:want[s]]
-            if len(d):
-                drafts[s] = d
-                tokens[s, 1:1 + len(d)] = d
+        with TraceAnnotation("repro.adapter.draft"):
+            for s, d in (self.propose_rows(want) if want else {}).items():
+                d = np.asarray(d, np.int64)[:want[s]]
+                if len(d):
+                    drafts[s] = d
+                    tokens[s, 1:1 + len(d)] = d
         logits, new_cache, hidden = loop.shared_forward(tokens, budget)
         # greedy winners computed ON DEVICE (jitted); the only per-step
         # device->host transfer is this (batch, width) i32 block — the
         # token stream emission every serving loop fundamentally needs
-        preds = np.asarray(greedy_tokens(logits))  # analysis: allow-host-sync
+        with TraceAnnotation("repro.adapter.readback"):
+            preds = np.asarray(greedy_tokens(logits))  # analysis: allow-host-sync
         advances = np.zeros((eng.batch,), np.int32)
-        for s in slots:
-            req = loop.active[s]
-            k = 0
-            d = drafts.get(s)
-            if d is not None:
-                while k < len(d) and preds[s, k] == d[k]:
-                    k += 1
-                req.generated.extend(int(t) for t in d[:k])
-            bonus = int(preds[s, k])
-            req.generated.append(bonus)
-            advances[s] = 1 + k                  # pending + accepted drafts
-            req.pending = bonus
-            self.observe(req, k, hidden[s])
+        with TraceAnnotation("repro.adapter.accept"):
+            for s in slots:
+                req = loop.active[s]
+                k = 0
+                d = drafts.get(s)
+                if d is not None:
+                    while k < len(d) and preds[s, k] == d[k]:
+                        k += 1
+                    req.generated.extend(int(t) for t in d[:k])
+                bonus = int(preds[s, k])
+                req.generated.append(bonus)
+                advances[s] = 1 + k              # pending + accepted drafts
+                req.pending = bonus
+                self.observe(req, k, hidden[s])
         eng.commit_slots(new_cache, advances)

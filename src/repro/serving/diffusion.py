@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.algorithm import ParallelDecodeAlgorithm, SlotAdapter
 from repro.serving.engine import DecodeEngine
@@ -181,13 +182,14 @@ class DiffusionSlotAdapter(SlotAdapter):
             if all(resolved[s].all() for s in slots):
                 break
             logits, _, _ = loop.shared_forward(block_tokens(), budget)
-            for s in slots:
-                if resolved[s].all():
-                    continue
-                lg = np.asarray(logits[s].astype(jnp.float32))
-                last_lg[s] = lg
-                refine_block(blocks[s], resolved[s], lg,
-                             max(1, int(np.ceil(n[s] / self.refine_steps))))
+            todo = [s for s in slots if not resolved[s].all()]
+            with TraceAnnotation("repro.adapter.readback"):
+                for s in todo:
+                    last_lg[s] = np.asarray(logits[s].astype(jnp.float32))
+            with TraceAnnotation("repro.adapter.accept"):
+                for s in todo:
+                    refine_block(blocks[s], resolved[s], last_lg[s], max(
+                        1, int(np.ceil(n[s] / self.refine_steps))))
         for s in slots:
             if not resolved[s].all():
                 blocks[s][~resolved[s]] = (

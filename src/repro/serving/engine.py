@@ -18,6 +18,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.arch import LAYER_ATTN, ArchConfig
 from repro.core.granularity import GranularitySpec
@@ -87,6 +88,16 @@ def _scatter_prefill(cache, scratch, flat_idx, rows, cols):
         flat = flat.at[:, flat_idx].set(scr[:, rows, cols])
         return flat.reshape(pool.shape)
     return jax.tree.map(one, cache, scratch)
+
+
+def _prefill_span(entry: Dict) -> TraceAnnotation:
+    """Host span around one admission forward, carrying the counts its
+    ``prefill_log`` entry keeps: prompt tokens computed, and the tokens
+    the forward runs (every row at the bucket width)."""
+    return TraceAnnotation("repro.engine.prefill",
+                           computed_tokens=entry["computed_tokens"],
+                           padded_tokens=entry["padded_tokens"],
+                           bucket=entry["bucket"])
 
 
 @dataclass
@@ -305,23 +316,24 @@ class DecodeEngine:
                        list(prompts))]
         out: Dict[int, Tuple[Array, Array]] = {}
         for width, rows in groups:
-            toks = np.zeros((self.batch, width), np.int32)
-            for s in rows:
-                toks[s, :lens[s]] = np.asarray(prompts[s], np.int64)
-            logits, new_cache, hidden = _prefill_fn(
-                self.params, self.cfg, jnp.asarray(toks), self.cache,
-                self.use_kernel)
-            self.cache = jax.tree.map(
-                lambda old, new: jnp.where(self._row_mask(rows, old),
-                                           new, old),
-                self.cache, new_cache)
-            for s in rows:
-                self._set_slot_len(s, lens[s])
-                out[s] = (logits[s, lens[s] - 1], hidden[s, lens[s] - 1])
-            self.prefill_log.append({"slots": sorted(rows),
-                                     "bucket": width,
-                                     "computed_tokens": sum(
-                                         lens[s] for s in rows)})
+            entry = {"slots": sorted(rows), "bucket": width,
+                     "computed_tokens": sum(lens[s] for s in rows),
+                     "padded_tokens": self.batch * width}
+            with _prefill_span(entry):
+                toks = np.zeros((self.batch, width), np.int32)
+                for s in rows:
+                    toks[s, :lens[s]] = np.asarray(prompts[s], np.int64)
+                logits, new_cache, hidden = _prefill_fn(
+                    self.params, self.cfg, jnp.asarray(toks), self.cache,
+                    self.use_kernel)
+                self.cache = jax.tree.map(
+                    lambda old, new: jnp.where(self._row_mask(rows, old),
+                                               new, old),
+                    self.cache, new_cache)
+                for s in rows:
+                    self._set_slot_len(s, lens[s])
+                    out[s] = (logits[s, lens[s] - 1], hidden[s, lens[s] - 1])
+            self.prefill_log.append(entry)
         return out
 
     def _prefill_slots_paged(self, prompts: Dict[int, Array],
@@ -368,64 +380,69 @@ class DecodeEngine:
         bs = mgr.block_size
         if full:
             width = self.prefill_bucket(max(lens[s] for s in full))
-            toks = np.zeros((self.batch, width), np.int32)
-            for s in full:
-                toks[s, :lens[s]] = tok_host[s]
-            scratch = init_cache(self.cfg, self.batch, width)
-            logits, scratch, hidden = _prefill_fn(
-                self.params, self.cfg, jnp.asarray(toks), scratch,
-                self.use_kernel)
-            rows, cols, flats = [], [], []
-            for s in full:
-                pos = np.arange(lens[s])
-                page = mgr.tables[s, pos // bs].astype(np.int64)
-                rows.append(np.full(lens[s], s, np.int64))
-                cols.append(pos)
-                flats.append(page * bs + pos % bs)
-            rows = np.concatenate(rows)
-            cols = np.concatenate(cols)
-            flats = np.concatenate(flats)
-            # pad the scatter to a power-of-two bucket (compile reuse);
-            # pad entries dump into the trash page
-            m = 8
-            while m < len(rows):
-                m *= 2
-            pad = m - len(rows)
-            rows = np.pad(rows, (0, pad))
-            cols = np.pad(cols, (0, pad))
-            flats = np.pad(flats, (0, pad), constant_values=mgr.trash * bs)
-            self.cache = _scatter_prefill(
-                self.cache, scratch, jnp.asarray(flats, jnp.int32),
-                jnp.asarray(rows, jnp.int32), jnp.asarray(cols, jnp.int32))
-            for s in full:
-                self._set_slot_len(s, lens[s])
-                out[s] = (logits[s, lens[s] - 1], hidden[s, lens[s] - 1])
-            self.prefill_log.append({"slots": full, "bucket": width,
-                                     "cached_tokens": 0,
-                                     "computed_tokens": sum(
-                                         lens[s] for s in full)})
+            entry = {"slots": full, "bucket": width, "cached_tokens": 0,
+                     "computed_tokens": sum(lens[s] for s in full),
+                     "padded_tokens": self.batch * width}
+            with _prefill_span(entry):
+                toks = np.zeros((self.batch, width), np.int32)
+                for s in full:
+                    toks[s, :lens[s]] = tok_host[s]
+                scratch = init_cache(self.cfg, self.batch, width)
+                logits, scratch, hidden = _prefill_fn(
+                    self.params, self.cfg, jnp.asarray(toks), scratch,
+                    self.use_kernel)
+                rows, cols, flats = [], [], []
+                for s in full:
+                    pos = np.arange(lens[s])
+                    page = mgr.tables[s, pos // bs].astype(np.int64)
+                    rows.append(np.full(lens[s], s, np.int64))
+                    cols.append(pos)
+                    flats.append(page * bs + pos % bs)
+                rows = np.concatenate(rows)
+                cols = np.concatenate(cols)
+                flats = np.concatenate(flats)
+                # pad the scatter to a power-of-two bucket (compile reuse);
+                # pad entries dump into the trash page
+                m = 8
+                while m < len(rows):
+                    m *= 2
+                pad = m - len(rows)
+                rows = np.pad(rows, (0, pad))
+                cols = np.pad(cols, (0, pad))
+                flats = np.pad(flats, (0, pad),
+                               constant_values=mgr.trash * bs)
+                self.cache = _scatter_prefill(
+                    self.cache, scratch, jnp.asarray(flats, jnp.int32),
+                    jnp.asarray(rows, jnp.int32),
+                    jnp.asarray(cols, jnp.int32))
+                for s in full:
+                    self._set_slot_len(s, lens[s])
+                    out[s] = (logits[s, lens[s] - 1], hidden[s, lens[s] - 1])
+            self.prefill_log.append(entry)
         if hits:
             suf = {s: lens[s] - plans[s].cached_len for s in hits}
-            for s in hits:
-                self._set_slot_len(s, plans[s].cached_len)
             width = self.prefill_bucket(max(suf.values()))
-            toks = np.zeros((self.batch, width), np.int32)
-            for s in hits:
-                toks[s, :suf[s]] = tok_host[s][plans[s].cached_len:]
-            logits, new_cache, hidden = _decode_paged_fn(
-                self.params, self.cfg, jnp.asarray(toks), self.cache,
-                self.slot_lens, self._device_tables(), self.use_kernel)
-            # suffix KV is committed; rows outside the hit group wrote
-            # past their own committed length (or into the trash page),
-            # which no mask ever reads back
-            self.cache = new_cache
-            for s in hits:
-                self._set_slot_len(s, lens[s])
-                out[s] = (logits[s, suf[s] - 1], hidden[s, suf[s] - 1])
-            self.prefill_log.append({
-                "slots": hits, "bucket": width,
-                "cached_tokens": sum(plans[s].cached_len for s in hits),
-                "computed_tokens": sum(suf.values())})
+            entry = {"slots": hits, "bucket": width,
+                     "cached_tokens": sum(plans[s].cached_len for s in hits),
+                     "computed_tokens": sum(suf.values()),
+                     "padded_tokens": self.batch * width}
+            with _prefill_span(entry):
+                for s in hits:
+                    self._set_slot_len(s, plans[s].cached_len)
+                toks = np.zeros((self.batch, width), np.int32)
+                for s in hits:
+                    toks[s, :suf[s]] = tok_host[s][plans[s].cached_len:]
+                logits, new_cache, hidden = _decode_paged_fn(
+                    self.params, self.cfg, jnp.asarray(toks), self.cache,
+                    self.slot_lens, self._device_tables(), self.use_kernel)
+                # suffix KV is committed; rows outside the hit group wrote
+                # past their own committed length (or into the trash
+                # page), which no mask ever reads back
+                self.cache = new_cache
+                for s in hits:
+                    self._set_slot_len(s, lens[s])
+                    out[s] = (logits[s, suf[s] - 1], hidden[s, suf[s] - 1])
+            self.prefill_log.append(entry)
         for s in sorted(prompts):
             mgr.register_prompt(s, tok_host[s].tolist())
         return out
@@ -444,12 +461,14 @@ class DecodeEngine:
         Pallas decode-attention kernel's scalar-prefetch lane — one
         quantized launch for the whole mixed-length batch (on a paged
         engine, with the block tables as a second prefetch operand)."""
-        if self.manager is not None:
-            return _decode_paged_fn(self.params, self.cfg, tokens,
-                                    self.cache, self.slot_lens,
-                                    self._device_tables(), self.use_kernel)
-        return _decode_fn(self.params, self.cfg, tokens, self.cache,
-                          self.slot_lens, self.use_kernel)
+        with TraceAnnotation("repro.engine.decode"):
+            if self.manager is not None:
+                return _decode_paged_fn(self.params, self.cfg, tokens,
+                                        self.cache, self.slot_lens,
+                                        self._device_tables(),
+                                        self.use_kernel)
+            return _decode_fn(self.params, self.cfg, tokens, self.cache,
+                              self.slot_lens, self.use_kernel)
 
     def commit_slots(self, new_cache: Dict, advances) -> None:
         """Commit per-slot: rows with advance > 0 take the new cache and
@@ -466,20 +485,21 @@ class DecodeEngine:
         rows that advanced 0 only wrote past their committed length —
         positions every mask skips until a later forward overwrites
         them.  Per-row selection would therefore change nothing."""
-        adv_host = np.asarray(advances, np.int64)
-        adv = jnp.asarray(adv_host, jnp.int32)
-        self.slot_lens_host = self.slot_lens_host + adv_host
-        if self.manager is not None:
-            self.cache = new_cache
+        with TraceAnnotation("repro.engine.commit"):
+            adv_host = np.asarray(advances, np.int64)
+            adv = jnp.asarray(adv_host, jnp.int32)
+            self.slot_lens_host = self.slot_lens_host + adv_host
+            if self.manager is not None:
+                self.cache = new_cache
+                self.slot_lens = self.slot_lens + adv
+                return
+            keep = adv > 0                           # (batch,) on device
+            self.cache = jax.tree.map(
+                lambda old, new: jnp.where(
+                    keep.reshape((1, self.batch) + (1,) * (old.ndim - 2)),
+                    new, old),
+                self.cache, new_cache)
             self.slot_lens = self.slot_lens + adv
-            return
-        keep = adv > 0                               # (batch,) on device
-        self.cache = jax.tree.map(
-            lambda old, new: jnp.where(
-                keep.reshape((1, self.batch) + (1,) * (old.ndim - 2)),
-                new, old),
-            self.cache, new_cache)
-        self.slot_lens = self.slot_lens + adv
 
     def release_slot(self, slot: int) -> None:
         if self.manager is not None:

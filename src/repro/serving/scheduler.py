@@ -74,6 +74,7 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.decode_attention.ops import slack_report
 from repro.serving.algorithm import SlotAdapter
@@ -156,6 +157,8 @@ class Request:
     done: bool = False
     slo_class: str = "default"
     preemptions: int = 0                   # times evicted + requeued
+    t_submit: float = 0.0                  # time.perf_counter() at submit
+    t_admit: Optional[float] = None        #   and at the first admission
 
     @property
     def context(self) -> np.ndarray:
@@ -287,7 +290,7 @@ class ServingLoop:
                     f"request needs up to {worst} KV blocks but the pool "
                     f"only has {mgr.n_blocks}; it can never be admitted")
         req = Request(self._next_rid, prompt, int(max_tokens),
-                      slo_class=slo_class)
+                      slo_class=slo_class, t_submit=time.perf_counter())
         self._next_rid += 1
         self.waiting.append(req)
         return req
@@ -421,6 +424,40 @@ class ServingLoop:
         inherent — and batched: one ``greedy_tokens`` readback covers
         every freshly admitted slot (resumed requests need none, their
         pending token is host state already)."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("repro.scheduler.admit") as span:
+            admitted = self._select()
+            if not admitted:
+                return 0
+            span.set_metadata(rids=" ".join(
+                str(r.rid) for r in admitted.values()))
+            outs = self.engine.prefill_slots(
+                {s: self._admit_tokens(r) for s, r in admitted.items()},
+                reserve={s: self._reserve_len(r)
+                         for s, r in admitted.items()})
+            fresh = sorted(s for s, r in admitted.items() if not r.generated)
+            if fresh:
+                # first token of every fresh request in ONE device argmax +
+                # one small (k,) readback — the admission-boundary transfer
+                with TraceAnnotation("repro.scheduler.first_token"):
+                    first = np.asarray(greedy_tokens(
+                        jnp.stack([outs[s][0] for s in fresh])))
+                for i, s in enumerate(fresh):
+                    req = admitted[s]
+                    req.pending = int(first[i])
+                    req.generated = [req.pending]
+            for slot, req in admitted.items():
+                if req.preemptions and slot not in fresh:
+                    self.resumed_total += 1
+                if req.t_admit is None:
+                    req.t_admit = t0
+                self.active[slot] = req
+                self.adapter.begin(req, outs[slot][1])
+            return len(admitted)
+
+    def _select(self) -> Dict[int, Request]:
+        """The admission group: {slot: request} popped from the queue in
+        priority order while the budget and the pool allow."""
         admitted: Dict[int, Request] = {}
         promised = 0                      # blocks owed to this group
         ell = int(self.engine.slot_lens_host.max())
@@ -445,27 +482,7 @@ class ServingLoop:
             cand.slot = slot
             admitted[slot] = cand
             ell = ell_next
-        if not admitted:
-            return 0
-        outs = self.engine.prefill_slots(
-            {s: self._admit_tokens(r) for s, r in admitted.items()},
-            reserve={s: self._reserve_len(r) for s, r in admitted.items()})
-        fresh = sorted(s for s, r in admitted.items() if not r.generated)
-        if fresh:
-            # first token of every fresh request in ONE device argmax +
-            # one small (k,) readback — the admission-boundary transfer
-            first = np.asarray(greedy_tokens(
-                jnp.stack([outs[s][0] for s in fresh])))
-            for i, s in enumerate(fresh):
-                req = admitted[s]
-                req.pending = int(first[i])
-                req.generated = [req.pending]
-        for slot, req in admitted.items():
-            if req.preemptions and slot not in fresh:
-                self.resumed_total += 1
-            self.active[slot] = req
-            self.adapter.begin(req, outs[slot][1])
-        return len(admitted)
+        return admitted
 
     # ------------------------------------------------------------------
     def _attn_slack(self, width: int) -> Optional[Dict]:
@@ -498,27 +515,28 @@ class ServingLoop:
         WITHOUT committing; appends this forward's telemetry entry.
         Returns (logits, new_cache, hidden)."""
         width = tokens.shape[1]
-        entry = {
-            "active": len(self.active), "width": width,
-            "positions": len(self.active) * width, "budget": budget,
-            "budget_analytic": self._budget_info.get("analytic", budget),
-            "ell": self._budget_info.get("ell", 1),
-        }
-        if "calibrated" in self._budget_info:
-            entry["budget_calibrated"] = self._budget_info["calibrated"]
-        if self.engine.manager is not None:
-            entry["kv_blocks_used"] = self.engine.manager.blocks_used()
-        slack = self._attn_slack(width)
-        if slack is not None:
-            entry.update({
-                "attn_rows_physical": slack["rows_physical"],
-                "attn_row_util": slack["row_utilization"],
-                "kv_tiles_executed": slack["kv_tiles_executed"],
-                "kv_tiles_grid": slack["kv_tiles_grid"],
-                "kv_tiles_skipped": slack["kv_tiles_skipped"],
-                "kv_tile_util": slack["kv_tile_utilization"],
-            })
-        self.step_log.append(entry)
+        with TraceAnnotation("repro.scheduler.log_forward"):
+            entry = {
+                "active": len(self.active), "width": width,
+                "positions": len(self.active) * width, "budget": budget,
+                "budget_analytic": self._budget_info.get("analytic", budget),
+                "ell": self._budget_info.get("ell", 1),
+            }
+            if "calibrated" in self._budget_info:
+                entry["budget_calibrated"] = self._budget_info["calibrated"]
+            if self.engine.manager is not None:
+                entry["kv_blocks_used"] = self.engine.manager.blocks_used()
+            slack = self._attn_slack(width)
+            if slack is not None:
+                entry.update({
+                    "attn_rows_physical": slack["rows_physical"],
+                    "attn_row_util": slack["row_utilization"],
+                    "kv_tiles_executed": slack["kv_tiles_executed"],
+                    "kv_tiles_grid": slack["kv_tiles_grid"],
+                    "kv_tiles_skipped": slack["kv_tiles_skipped"],
+                    "kv_tile_util": slack["kv_tile_utilization"],
+                })
+            self.step_log.append(entry)
         return self.engine.decode_slots(jnp.asarray(tokens, jnp.int32))
 
     # ------------------------------------------------------------------
@@ -532,38 +550,45 @@ class ServingLoop:
         (``repro.analysis`` walks exactly this function)."""
         if not self.active:
             return bool(self.waiting)
-        budget = self.budget()
-        slots = sorted(self.active)
-        width = self.adapter.width(len(slots), budget)
-        mark = len(self.step_log)
-        t0 = time.perf_counter()
-        self.adapter.run_step(slots, width, budget)
-        # --- step latency + controller feedback ------------------------
-        # run_step host-syncs on its accept loop, so the wall clock is a
-        # faithful per-step latency on a real accelerator; step_clock
-        # substitutes a latency model per forward (benchmarks on CPU).
-        dt = time.perf_counter() - t0
-        new = self.step_log[mark:]
-        if new:
-            if self.step_clock is not None:
-                ell = self._budget_info.get("ell", 1)
-                dt = sum(self.step_clock(e["width"], ell) for e in new)
-            new[-1]["step_latency_s"] = dt
-            if self.controller is not None:
-                ratio = self.controller.observe(
-                    self._budget_info.get("ell", 1),
-                    max(e["width"] for e in new), dt / len(new))
-                if ratio is not None:
-                    new[-1]["latency_ratio"] = ratio
-        # --- retire ----------------------------------------------------
-        for s in slots:
-            req = self.active[s]
-            if len(req.generated) >= req.max_tokens:
-                req.done = True
-                self.finished[req.rid] = req
-                del self.active[s]
-                self.engine.release_slot(s)
-                self.free_slots.append(s)
+        with TraceAnnotation("repro.scheduler.step") as span:
+            budget = self.budget()
+            slots = sorted(self.active)
+            width = self.adapter.width(len(slots), budget)
+            span.set_metadata(width=width, active=len(slots))
+            mark = len(self.step_log)
+            t0 = time.perf_counter()
+            self.adapter.run_step(slots, width, budget)
+            # --- step latency + controller feedback --------------------
+            # run_step host-syncs on its accept loop, so the wall clock is
+            # a faithful per-step latency on a real accelerator;
+            # step_clock substitutes a latency model per forward
+            # (benchmarks on CPU).
+            dt = time.perf_counter() - t0
+            new = self.step_log[mark:]
+            if new:
+                if self.step_clock is not None:
+                    ell = self._budget_info.get("ell", 1)
+                    dt = sum(self.step_clock(e["width"], ell) for e in new)
+                new[-1]["step_latency_s"] = dt
+                if self.controller is not None:
+                    ratio = self.controller.observe(
+                        self._budget_info.get("ell", 1),
+                        max(e["width"] for e in new), dt / len(new))
+                    if ratio is not None:
+                        new[-1]["latency_ratio"] = ratio
+            # --- retire ------------------------------------------------
+            with TraceAnnotation("repro.scheduler.retire") as retire:
+                done = []
+                for s in slots:
+                    req = self.active[s]
+                    if len(req.generated) >= req.max_tokens:
+                        req.done = True
+                        self.finished[req.rid] = req
+                        del self.active[s]
+                        self.engine.release_slot(s)
+                        self.free_slots.append(s)
+                        done.append(str(req.rid))
+                retire.set_metadata(rids=" ".join(done))
         return bool(self.active or self.waiting)
 
     # ------------------------------------------------------------------
