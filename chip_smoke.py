@@ -177,13 +177,17 @@ def check_kernels(reduced: bool = False) -> None:
         n_blk = s // bs
         perm = rng.permutation(b * n_blk).astype(np.int32)
         tables = jnp.asarray(perm.reshape(b, n_blk))
-        pool_shape = (b * n_blk + 1, bs, a.n_kv_heads, a.head_dim)
-        kp = jnp.zeros(pool_shape, jnp.bfloat16).at[tables.reshape(-1)].set(
-            kc.reshape(b * n_blk, bs, a.n_kv_heads, a.head_dim))
-        vp = jnp.zeros(pool_shape, jnp.bfloat16).at[tables.reshape(-1)].set(
-            vc.reshape(b * n_blk, bs, a.n_kv_heads, a.head_dim))
+        pool_shape = (1, a.n_kv_heads, b * n_blk + 1, a.head_dim, bs)
+
+        def pages(c):
+            return c.reshape(b * n_blk, bs, a.n_kv_heads,
+                             a.head_dim).transpose(0, 2, 3, 1)
+        kp = jnp.zeros(pool_shape, jnp.bfloat16).at[
+            0, :, tables.reshape(-1)].set(pages(kc))
+        vp = jnp.zeros(pool_shape, jnp.bfloat16).at[
+            0, :, tables.reshape(-1)].set(pages(vc))
         _compare(f"kernels paged_attention n={n} b={b} s={s} block={bs}",
-                 decode_attention_paged(q, kp, vp, lens, tables), ref,
+                 decode_attention_paged(q, kp, vp, lens, tables, 0), ref,
                  RTOL_BF16)
 
     # --- grouped MoE FFN at granite_moe_3b_a800m widths ------------------
@@ -325,10 +329,15 @@ def kernel_vs_xla(reduced: bool = False) -> None:
     tokens = jax.random.randint(jax.random.PRNGKey(SEED + 1),
                                 (size.batch, 8), 0, cfg.vocab_size)
     tables = jnp.asarray(eng.manager.device_tables())
-    got = _decode_paged_fn(params, cfg, tokens, eng.cache, eng.slot_lens,
-                           tables, use_kernel=True)[0]
-    ref = _decode_paged_fn(params, cfg, tokens, eng.cache, eng.slot_lens,
-                           tables, use_kernel=False)[0]
+    # each forward donates the pool it is given: hand the next one the
+    # pool the last returned.  Both write the same positions before they
+    # read them, past every row's committed length.
+    got, eng.cache, _ = _decode_paged_fn(params, cfg, tokens, eng.cache,
+                                         eng.slot_lens, tables,
+                                         use_kernel=True)
+    ref, eng.cache, _ = _decode_paged_fn(params, cfg, tokens, eng.cache,
+                                         eng.slot_lens, tables,
+                                         use_kernel=False)
     _compare(f"xla decode logits {cfg.name} slots={size.batch} n=8 "
              f"lens={eng.slot_lens_host.tolist()}", got, ref, RTOL_LOGITS)
 

@@ -175,11 +175,21 @@ def default_targets() -> List[CaptureTarget]:
         from repro.kernels.decode_attention import ops
         n_phys, bs, kv, dh, b = 6, 16, 2, 128, 2
         q = jnp.zeros((b, 1, 4, dh), jnp.float32)
-        kpool = jnp.zeros((n_phys, bs, kv, dh), jnp.float32)
-        vpool = jnp.zeros((n_phys, bs, kv, dh), jnp.float32)
+        kpool = jnp.zeros((2, kv, n_phys, dh, bs), jnp.float32)
+        vpool = jnp.zeros((2, kv, n_phys, dh, bs), jnp.float32)
         lens = jnp.asarray([5, 30], jnp.int32)
         tables = jnp.asarray([[0, 1, 5, 5], [2, 3, 4, 5]], jnp.int32)
-        ops.decode_attention_paged(q, kpool, vpool, lens, tables)
+        ops.decode_attention_paged(q, kpool, vpool, lens, tables, 1)
+
+    def paged_write():
+        from repro.kernels.decode_attention import ops
+        n_phys, bs, kv, dh, b, n = 6, 16, 2, 128, 2, 3
+        kpool = jnp.zeros((2, kv, n_phys, dh, bs), jnp.float32)
+        vpool = jnp.zeros((2, kv, n_phys, dh, bs), jnp.float32)
+        new = jnp.zeros((b, n, kv, dh), jnp.float32)
+        pages = jnp.asarray([[1, 5], [3, 4]], jnp.int32)  # row 1 straddles
+        starts = jnp.asarray([2, 14], jnp.int32)
+        ops.paged_kv_write(kpool, vpool, new, new, 1, pages, starts)
 
     def moe():
         from repro.kernels.moe_ffn import ops
@@ -213,6 +223,8 @@ def default_targets() -> List[CaptureTarget]:
                       kp["decode_attention"], ragged(1, window=64)),
         CaptureTarget("decode_attention_paged/n1", kp["decode_attention"],
                       paged),
+        CaptureTarget("paged_kv_write/n3", kp["decode_attention"],
+                      paged_write),
         CaptureTarget("grouped_ffn/decode", kp["moe_ffn"], moe),
         CaptureTarget("selective_scan/decode", kp["mamba_scan"], scan),
     ]

@@ -49,7 +49,8 @@ NEG_INF = -1e30
 def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
                  m_ref, l_ref, acc_ref, *,
                  q_block: int, k_block: int, g: int, scale: float,
-                 window: Optional[int], n_kv_tiles: int, n_logical: int):
+                 window: Optional[int], n_kv_tiles: int, n_logical: int,
+                 kv_lanes: bool = False):
     ib = pl.program_id(0)
     iq = pl.program_id(2)
     ij = pl.program_id(3)
@@ -79,14 +80,18 @@ def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(useful)
     def _compute():
         rows = g * q_block
-        q = q_ref[0, 0].reshape(rows, q_ref.shape[-1]).astype(jnp.float32)
-        # dense layout blocks are (1, 1, kb, dh); paged pool blocks are
-        # (1, kb, dh) — flatten either to the (kb, dh) tile
-        k = k_ref[...].reshape(k_block, k_ref.shape[-1]).astype(jnp.float32)
-        v = v_ref[...].reshape(k_block, v_ref.shape[-1]).astype(jnp.float32)
+        dh = q_ref.shape[-1]
+        q = q_ref[0, 0].reshape(rows, dh).astype(jnp.float32)
+        # dense layout blocks are (1, 1, kb, dh); paged pool pages hold
+        # their positions on the lanes, (1, 1, 1, dh, kb): flatten either
+        # to its 2-D tile and contract over dh wherever it sits
+        tile = (dh, k_block) if kv_lanes else (k_block, dh)
+        d_axis = 0 if kv_lanes else 1
+        k = k_ref[...].reshape(tile).astype(jnp.float32)
+        v = v_ref[...].reshape(tile).astype(jnp.float32)
 
         scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (d_axis,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (rows, kb)
 
         # --- causal / window / validity mask -------------------------------
@@ -108,8 +113,9 @@ def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(scores - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = (alpha * acc_ref[...]
-                        + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                              preferred_element_type=jnp.float32))
+                        + jax.lax.dot_general(
+                            p, v, (((1,), (1 - d_axis,)), ((), ())),
+                            preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
     @pl.when(ij == n_kv_tiles - 1)
@@ -185,33 +191,36 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
 
 
 def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
-                                  block_tables, *, q_block: int,
-                                  block_size: int, scale: float,
+                                  block_tables, layer, *, q_block: int,
+                                  scale: float,
                                   window: Optional[int] = None,
                                   n_logical: Optional[int] = None,
                                   interpret: bool = False):
     """Block-table-indexed variant: the KV cache is a GLOBAL paged pool.
 
-    q: (b, kv, g, n_pad, dh); k_pool/v_pool: (kv, n_phys*block_size, dh)
-    — the refcounted block pool flattened along the position axis, one
-    physical page per kv tile (the page size IS this launch's k_block);
+    q: (b, kv, g, n_pad, dh); k_pool/v_pool: (layers, kv, n_phys, dh,
+    block_size) — every layer's refcounted block pool, one physical
+    page per kv tile (the page size IS this launch's k_block), each page
+    holding its positions on the lanes;
     cache_lens: (b,) i32; block_tables: (b, max_blocks) i32 mapping row
-    b's LOGICAL kv tile ij to a physical page.
+    b's LOGICAL kv tile ij to a physical page; layer: (1,) i32, the
+    layer whose pages this launch reads.
 
     This is the (b,) ``cache_lens`` scalar-prefetch machinery
-    generalized one step: a SECOND prefetch operand carries the block
-    tables, and the K/V BlockSpec index map — the same per-row
+    generalized: further prefetch operands carry the block tables and
+    the layer, and the K/V BlockSpec index map — the same per-row
     useful-tile clamp as the ragged dense kernel — returns
-    ``bt[ib, clamp(ij)]`` instead of ``clamp(ij)``, so the DMA engine
-    walks each row's (arbitrarily fragmented) page list while the
-    in-kernel masks keep operating in LOGICAL positions.  The tile-skip
-    rule (and therefore ``ops.slack_report``) is unchanged: a skipped
-    grid step revisits the row's last useful page, and Pallas elides
-    the copy when the physical page index is unchanged.  Rows whose
-    table entries point at the trailing trash page (inactive slots)
-    read junk that the causal mask zeroes out exactly.
+    ``(layer, ik, bt[ib, clamp(ij)])`` instead of ``clamp(ij)``, so the
+    DMA engine walks each row's (arbitrarily fragmented) page list in
+    the stacked pool while the in-kernel masks keep operating in LOGICAL
+    positions.  The tile-skip rule (and therefore ``ops.slack_report``)
+    is unchanged: a skipped grid step revisits the row's last useful
+    page, and Pallas elides the copy when the page index is unchanged.
+    Rows whose table entries point at the trailing trash page (inactive
+    slots) read junk that the causal mask zeroes out exactly.
     """
     b, kv, g, n_pad, dh = q.shape
+    block_size = k_pool.shape[4]
     n_q_tiles = n_pad // q_block
     n_kv_tiles = block_tables.shape[1]
     grid = (b, kv, n_q_tiles, n_kv_tiles)
@@ -219,15 +228,17 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
     n_log = n_pad if n_logical is None else n_logical
     kernel = functools.partial(
         _attn_kernel, q_block=q_block, k_block=block_size, g=g, scale=scale,
-        window=window, n_kv_tiles=n_kv_tiles, n_logical=n_log)
+        window=window, n_kv_tiles=n_kv_tiles, n_logical=n_log,
+        kv_lanes=True)
 
-    def paged_kernel(lens_ref, bt_ref, *refs, **kw):
-        # the block tables only steer the index maps; the kernel body is
-        # the ragged kernel unchanged (it masks in logical positions)
-        del bt_ref
+    def paged_kernel(lens_ref, bt_ref, layer_ref, *refs, **kw):
+        # the block tables and the layer only steer the index maps; the
+        # kernel body is the ragged kernel's (it masks in logical
+        # positions), fed pages with their positions on the lanes
+        del bt_ref, layer_ref
         return kernel(lens_ref, *refs, **kw)
 
-    def kv_index(ib, ik, iq, ij, lens_ref, bt_ref):
+    def kv_index(ib, ik, iq, ij, lens_ref, bt_ref, layer_ref):
         # identical useful-range clamp to the dense ragged kernel, then
         # mapped through the row's block table: logical tile -> physical
         # page.  Entries inside the clamp range are always valid pages
@@ -240,18 +251,18 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
             first = jnp.maximum(
                 (lens_ref[ib] + iq * q_block - window + 1) // block_size, 0)
             idx = jnp.maximum(idx, jnp.minimum(first, last))
-        return (ik, bt_ref[ib, idx], 0)
+        return (layer_ref[0], ik, bt_ref[ib, idx], 0, 0)
 
     return pl.pallas_call(
         paged_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, q_block, dh),
                              lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0)),
-                pl.BlockSpec((1, block_size, dh), kv_index),
-                pl.BlockSpec((1, block_size, dh), kv_index),
+                pl.BlockSpec((1, 1, 1, dh, block_size), kv_index),
+                pl.BlockSpec((1, 1, 1, dh, block_size), kv_index),
             ],
             out_specs=pl.BlockSpec((1, 1, g, q_block, dh),
                                    lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0)),
@@ -264,4 +275,71 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, n_pad, dh), q.dtype),
         interpret=interpret,
         name="decode_attention_paged",
-    )(cache_lens, block_tables, q, k_pool, v_pool)
+    )(cache_lens, block_tables, layer, q, k_pool, v_pool)
+
+
+def paged_kv_write_pallas(k_cols, v_cols, k_pool, v_pool, layer, pages,
+                          starts, *, interpret: bool = False):
+    """Write each row's new K/V positions into the stacked paged pool, in
+    place: the outputs alias ``k_pool`` and ``v_pool``.
+
+    k_cols/v_cols: (b, kv, dh, n) — row b's n new positions, one column
+    each; k_pool/v_pool: (layers, kv, n_phys, dh, bs); layer: (1,) i32;
+    pages: (b, p) i32, the pages of row b's logical blocks from the
+    first new position's on (the last page repeated past the last new
+    position); starts: (b,) i32, the first new position's offset in its
+    page.
+
+    Grid (b, p): step (ib, j) reads page ``pages[ib, j]`` of every kv
+    head, sets the lanes row ib's new positions fall on and writes the
+    page back — one read and one write of each page the new positions
+    touch, for all heads and positions at once.  Live pages are written
+    by their owning row alone; rows that share the trash page may lose
+    each other's writes there, which nothing reads.
+    """
+    _, kv, _, dh, bs = k_pool.shape
+    b, n_pages = pages.shape
+    n = k_cols.shape[-1]
+
+    def kernel(layer_ref, pages_ref, starts_ref, kc_ref, vc_ref, kin_ref,
+               vin_ref, kout_ref, vout_ref):
+        ib, j = pl.program_id(0), pl.program_id(1)
+        start = starts_ref[ib]
+        # steps past the row's last page see that page again (its index
+        # unchanged, so not fetched again) and write it again, the same
+        jb = jnp.minimum(j, (start + n - 1) // bs)
+        # lane l of this page holds new position l + jb*bs - start: a
+        # one-hot product places the columns exactly
+        shift = start - jb * bs
+        src = jax.lax.broadcasted_iota(jnp.int32, (n, bs), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (n, bs), 1)
+        onehot = (lane - shift == src).astype(kc_ref.dtype)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) - shift
+        hit = (lane >= 0) & (lane < n)
+        for c_ref, i_ref, o_ref in ((kc_ref, kin_ref, kout_ref),
+                                    (vc_ref, vin_ref, vout_ref)):
+            placed = jnp.dot(c_ref[...].reshape(kv * dh, n), onehot,
+                             preferred_element_type=jnp.float32)
+            page = i_ref[...].reshape(kv * dh, bs)
+            o_ref[...] = jnp.where(hit, placed.astype(page.dtype),
+                                   page).reshape(o_ref.shape)
+
+    def page_index(ib, j, layer_ref, pages_ref, starts_ref):
+        return (layer_ref[0], 0, pages_ref[ib, j], 0, 0)
+
+    page_spec = pl.BlockSpec((1, kv, 1, dh, bs), page_index)
+    cols_spec = pl.BlockSpec((1, kv, dh, n), lambda ib, j, *_: (ib, 0, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, n_pages),
+            in_specs=[cols_spec, cols_spec, page_spec, page_spec],
+            out_specs=[page_spec, page_spec],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        input_output_aliases={5: 0, 6: 1},
+        interpret=interpret,
+        name="paged_kv_write",
+    )(layer, pages, starts, k_cols, v_cols, k_pool, v_pool)

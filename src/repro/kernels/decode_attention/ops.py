@@ -13,7 +13,8 @@ is exactly the granularity the NFP predictor reads from
 in a global refcounted block pool and a (b, max_blocks) block table
 (second scalar-prefetch operand) maps each row's logical kv tile to a
 physical page — the page size is that launch's k_block, so paging slots
-straight into the same tile-skip machinery.
+straight into the same tile-skip machinery.  ``paged_kv_write`` writes
+a forward's new K/V into that pool in place, a page at a time.
 
 ``slack_report`` models the kernel's physical work for one forward in
 plain numpy — useful vs padded query rows, and executed vs grid kv tiles
@@ -34,7 +35,8 @@ import numpy as np
 
 from repro.core.granularity import cdiv, round_up, select_q_block
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_paged_pallas, decode_attention_pallas)
+    decode_attention_paged_pallas, decode_attention_pallas,
+    paged_kv_write_pallas)
 
 K_BLOCK = 128
 
@@ -84,26 +86,29 @@ def decode_attention_ragged(q, k_cache, v_cache, cache_lens, *,
 
 @functools.partial(jax.jit, static_argnames=("window", "q_block_override",
                                              "interpret"))
-def decode_attention_paged(q, k_pool, v_pool, cache_lens, block_tables, *,
-                           window: Optional[int] = None,
+def decode_attention_paged(q, k_pool, v_pool, cache_lens, block_tables,
+                           layer, *, window: Optional[int] = None,
                            q_block_override: Optional[int] = None,
                            interpret: Optional[bool] = None):
     """Paged-pool kernel entry the scheduler's paged cache serves.
 
-    q: (b, n, h, dh); k_pool/v_pool: (n_phys, bs, kv, dh) — the global
-    refcounted block pool (``serving.paged``), whose page size ``bs``
-    becomes this launch's kv tile (k_block); cache_lens: (b,) committed
-    lengths; block_tables: (b, max_blocks) i32 logical->physical page
-    map per row (unassigned entries point at the trailing trash page).
+    q: (b, n, h, dh); k_pool/v_pool: (layers, kv, n_phys, dh, bs) — the
+    global refcounted block pool of every layer (``serving.paged``), as
+    the engine stores it, whose page size ``bs`` becomes this launch's
+    kv tile (k_block); layer: this launch's index into the pool's first
+    axis (a traced scalar); cache_lens: (b,) committed lengths;
+    block_tables: (b, max_blocks) i32 logical->physical page map per row
+    (unassigned entries point at the trailing trash page).
 
     Row b's N query positions sit at cache_lens[b] .. cache_lens[b]+N-1
-    in LOGICAL positions; their K/V must already be scattered into the
-    pool at the pages the table names.  Returns (b, n, h, dh).
+    in LOGICAL positions; their K/V must already be written into the
+    pool at the pages the table names.  The pool goes to the kernel
+    untouched: no slice, transpose or copy of it.  Returns (b, n, h, dh).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, n, h, dh = q.shape
-    n_phys, bs, kv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    kv = k_pool.shape[1]
     g = h // kv
     q_block = q_block_override or select_q_block(n, dh)
     n_pad = round_up(n, q_block)
@@ -111,18 +116,44 @@ def decode_attention_paged(q, k_pool, v_pool, cache_lens, block_tables, *,
 
     qk = q.reshape(b, n, kv, g, dh).transpose(0, 2, 3, 1, 4)   # (b,kv,g,n,dh)
     qk = jnp.pad(qk, ((0, 0), (0, 0), (0, 0), (0, n_pad - n), (0, 0)))
-    # pool -> (kv, n_phys*bs, dh): one physical page per kv-tile DMA
-    kk = k_pool.transpose(2, 0, 1, 3).reshape(kv, n_phys * bs, dh)
-    vk = v_pool.transpose(2, 0, 1, 3).reshape(kv, n_phys * bs, dh)
     lens = jnp.broadcast_to(
         jnp.asarray(cache_lens, jnp.int32).reshape(-1), (b,))
     bt = jnp.asarray(block_tables, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    o = decode_attention_paged_pallas(qk, kk, vk, lens, bt, q_block=q_block,
-                                      block_size=bs, scale=scale,
+    o = decode_attention_paged_pallas(qk, k_pool, v_pool, lens, bt, layer,
+                                      q_block=q_block, scale=scale,
                                       window=window, n_logical=n,
                                       interpret=interpret)
     return o[:, :, :, :n].transpose(0, 3, 1, 2, 4).reshape(b, n, h, dh)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_kv_write(k_pool, v_pool, k, v, layer, pages, starts, *,
+                   interpret: Optional[bool] = None):
+    """Write a forward's new K/V into the stacked paged pool in place.
+
+    k_pool/v_pool: (layers, kv, n_phys, dh, bs); k/v: (b, n, kv, dh), row
+    b's n new positions, the first at offset ``starts[b]`` of page
+    ``pages[b, 0]``; pages: (b, p) the pages of the row's consecutive
+    logical blocks from there, p at least the blocks the n positions
+    span, entries past the last of them repeating its page; layer: the
+    pool's layer to write (a traced scalar).  Returns the
+    updated (k_pool, v_pool), the same buffers where the caller's are
+    not needed after the call (a scan carry, a donated argument).
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+
+    def cols(x):                   # (b, n, kv, dh) -> (b, kv, dh, n)
+        return x.astype(k_pool.dtype).transpose(0, 2, 3, 1)
+
+    k_pool, v_pool = paged_kv_write_pallas(
+        cols(k), cols(v), k_pool, v_pool,
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.asarray(pages, jnp.int32), jnp.asarray(starts, jnp.int32),
+        interpret=interpret)
+    return k_pool, v_pool
 
 
 @functools.partial(jax.jit, static_argnames=("window", "q_block_override",

@@ -72,19 +72,26 @@ def init_kv_cache(batch: int, max_len: int, a: AttentionSpec,
 
 def init_paged_kv_cache(n_phys: int, block_size: int, a: AttentionSpec,
                         dtype=jnp.bfloat16) -> Dict:
-    """Paged decode cache: a GLOBAL pool of ``n_phys`` blocks of
-    ``block_size`` positions, shared by all slots through per-slot block
-    tables (``serving.paged.BlockManager``).  The last block is the
-    write-dump page unattached table entries point at."""
+    """One layer of the paged decode cache: a GLOBAL pool of ``n_phys``
+    blocks of ``block_size`` positions, shared by all slots through
+    per-slot block tables (``serving.paged.BlockManager``).  The last
+    block is the write-dump page unattached table entries point at.
+
+    A page holds its positions on the minor axis, (d, block): GQA/MHA
+    K and V are (kv, n_phys, head_dim, block), MLA leaves (n_phys, d,
+    block).  With a 128-position page that minor axis fills the TPU's
+    128 lanes, so the device stores the pool row-major — the layout the
+    paged kernel's DMA reads page by page — and never pads a head_dim
+    such as 80 or 64 up to the lanes."""
     if a.kind == "mla":
         return {
-            "latent": jnp.zeros((n_phys, block_size, a.kv_lora_rank), dtype),
-            "k_rope": jnp.zeros((n_phys, block_size, a.qk_rope_head_dim),
+            "latent": jnp.zeros((n_phys, a.kv_lora_rank, block_size), dtype),
+            "k_rope": jnp.zeros((n_phys, a.qk_rope_head_dim, block_size),
                                 dtype),
         }
     return {
-        "k": jnp.zeros((n_phys, block_size, a.n_kv_heads, a.head_dim), dtype),
-        "v": jnp.zeros((n_phys, block_size, a.n_kv_heads, a.head_dim), dtype),
+        "k": jnp.zeros((a.n_kv_heads, n_phys, a.head_dim, block_size), dtype),
+        "v": jnp.zeros((a.n_kv_heads, n_phys, a.head_dim, block_size), dtype),
     }
 
 
@@ -127,43 +134,51 @@ def _update_rows(cache: Array, new: Array, offsets: Array) -> Array:
     return jax.vmap(one)(cache, new, offsets)
 
 
-def _paged_write_idx(block_tables: Array, q_pos: Array, block_size: int,
-                     n_phys: int) -> Array:
-    """Flat pool slots (page*block_size + offset) for per-row positions
-    (b, n).  Positions past the table's coverage — e.g. junk rows of a
+def _paged_slots(block_tables: Array, pos: Array, n_phys: int,
+                 block_size: int) -> Tuple[Array, Array]:
+    """Pool (page, offset) of per-row logical positions (b, n).
+    Positions past the table's coverage — e.g. junk rows of a
     width-bucketed batched forward on an inactive slot — fall through to
     the trailing trash page, never a live block."""
     b, max_blocks = block_tables.shape
-    blk_idx = jnp.clip(q_pos // block_size, 0, max_blocks - 1)
+    blk_idx = jnp.clip(pos // block_size, 0, max_blocks - 1)
     page = jnp.take_along_axis(block_tables, blk_idx, axis=1)
-    page = jnp.where(q_pos < max_blocks * block_size, page, n_phys - 1)
-    return page * block_size + q_pos % block_size
+    page = jnp.where(pos < max_blocks * block_size, page, n_phys - 1)
+    return page, pos % block_size
 
 
-def _paged_update(pool: Array, new: Array, flat_idx: Array) -> Array:
-    """Scatter ``new`` (b, n, ...) into the pool (n_phys, bs, ...) at
-    flat slot indices (b, n).  Live-block destinations are disjoint by
-    construction (writes require refcount-1 ownership; see
-    ``serving.paged``); only trash-page slots may collide, where the
-    winner is irrelevant."""
-    n_phys, bs = pool.shape[0], pool.shape[1]
-    flat = pool.reshape((n_phys * bs,) + pool.shape[2:])
-    flat = flat.at[flat_idx.reshape(-1)].set(
-        new.reshape((-1,) + new.shape[2:]))
-    return flat.reshape(pool.shape)
+def _pool_geometry(pool: Array) -> Tuple[int, int]:
+    """(n_phys, block) of a stacked paged pool leaf, (layers, [kv,]
+    n_phys, d, block)."""
+    return pool.shape[-3], pool.shape[-1]
 
 
-def _paged_gather(pool: Array, block_tables: Array) -> Array:
-    """Materialize each row's VIRTUAL contiguous cache from the pool:
-    (n_phys, bs, ...) + (b, max_blocks) -> (b, max_blocks*bs, ...).
+def _paged_write(pool: Array, layer, new: Array, page: Array,
+                 off: Array) -> Array:
+    """Write new entries (b, n, [kv,] d) into layer ``layer`` of a
+    stacked pool leaf at (page, offset) slots (b, n) — the XLA path's
+    write (the Pallas path writes with ``paged_kv_write``).  Live-block
+    destinations are disjoint by construction (writes require
+    refcount-1 ownership; see ``serving.paged``); only trash-page slots
+    may collide, where the winner is irrelevant."""
+    if pool.ndim == 5:             # (layers, kv, n_phys, dh, bs)
+        heads = jnp.arange(pool.shape[1], dtype=jnp.int32)
+        at = (layer, heads, page[..., None], slice(None), off[..., None])
+    else:                          # (layers, n_phys, d, bs)
+        at = (layer, page, slice(None), off)
+    return pool.at[at].set(new.astype(pool.dtype))
+
+
+def _paged_gather(pool: Array, layer, block_tables: Array) -> Array:
+    """Materialize each row's VIRTUAL contiguous cache from one layer
+    of the stacked pool: + (b, max_blocks) -> (b, max_blocks*bs, ...).
     The XLA reference path for paged decode — the Pallas path never
     materializes this, its DMA index map walks the table instead."""
-    n_phys, bs = pool.shape[0], pool.shape[1]
-    b, max_blocks = block_tables.shape
-    flat = pool.reshape((n_phys * bs,) + pool.shape[2:])
-    idx = (block_tables[:, :, None] * bs
-           + jnp.arange(bs, dtype=jnp.int32)[None, None, :])
-    return flat[idx.reshape(b, max_blocks * bs)]
+    b = block_tables.shape[0]
+    pages = pool[(layer,) + (slice(None),) * (pool.ndim - 4)
+                 + (block_tables,)]                # (b, mb, [kv,] d, bs)
+    pages = jnp.moveaxis(pages, -1, 2)             # (b, mb, bs, [kv,] d)
+    return pages.reshape((b, -1) + pages.shape[3:])
 
 
 def _causal_mask(q_pos: Array, kv_pos: Array,
@@ -257,22 +272,23 @@ def gqa_decode(params, a: AttentionSpec, x: Array, cache: Dict,
     return out, {"k": k_cache, "v": v_cache}
 
 
-def gqa_decode_paged(params, a: AttentionSpec, x: Array, cache: Dict,
-                     cache_len, block_tables: Array, theta: float,
+def gqa_decode_paged(params, a: AttentionSpec, x: Array, pool: Dict,
+                     cache_len, block_tables: Array, layer, theta: float,
                      use_kernel: bool = False) -> Tuple[Array, Dict]:
     """Paged multi-position decode: the cache is a global block pool
     (``init_paged_kv_cache``) indexed through per-row block tables.
 
-    Identical math to ``gqa_decode`` — the N new positions' K/V are
-    scattered to the pages the table names, then attention runs over
-    each row's virtual cache (gathered for the XLA path; walked by the
-    block-table DMA index map on the Pallas path).  Junk rows of a
-    batched forward write to the trash page, so a live block is only
-    ever written by the slot that owns it.
+    ``pool`` holds every layer's pages, stacked (layers, kv, n_phys, dh,
+    block), and ``layer`` says which are this layer's: the N new
+    positions' K/V are written straight into them, and attention runs
+    over each row's virtual cache (gathered for the XLA path; walked by
+    the block-table DMA index map on the Pallas path) with identical
+    math to ``gqa_decode``.  Junk rows of a batched forward write to the
+    trash page, so a live block is only ever written by the slot that
+    owns it.  Returns the output and the updated stacked pool.
     """
     b, n, d = x.shape
-    bs = cache["k"].shape[1]
-    n_phys = cache["k"].shape[0]
+    n_phys, bs = _pool_geometry(pool["k"])
     offsets = _row_offsets(cache_len, b)
     q_pos = offsets[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
     q = (x @ params["wq"]).reshape(b, n, a.n_heads, a.head_dim)
@@ -281,18 +297,36 @@ def gqa_decode_paged(params, a: AttentionSpec, x: Array, cache: Dict,
     q = apply_rope(q, q_pos, theta)
     k = apply_rope(k, q_pos, theta)
     bt = jnp.asarray(block_tables, jnp.int32)
-    flat_idx = _paged_write_idx(bt, q_pos, bs, n_phys)
-    k_pool = _paged_update(cache["k"], k, flat_idx)
-    v_pool = _paged_update(cache["v"], v, flat_idx)
     window = a.window if a.kind == "swa" else None
     scale = 1.0 / (a.head_dim ** 0.5)
     if use_kernel:
-        from repro.kernels.decode_attention.ops import decode_attention_paged
-        ctx = decode_attention_paged(q, k_pool, v_pool, offsets, bt,
+        from repro.kernels.decode_attention.ops import (
+            decode_attention_paged, paged_kv_write)
+        k_pool, v_pool = pool["k"], pool["v"]
+        # at most 128 new positions a launch: the write kernel holds
+        # them, for every head, in VMEM
+        for c0 in range(0, n, 128):
+            m = min(n, c0 + 128) - c0
+            first = offsets + c0
+            # the pages of each row's blocks from its first new position
+            # on, as many as m positions can span
+            blocks = first[:, None] // bs + jnp.arange(
+                (m - 1) // bs + 2, dtype=jnp.int32)[None, :]
+            blocks = jnp.minimum(blocks, (first[:, None] + m - 1) // bs)
+            pages, _ = _paged_slots(bt, blocks * bs, n_phys, bs)
+            # static slices of the traced forward: no compile of their own
+            k_pool, v_pool = paged_kv_write(
+                k_pool, v_pool,
+                k[:, c0:c0 + m], v[:, c0:c0 + m],  # analysis: allow-recompile-hazard
+                layer, pages, first % bs)
+        ctx = decode_attention_paged(q, k_pool, v_pool, offsets, bt, layer,
                                      window=window)
     else:
-        k_virt = _paged_gather(k_pool, bt)
-        v_virt = _paged_gather(v_pool, bt)
+        page, off = _paged_slots(bt, q_pos, n_phys, bs)
+        k_pool = _paged_write(pool["k"], layer, k, page, off)
+        v_pool = _paged_write(pool["v"], layer, v, page, off)
+        k_virt = _paged_gather(k_pool, layer, bt)
+        v_virt = _paged_gather(v_pool, layer, bt)
         s_virt = k_virt.shape[1]
         kv_pos = jnp.broadcast_to(
             jnp.arange(s_virt, dtype=jnp.int32)[None, :], (b, s_virt))
@@ -453,24 +487,25 @@ def mla_decode(params, a: AttentionSpec, x: Array, cache: Dict,
     return out, {"latent": latent, "k_rope": k_rope}
 
 
-def mla_decode_paged(params, a: AttentionSpec, x: Array, cache: Dict,
-                     cache_len, block_tables: Array, theta: float
+def mla_decode_paged(params, a: AttentionSpec, x: Array, pool: Dict,
+                     cache_len, block_tables: Array, layer, theta: float
                      ) -> Tuple[Array, Dict]:
-    """Absorbed MLA decode over a paged latent pool (XLA path only —
-    the Pallas kernel serves GQA/SWA geometries, as in the dense case)."""
+    """Absorbed MLA decode over the stacked paged latent pool (layers,
+    n_phys, d, block), writing and reading layer ``layer`` (XLA path
+    only — the Pallas kernel serves GQA/SWA geometries, as in the dense
+    case)."""
     b, n, _ = x.shape
-    bs = cache["latent"].shape[1]
-    n_phys = cache["latent"].shape[0]
+    n_phys, bs = _pool_geometry(pool["latent"])
     offsets = _row_offsets(cache_len, b)
     q_pos = offsets[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
     q_nope, q_rope = _mla_q(params, a, x, q_pos, theta)
     latent_new, k_rope_new = _mla_latent(params, a, x, q_pos, theta)
     bt = jnp.asarray(block_tables, jnp.int32)
-    flat_idx = _paged_write_idx(bt, q_pos, bs, n_phys)
-    latent_pool = _paged_update(cache["latent"], latent_new, flat_idx)
-    k_rope_pool = _paged_update(cache["k_rope"], k_rope_new, flat_idx)
-    latent = _paged_gather(latent_pool, bt)
-    k_rope = _paged_gather(k_rope_pool, bt)
+    page, off = _paged_slots(bt, q_pos, n_phys, bs)
+    latent_pool = _paged_write(pool["latent"], layer, latent_new, page, off)
+    k_rope_pool = _paged_write(pool["k_rope"], layer, k_rope_new, page, off)
+    latent = _paged_gather(latent_pool, layer, bt)
+    k_rope = _paged_gather(k_rope_pool, layer, bt)
     s_virt = latent.shape[1]
     wkv_b = params["wkv_b"].reshape(a.kv_lora_rank, a.n_heads,
                                     a.qk_nope_head_dim + a.v_head_dim)
@@ -506,13 +541,15 @@ def attention_full(params, a: AttentionSpec, x, positions, theta,
 
 def attention_decode(params, a: AttentionSpec, x, cache, cache_len, theta,
                      use_kernel: bool = False, swa_ring: bool = False,
-                     block_tables=None):
+                     block_tables=None, layer=None):
+    """Decode-mode attention.  With ``block_tables``, ``cache`` is the
+    whole stacked paged pool and ``layer`` this layer's index in it."""
     if block_tables is not None:
         if a.kind == "mla":
             return mla_decode_paged(params, a, x, cache, cache_len,
-                                    block_tables, theta)
+                                    block_tables, layer, theta)
         return gqa_decode_paged(params, a, x, cache, cache_len, block_tables,
-                                theta, use_kernel)
+                                layer, theta, use_kernel)
     if a.kind == "mla":
         return mla_decode(params, a, x, cache, cache_len, theta)
     if swa_ring and a.kind == "swa":

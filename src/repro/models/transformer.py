@@ -165,10 +165,12 @@ def init_paged_cache(cfg: ArchConfig, n_phys: int, block_size: int,
                      dtype=jnp.bfloat16) -> Dict:
     """Paged decode state: every attention layer shares ONE logical
     block layout (the per-slot block tables in ``serving.paged``), each
-    layer owning its own (n_phys, block_size, ...) pool.  Paging covers
-    KV caches only — recurrent (SSM/hybrid) state and encoder memory
-    have no sequence axis to page, so those archs keep the dense cache
-    (``DecodeEngine`` rejects them in paged mode)."""
+    layer owning its own pages of a stacked pool — GQA/MHA K and V
+    (layers, kv, n_phys, head_dim, block_size), MLA (layers, n_phys, d,
+    block_size).  Paging covers KV caches only — recurrent (SSM/hybrid)
+    state and encoder memory have no sequence axis to page, so those
+    archs keep the dense cache (``DecodeEngine`` rejects them in paged
+    mode)."""
     segs = []
     for kind, count in make_segments(cfg):
         if kind != LAYER_ATTN:
@@ -197,7 +199,7 @@ def _ffn_apply(lp, cfg: ArchConfig, h: Array, routing_override):
 def _attn_layer(lp, cfg: ArchConfig, x: Array, positions, cache, cache_len,
                 mode: str, use_kernel: bool, routing_override,
                 memory: Optional[Array], swa_ring: bool = False,
-                block_tables=None):
+                block_tables=None, layer=None):
     # named scopes are path components of every op's ``tf_op`` in a
     # profiler trace: device time splits by layer part with no flag
     with jax.named_scope("attention"):
@@ -206,7 +208,8 @@ def _attn_layer(lp, cfg: ArchConfig, x: Array, positions, cache, cache_len,
             att, new_cache = attention_decode(lp["attn"], cfg.attention, h,
                                               cache, cache_len, cfg.rope_theta,
                                               use_kernel, swa_ring,
-                                              block_tables=block_tables)
+                                              block_tables=block_tables,
+                                              layer=layer)
         else:
             att, new_cache = attention_full(lp["attn"], cfg.attention, h,
                                             positions, cfg.rope_theta,
@@ -298,7 +301,11 @@ def forward(params, cfg: ArchConfig, inputs: Dict, *, mode: str = "train",
     ``block_tables`` (b, max_blocks) i32 switches decode-mode attention
     onto the PAGED cache path: ``cache`` must then be an
     ``init_paged_cache`` pool and ``cache_len`` a (b,) per-slot length
-    vector (``serving.paged`` owns the table bookkeeping).
+    vector (``serving.paged`` owns the table bookkeeping).  The layer
+    scan then carries the stacked pool instead of slicing it per layer:
+    each layer writes its new positions into the carry and attention
+    reads its pages there, so the pool is never copied, and under a
+    caller that donates it the returned pool is the same buffer.
 
     ``hidden`` is the final-norm output (b, s, d) — the representation
     the LM head (and any auxiliary head bank, e.g. MTP) reads.  Serving
@@ -344,7 +351,15 @@ def forward(params, cfg: ArchConfig, inputs: Dict, *, mode: str = "train",
         sp = params["segments"][si]
         seg_cache = None if cache is None else cache["segments"][si]
 
-        if kind == LAYER_ATTN:
+        if kind == LAYER_ATTN and block_tables is not None:
+            def body(carry, inp):
+                (x, pool), (lp, layer) = carry, inp
+                y, pool, aux = _attn_layer(lp, cfg, x, positions, pool,
+                                           cache_len, mode, use_kernel,
+                                           routing_override, memory,
+                                           swa_ring, block_tables, layer)
+                return (y, pool), aux
+        elif kind == LAYER_ATTN:
             def body(x, inp, _kind=kind):
                 lp, lc = inp
                 y, nc, aux = _attn_layer(lp, cfg, x, positions, lc, cache_len,
@@ -394,7 +409,12 @@ def forward(params, cfg: ArchConfig, inputs: Dict, *, mode: str = "train",
             if frac > 0:
                 body = jax.checkpoint(body)
             with jax.named_scope("layers"):
-                x, (ncs, auxs) = jax.lax.scan(body, x, (sp, seg_cache))
+                if block_tables is not None:
+                    layer_ids = jnp.arange(count, dtype=jnp.int32)
+                    (x, ncs), auxs = jax.lax.scan(body, (x, seg_cache),
+                                                  (sp, layer_ids))
+                else:
+                    x, (ncs, auxs) = jax.lax.scan(body, x, (sp, seg_cache))
             new_segments.append(ncs)
         aux_total = aux_total + jnp.sum(auxs)
 

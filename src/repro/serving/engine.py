@@ -49,7 +49,11 @@ def _decode_fn(params, cfg: ArchConfig, tokens, cache, cache_len,
     return logits, cache, hidden
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "use_kernel"))
+# The paged programs take the pool donated: their output pool is the
+# input's buffer, updated in place, and the caller's reference to the
+# pool they were given is dead once they return.
+@functools.partial(jax.jit, static_argnames=("cfg", "use_kernel"),
+                   donate_argnames=("cache",))
 def _decode_paged_fn(params, cfg: ArchConfig, tokens, cache, slot_lens,
                      block_tables, use_kernel=False):
     logits, cache, _, hidden = forward(params, cfg, {"tokens": tokens},
@@ -69,24 +73,35 @@ def greedy_tokens(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnames=("cache",))
 def _copy_pool_blocks(cache, src, dst):
     """Copy pool blocks src -> dst across every layer (the COW device
-    op).  Pool leaves are (layers, n_phys, block, ...): index axis 1."""
-    return jax.tree.map(lambda pool: pool.at[:, dst].set(pool[:, src]), cache)
+    op).  Pool leaves are (layers, [kv,] n_phys, d, block): index the
+    page axis, third from last."""
+    return jax.tree.map(
+        lambda pool: pool.at[..., dst, :, :].set(pool[..., src, :, :]),
+        cache)
 
 
-@jax.jit
-def _scatter_prefill(cache, scratch, flat_idx, rows, cols):
+@functools.partial(jax.jit, donate_argnames=("cache",))
+def _scatter_prefill(cache, scratch, pages, rows, blocks):
     """Move freshly prefilled KV from the dense scratch cache into pool
-    pages: scratch[(row, col)] -> pool_flat[flat_idx], per layer.
+    pages, a whole page at a time: block ``blocks[i]`` of scratch row
+    ``rows[i]`` -> pool page ``pages[i]``, every layer.  A prompt's last
+    page also takes the scratch's padding positions, past the prompt's
+    length, where no mask reads before a forward overwrites them.
     Padding entries target the trash page (duplicate-index writes there
     are harmless)."""
     def one(pool, scr):
-        n_phys, bs = pool.shape[1], pool.shape[2]
-        flat = pool.reshape((pool.shape[0], n_phys * bs) + pool.shape[3:])
-        flat = flat.at[:, flat_idx].set(scr[:, rows, cols])
-        return flat.reshape(pool.shape)
+        bs = pool.shape[-1]
+        s = scr.shape[2]
+        pad = [(0, 0)] * scr.ndim
+        pad[2] = (0, -s % bs)
+        scr = jnp.pad(scr, pad)
+        scr = scr.reshape(scr.shape[:2] + (-1, bs) + scr.shape[3:])
+        new = scr[:, rows, blocks]                 # (L, m, bs, [kv,] d)
+        new = jnp.moveaxis(jnp.moveaxis(new, 2, -1), 1, -3)
+        return pool.at[..., pages, :, :].set(new)  # (L, [kv,] m, d, bs)
     return jax.tree.map(one, cache, scratch)
 
 
@@ -391,30 +406,28 @@ class DecodeEngine:
                 logits, scratch, hidden = _prefill_fn(
                     self.params, self.cfg, jnp.asarray(toks), scratch,
                     self.use_kernel)
-                rows, cols, flats = [], [], []
+                rows, blocks, pages = [], [], []
                 for s in full:
-                    pos = np.arange(lens[s])
-                    page = mgr.tables[s, pos // bs].astype(np.int64)
-                    rows.append(np.full(lens[s], s, np.int64))
-                    cols.append(pos)
-                    flats.append(page * bs + pos % bs)
+                    n_blk = -(-lens[s] // bs)
+                    rows.append(np.full(n_blk, s, np.int64))
+                    blocks.append(np.arange(n_blk))
+                    pages.append(mgr.tables[s, :n_blk].astype(np.int64))
                 rows = np.concatenate(rows)
-                cols = np.concatenate(cols)
-                flats = np.concatenate(flats)
+                blocks = np.concatenate(blocks)
+                pages = np.concatenate(pages)
                 # pad the scatter to a power-of-two bucket (compile reuse);
                 # pad entries dump into the trash page
-                m = 8
+                m = 1
                 while m < len(rows):
                     m *= 2
                 pad = m - len(rows)
                 rows = np.pad(rows, (0, pad))
-                cols = np.pad(cols, (0, pad))
-                flats = np.pad(flats, (0, pad),
-                               constant_values=mgr.trash * bs)
+                blocks = np.pad(blocks, (0, pad))
+                pages = np.pad(pages, (0, pad), constant_values=mgr.trash)
                 self.cache = _scatter_prefill(
-                    self.cache, scratch, jnp.asarray(flats, jnp.int32),
+                    self.cache, scratch, jnp.asarray(pages, jnp.int32),
                     jnp.asarray(rows, jnp.int32),
-                    jnp.asarray(cols, jnp.int32))
+                    jnp.asarray(blocks, jnp.int32))
                 for s in full:
                     self._set_slot_len(s, lens[s])
                     out[s] = (logits[s, lens[s] - 1], hidden[s, lens[s] - 1])
@@ -432,13 +445,12 @@ class DecodeEngine:
                 toks = np.zeros((self.batch, width), np.int32)
                 for s in hits:
                     toks[s, :suf[s]] = tok_host[s][plans[s].cached_len:]
-                logits, new_cache, hidden = _decode_paged_fn(
+                logits, self.cache, hidden = _decode_paged_fn(
                     self.params, self.cfg, jnp.asarray(toks), self.cache,
                     self.slot_lens, self._device_tables(), self.use_kernel)
                 # suffix KV is committed; rows outside the hit group wrote
                 # past their own committed length (or into the trash
                 # page), which no mask ever reads back
-                self.cache = new_cache
                 for s in hits:
                     self._set_slot_len(s, lens[s])
                     out[s] = (logits[s, suf[s] - 1], hidden[s, suf[s] - 1])
@@ -460,13 +472,21 @@ class DecodeEngine:
         With ``use_kernel=True`` the per-slot lengths ride the ragged
         Pallas decode-attention kernel's scalar-prefetch lane — one
         quantized launch for the whole mixed-length batch (on a paged
-        engine, with the block tables as a second prefetch operand)."""
+        engine, with the block tables and the layer as further prefetch
+        operands).
+
+        A paged engine donates its pool to the forward, which writes the
+        new positions in place, and adopts the returned pool at once:
+        ``self.cache`` IS ``new_cache`` on return, and any reference to
+        the pool from before the call is dead.  That is safe without a
+        commit for the reason ``commit_slots`` gives: the forward wrote
+        only past each row's committed length, or to the trash page."""
         with TraceAnnotation("repro.engine.decode"):
             if self.manager is not None:
-                return _decode_paged_fn(self.params, self.cfg, tokens,
-                                        self.cache, self.slot_lens,
-                                        self._device_tables(),
-                                        self.use_kernel)
+                logits, self.cache, hidden = _decode_paged_fn(
+                    self.params, self.cfg, tokens, self.cache,
+                    self.slot_lens, self._device_tables(), self.use_kernel)
+                return logits, self.cache, hidden
             return _decode_fn(self.params, self.cfg, tokens, self.cache,
                               self.slot_lens, self.use_kernel)
 
@@ -479,7 +499,8 @@ class DecodeEngine:
         (the adapters' accept counts always are): they also feed the
         ``slot_lens_host`` mirror the scheduler budgets against.
 
-        A paged engine adopts the new pool wholesale: the forward's
+        A paged engine adopts the new pool wholesale (``decode_slots``
+        already did, so it is the same buffer): the forward's
         writes only ever touch pages the writing slot exclusively owns
         (COW guarantees refcount-1 at write time) or the trash page, and
         rows that advanced 0 only wrote past their committed length —

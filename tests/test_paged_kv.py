@@ -19,9 +19,11 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs import get_config
+from repro.core.arch import AttentionSpec
 from repro.kernels.decode_attention.ops import decode_attention_paged
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.models import init_model
+from repro.models.attention import gqa_decode_paged, init_attention
 from repro.serving import (BlockManager, DecodeEngine, PagedKVConfig,
                            ServingLoop, init_mtp_heads)
 
@@ -105,6 +107,57 @@ def test_paged_matches_dense_mla(model):
                       paged=PagedKVConfig(block_size=16))
     for rid in dense:
         assert np.array_equal(dense[rid], paged[rid])
+
+
+def _live_kv(eng, slot, length):
+    """Slot ``slot``'s first ``length`` committed K and V positions, per
+    layer, as (layers, length, kv, dh): read from the dense cache, or
+    gathered from the paged pool (layers, kv, n_phys, dh, block) through
+    the slot's block table."""
+    seg = eng.cache["segments"][0]
+    if eng.manager is None:
+        return [np.asarray(seg[k][:, slot, :length]) for k in ("k", "v")]
+    bs = eng.manager.block_size
+    pages = eng.manager.tables[slot, :-(-length // bs)]
+    out = []
+    for k in ("k", "v"):
+        pool = np.asarray(seg[k])[:, :, pages]        # (L, kv, nb, dh, bs)
+        virt = pool.transpose(0, 2, 4, 1, 3)          # (L, nb, bs, kv, dh)
+        out.append(virt.reshape((pool.shape[0], -1) + virt.shape[3:])
+                   [:, :length])
+    return out
+
+
+@pytest.mark.parametrize("use_kernel,block", [(False, 16), (True, 128)])
+def test_in_place_paged_forward_matches_dense(model, use_kernel, block):
+    """The paged forward writes its pool in place and hands it back
+    (the engine adopts it before any commit): over two forwards with a
+    commit between them that advances some rows by 0, its logits and the
+    pool's committed positions equal the dense slotted engine's."""
+    cfg, params = model
+    rng = np.random.default_rng(17)
+    prompts = {s: p for s, p in enumerate(_prompts(cfg, 3, seed=17,
+                                                   lo=12, hi=40))}
+    engines = [DecodeEngine(cfg, params, batch=3, max_len=MAX_LEN,
+                            use_kernel=use_kernel, paged=paged)
+               for paged in (None, PagedKVConfig(block_size=block))]
+    for eng in engines:
+        eng.prefill_slots(prompts)
+    for advances in ([3, 0, 2], [1, 3, 0]):
+        toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (3, 3)),
+                           jnp.int32)
+        logits = []
+        for eng in engines:
+            lg, new_cache, _ = eng.decode_slots(toks)
+            eng.commit_slots(new_cache, np.asarray(advances))
+            logits.append(np.asarray(lg.astype(jnp.float32)))
+        np.testing.assert_array_equal(logits[0], logits[1])
+    dense, paged = engines
+    assert np.array_equal(dense.slot_lens_host, paged.slot_lens_host)
+    for s, length in enumerate(paged.slot_lens_host):
+        for want, got in zip(_live_kv(dense, s, int(length)),
+                             _live_kv(paged, s, int(length))):
+            np.testing.assert_array_equal(want, got)
 
 
 def test_paged_small_pool_backpressure(model):
@@ -357,10 +410,12 @@ def test_refcount_sharing_and_eviction():
 # ===========================================================================
 
 
-def _pool_from_dense(k_dense, v_dense, lens, n, bs, layout, seed=0):
-    """Pack a dense (b, s, kv, dh) cache into a pool under ``layout``:
+def _pool_from_dense(k_dense, v_dense, lens, n, bs, layout, layers=3,
+                     layer=1, seed=0):
+    """Pack a dense (b, s, kv, dh) cache into layer ``layer`` of a
+    stacked (layers, kv, n_phys, dh, bs) pool under ``layout``:
     'fragmented' (random pages), 'reversed' (descending pages),
-    'identity' (pages in order)."""
+    'identity' (pages in order).  Every other layer holds noise."""
     b, s, kv, dh = k_dense.shape
     max_blocks = s // bs
     rng = np.random.default_rng(seed)
@@ -375,16 +430,17 @@ def _pool_from_dense(k_dense, v_dense, lens, n, bs, layout, seed=0):
         order = order[::-1]
     tables = np.full((b, max_blocks), n_phys - 1, np.int32)
     k_pool = np.asarray(
-        rng.standard_normal((n_phys, bs, kv, dh)), np.float32)
+        rng.standard_normal((layers, kv, n_phys, dh, bs)), np.float32)
     v_pool = np.asarray(
-        rng.standard_normal((n_phys, bs, kv, dh)), np.float32)
+        rng.standard_normal((layers, kv, n_phys, dh, bs)), np.float32)
     pi = 0
     for bi in range(b):
         for j in range(need[bi]):
             p = int(order[pi]); pi += 1
             tables[bi, j] = p
-            k_pool[p] = np.asarray(k_dense[bi, j * bs:(j + 1) * bs])
-            v_pool[p] = np.asarray(v_dense[bi, j * bs:(j + 1) * bs])
+            for pool, dense in ((k_pool, k_dense), (v_pool, v_dense)):
+                page = np.asarray(dense[bi, j * bs:(j + 1) * bs])
+                pool[layer, :, p] = page.transpose(1, 2, 0)
     return jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(tables)
 
 
@@ -393,7 +449,8 @@ def _pool_from_dense(k_dense, v_dense, lens, n, bs, layout, seed=0):
 def test_paged_kernel_adversarial_layouts(layout, window):
     """Kernel-vs-oracle parity under hostile tables: scattered and
     reversed physical pages, a len-0 row, a single-block slot, and a
-    full-cache row — junk in unattached pages must never leak through."""
+    full-cache row — junk in unattached pages, and in the other layers
+    of the stacked pool, must never leak through."""
     rng = np.random.default_rng(1)
     b, n, h, kv, dh = 4, 4, 8, 2, 64
     bs, s = 16, 96
@@ -404,11 +461,43 @@ def test_paged_kernel_adversarial_layouts(layout, window):
     k_pool, v_pool, tables = _pool_from_dense(k_dense, v_dense, lens, n,
                                               bs, layout)
     out = decode_attention_paged(q, k_pool, v_pool, jnp.asarray(lens),
-                                 tables, window=window)
+                                 tables, 1, window=window)
     ref = decode_attention_ref(q, k_dense, v_dense, jnp.asarray(lens),
                                window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("n", [1, 3, 20])
+def test_paged_kv_write_kernel_matches_xla_write(n):
+    """The kernel path's write (``paged_kv_write``, a page at a time)
+    leaves the stacked pool exactly as the XLA path's indexed write: in
+    layer 1 of 3, for rows whose new positions start a page, straddle
+    pages (n = 20 spans three), run past the block table into the trash
+    page, or sit in a row whose table is all trash."""
+    rng = np.random.default_rng(n)
+    a = AttentionSpec(kind="gqa", n_heads=4, n_kv_heads=2, head_dim=16)
+    b, bs, max_blocks, d = 4, 16, 4, 32
+    n_phys = b * max_blocks + 1
+    params = init_attention(jax.random.PRNGKey(n), d, a, jnp.float32)
+    pool = {k: jnp.asarray(rng.standard_normal(
+        (3, a.n_kv_heads, n_phys, a.head_dim, bs)), jnp.float32)
+        for k in ("k", "v")}
+    tables = rng.permutation(n_phys - 1)[:b * max_blocks].reshape(
+        b, max_blocks).astype(np.int32)
+    tables[3] = n_phys - 1                       # an inactive row
+    offsets = jnp.asarray([0, 14, 60, 5], jnp.int32)
+    x = jnp.asarray(rng.standard_normal((b, n, d)), jnp.float32)
+    outs = [gqa_decode_paged(params, a, x, dict(pool), offsets,
+                             jnp.asarray(tables), 1, 10000.0,
+                             use_kernel=use_kernel)
+            for use_kernel in (True, False)]
+    for k in ("k", "v"):
+        got, want = (np.asarray(o[1][k])[:, :, :-1] for o in outs)
+        np.testing.assert_array_equal(got, want)    # trash page aside
+    np.testing.assert_allclose(np.asarray(outs[0][0][:3]),
+                               np.asarray(outs[1][0][:3]),
+                               atol=2e-4, rtol=2e-4)
 
 
 # ===========================================================================

@@ -11,7 +11,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import init_model
 from repro.serving import (DecodeEngine, DiffusionBlockDecoder, MTPDecoder,
-                           ServingLoop, init_mtp_heads)
+                           PagedKVConfig, ServingLoop, init_mtp_heads)
 from repro.serving.diffusion import refine_block
 from repro.serving.engine import _prefill_fn
 
@@ -263,3 +263,40 @@ def test_commit_slots_row_mask_on_device(dense_setup):
     for b, a in zip(before, after):
         # row 1 untouched everywhere; row 0 advanced
         assert np.array_equal(b[:, 1], a[:, 1])
+
+
+def test_paged_engine_never_reads_a_donated_pool(dense_setup):
+    """Every program that updates a paged pool takes it donated, so a
+    caller that kept the pool it passed would read a deleted buffer.
+    The engine adopts each returned pool at once: two decode forwards
+    without a commit, a prefix-hit admission with a copy-on-write, and
+    a full admission all run on one engine, and the forwards agree."""
+    cfg, params, _ = dense_setup
+    eng = DecodeEngine(cfg, params, batch=2, max_len=96,
+                       paged=PagedKVConfig(block_size=16))
+    rng = np.random.default_rng(6)
+    prompt = rng.integers(0, cfg.vocab_size, size=32)
+    eng.prefill_slots({0: prompt})                     # _scatter_prefill
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 3)), jnp.int32)
+    before = eng.cache
+    first, new_cache, _ = eng.decode_slots(toks)
+    assert new_cache is eng.cache
+    assert all(x.is_deleted() for x in jax.tree.leaves(before))
+    # again, uncommitted: the same positions are written, then read
+    second, new_cache, _ = eng.decode_slots(toks)
+    np.testing.assert_array_equal(np.asarray(first[0]),
+                                  np.asarray(second[0]))
+    eng.commit_slots(new_cache, np.zeros(2, np.int64))
+    # the same 32-token prompt: 31 positions hit, the divergence lies
+    # inside a shared block and is copied on write before the suffix
+    # forward (_copy_pool_blocks, then _decode_paged_fn)
+    eng.prefill_slots({1: prompt})
+    assert eng.prefill_log[-1]["cached_tokens"] == 31
+    assert eng.manager.cow_copies == 1
+    eng.release_slot(0)
+    eng.prefill_slots({0: rng.integers(0, cfg.vocab_size, size=20)})
+    logits, new_cache, _ = eng.decode_slots(toks)
+    eng.commit_slots(new_cache, np.full(2, 3, np.int64))
+    assert np.isfinite(np.asarray(logits.astype(jnp.float32))).all()
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng.cache))
+    eng.manager.check_invariants()

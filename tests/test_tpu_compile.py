@@ -11,7 +11,9 @@ entry compiled for a described chip cannot be read back without one.
 from __future__ import annotations
 
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,12 +87,13 @@ def test_paged_attention_compiles(one_chip, arch, block):
     b, s, n = 4, 4096, 8
     n_phys = b * s // block + 1
     args = (_spec(one_chip, (b, n, a.n_heads, a.head_dim), jnp.bfloat16),
-            _spec(one_chip, (n_phys, block, a.n_kv_heads, a.head_dim),
+            _spec(one_chip, (2, a.n_kv_heads, n_phys, a.head_dim, block),
                   jnp.bfloat16),
-            _spec(one_chip, (n_phys, block, a.n_kv_heads, a.head_dim),
+            _spec(one_chip, (2, a.n_kv_heads, n_phys, a.head_dim, block),
                   jnp.bfloat16),
             _spec(one_chip, (b,), jnp.int32),
-            _spec(one_chip, (b, s // block), jnp.int32))
+            _spec(one_chip, (b, s // block), jnp.int32),
+            _spec(one_chip, (), jnp.int32))
     jax.jit(lambda *x: decode_attention_paged(*x, interpret=False)
             ).lower(*args).compile()
 
@@ -156,3 +159,59 @@ def test_decode_step_fits_one_chip(one_chip, monkeypatch):
     assert total < HBM_BYTES, (mem.argument_size_in_bytes,
                                mem.output_size_in_bytes,
                                mem.temp_size_in_bytes)
+
+
+# the benchmark's paged engines: slots, decode width; 1024 positions in
+# pages of 128
+BENCH_ENGINES = {"stablelm_3b": (8, 3), "granite_moe_3b_a800m": (16, 2)}
+
+
+def _instructions(hlo: str):
+    """name -> (opcode, dims, operand names) of every array-valued
+    instruction in a compiled module, fused computations included."""
+    out = {}
+    for m in re.finditer(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                         r"([\w\-]+)\(([^)]*)", hlo, re.M):
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        out[m.group(1)] = (m.group(3), dims,
+                           re.findall(r"%([\w.\-]+)", m.group(4)))
+    return out
+
+
+@pytest.mark.parametrize("arch", sorted(BENCH_ENGINES))
+def test_paged_decode_keeps_pool_in_place(one_chip, monkeypatch, arch):
+    """The benchmark's decode forward at published widths takes its pool
+    in place: the output aliases the donated pool, and no copy,
+    dynamic-slice or dynamic-update-slice moves a whole layer's pages
+    (an in-place dynamic-update-slice moves only its update)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config(arch)
+    (slots, width), max_len, block = BENCH_ENGINES[arch], 1024, 128
+    n_phys = slots * max_len // block + 1
+    params = jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype),
+        jax.eval_shape(functools.partial(init_model, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: init_paged_cache(cfg, n_phys, block)))
+    compiled = _decode_paged_fn.lower(
+        params, cfg, _spec(one_chip, (slots, width), jnp.int32), cache,
+        _spec(one_chip, (slots,), jnp.int32),
+        _spec(one_chip, (slots, max_len // block), jnp.int32),
+        use_kernel=True).compile()
+    leaves = jax.tree.leaves(cache)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    layer = leaves[0].size // leaves[0].shape[0]
+
+    def pages(dims):
+        return (math.prod(dims) >= layer
+                and bool({n_phys, n_phys * block} & set(dims)))
+
+    insts = _instructions(compiled.as_text())
+    moved = [name for name, (op, dims, args) in insts.items()
+             if (op in ("copy", "dynamic-slice") and pages(dims))
+             or (op == "dynamic-update-slice"
+                 and pages(insts.get(args[1], ("", ()))[1]))]
+    assert moved == [], moved
