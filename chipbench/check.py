@@ -1,4 +1,9 @@
-"""Whether what the timed path served is correct.
+"""Whether what the timed path served is correct: the default check.
+
+A configuration whose module (``chipbench/configs/<config>.py``, see
+``run.Model``) defines ``gaps`` is checked by that instead, on the same
+sample.  Each entry of the sample is ``(prompt, served tokens, the
+program's Request)``; the default reads the first two.
 
 A sample of the requests the window finished, drawn from the seed with
 the longest among them, is run through the float32 reference over each
@@ -17,17 +22,16 @@ precision puts first.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence
 
 import numpy as np
 
 import reference
 
 
-def sample(finished: Sequence[Tuple[np.ndarray, np.ndarray]], seed: int,
-           n: int) -> List[int]:
-    """Indices of ``n`` finished (prompt, tokens) pairs: the one with the
-    most served tokens, and the rest drawn from the seed."""
+def sample(finished: Sequence[tuple], seed: int, n: int) -> List[int]:
+    """Indices of ``n`` finished requests (prompt, tokens, ...): the one
+    with the most served tokens, and the rest drawn from the seed."""
     if not finished:
         return []
     longest = max(range(len(finished)), key=lambda i: len(finished[i][1]))
@@ -36,25 +40,25 @@ def sample(finished: Sequence[Tuple[np.ndarray, np.ndarray]], seed: int,
     return [longest] + [int(i) for i in rest[:max(0, n - 1)]]
 
 
-def _inputs(pairs):
+def _inputs(finished):
     """Reference inputs and targets: prompt + tokens[:-1] predicts
     tokens[j] at position len(prompt) - 1 + j."""
     seqs, spans = [], []
-    for prompt, tokens in pairs:
+    for prompt, tokens, *_ in finished:
         seqs.append(np.concatenate([prompt, tokens[:-1]]).astype(np.int32))
         spans.append((len(prompt) - 1, len(tokens)))
     return seqs, spans
 
 
-def gaps(arch: Dict, seed: int, pairs, control: bool = False) -> Dict:
+def gaps(arch: Dict, seed: int, finished, control: bool = False) -> Dict:
     """Widest and mean gap of the served tokens, and with ``control``
     also of the float8 control's own first choices at the same
     positions."""
-    seqs, spans = _inputs(pairs)
+    seqs, spans = _inputs(finished)
     hidden = reference.final_hidden(arch, seed, seqs, "f32")
     w = reference.head_weight(arch, seed)
     ref_hidden, targets = [], []
-    for h, (start, n), (_, tokens) in zip(hidden, spans, pairs):
+    for h, (start, n), (_, tokens, *_) in zip(hidden, spans, finished):
         ref_hidden.append(h[start:start + n])
         targets.append(np.asarray(tokens, np.int32))
     ref_hidden = np.concatenate(ref_hidden)

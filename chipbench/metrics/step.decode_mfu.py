@@ -1,8 +1,8 @@
 """Model FLOP utilization of the whole serving step in the traced
-window: the FLOPs the tokens received there need (``counts.model_flops``,
-each at its own context length) over the traced window's seconds times
+window: the FLOPs the tokens received there need (the configuration's
+``model_flops``, by default ``counts.model_flops``, each at its own
+context length) over the traced window's seconds times
 the chip's bf16 peak."""
-import counts
 
 
 def read(ctx):
@@ -11,5 +11,5 @@ def read(ctx):
                 for c in e.contexts]
     if not contexts:
         return None
-    flops = counts.model_flops(ctx.arch, contexts)
+    flops = ctx.model.model_flops(ctx.arch, contexts)
     return 100.0 * flops / (ctx.trace.window_s * ctx.peaks.bf16_flops)

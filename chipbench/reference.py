@@ -14,6 +14,11 @@ Every product runs at ``Precision.HIGHEST``.  ``precision="fp8"`` rounds
 each operand of each product to float8 e4m3 first: the control that a
 lower precision than the configuration's has to fail.
 
+``quantize``, ``mm``, ``rms``, ``rope``, ``swiglu`` and ``head_stats``
+are the building blocks a configuration's own module
+(``chipbench/configs/<config>.py``) may build another block from, with
+its weights drawn by path through ``weights``.
+
 Nothing here imports the program.
 """
 from __future__ import annotations
@@ -31,7 +36,9 @@ HI = jax.lax.Precision.HIGHEST
 BF16 = jnp.bfloat16
 
 
-def _q(x, precision: str):
+def quantize(x, precision: str):
+    """``x`` as an operand at ``precision``: unchanged for "f32",
+    rounded to float8 e4m3 (and widened back) for "fp8"."""
     if precision == "fp8":
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
     if precision != "f32":
@@ -39,8 +46,10 @@ def _q(x, precision: str):
     return x
 
 
-def _mm(a, b, precision: str):
-    return jnp.matmul(_q(a, precision), _q(b, precision), precision=HI)
+def mm(a, b, precision: str):
+    """A product whose operands are first taken to ``precision``."""
+    return jnp.matmul(quantize(a, precision), quantize(b, precision),
+                      precision=HI)
 
 
 def layer_shapes(arch: Dict) -> Dict[str, tuple]:
@@ -80,12 +89,12 @@ def _freeze(arch: Dict):
                          else v) for k, v in arch.items()))
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (n, L, heads, dh) at positions 0..L-1; rotates halves."""
     half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
@@ -98,33 +107,35 @@ def _rope(x, theta):
 def _attention(w, x, arch, precision):
     n, length, _ = x.shape
     h, kv, dh = arch["n_heads"], arch["n_kv_heads"], arch["head_dim"]
-    q = _mm(x, w["attn/wq"], precision).reshape(n, length, h, dh)
-    k = _mm(x, w["attn/wk"], precision).reshape(n, length, kv, dh)
-    v = _mm(x, w["attn/wv"], precision).reshape(n, length, kv, dh)
-    q, k = _rope(q, arch["rope_theta"]), _rope(k, arch["rope_theta"])
+    q = mm(x, w["attn/wq"], precision).reshape(n, length, h, dh)
+    k = mm(x, w["attn/wk"], precision).reshape(n, length, kv, dh)
+    v = mm(x, w["attn/wv"], precision).reshape(n, length, kv, dh)
+    q, k = rope(q, arch["rope_theta"]), rope(k, arch["rope_theta"])
     q = q.reshape(n, length, kv, h // kv, dh)
-    s = jnp.einsum("nqkgd,nskd->nkgqs", _q(q, precision), _q(k, precision),
-                   precision=HI) / np.sqrt(dh)
+    s = jnp.einsum("nqkgd,nskd->nkgqs", quantize(q, precision),
+                   quantize(k, precision), precision=HI) / np.sqrt(dh)
     causal = jnp.tril(jnp.ones((length, length), bool))
     p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-    ctx = jnp.einsum("nkgqs,nskd->nqkgd", _q(p, precision), _q(v, precision),
+    ctx = jnp.einsum("nkgqs,nskd->nqkgd", quantize(p, precision),
+                     quantize(v, precision),
                      precision=HI).reshape(n, length, h * dh)
-    return _mm(ctx, w["attn/wo"], precision)
+    return mm(ctx, w["attn/wo"], precision)
 
 
-def _swiglu(x, gate, up, down, precision):
-    return _mm(jax.nn.silu(_mm(x, gate, precision)) * _mm(x, up, precision),
+def swiglu(x, gate, up, down, precision):
+    """down(silu(x @ gate) * (x @ up))."""
+    return mm(jax.nn.silu(mm(x, gate, precision)) * mm(x, up, precision),
                down, precision)
 
 
 def _ffn(w, x, arch, precision):
     f = arch["ffn"]
     if f["kind"] != "moe":
-        return _swiglu(x, w["ffn/gate"], w["ffn/up"], w["ffn/down"],
+        return swiglu(x, w["ffn/gate"], w["ffn/up"], w["ffn/down"],
                        precision)
     shape = x.shape
     t = x.reshape(-1, shape[-1])
-    logits = _mm(t, w["ffn/router"], precision)
+    logits = mm(t, w["ffn/router"], precision)
     top, idx = jax.lax.top_k(logits, f["top_k"])
     gates = jax.nn.softmax(top, axis=-1)
     combine = jnp.zeros(logits.shape).at[
@@ -132,7 +143,7 @@ def _ffn(w, x, arch, precision):
 
     def expert(acc, inp):
         gate, up, down, c = inp
-        return acc + c[:, None] * _swiglu(t, gate, up, down, precision), None
+        return acc + c[:, None] * swiglu(t, gate, up, down, precision), None
 
     out, _ = jax.lax.scan(expert, jnp.zeros_like(t),
                           (w["ffn/w_gate"], w["ffn/w_up"], w["ffn/w_down"],
@@ -145,8 +156,8 @@ def _layer(w, x, arch_key, precision):
     arch = dict(arch_key)
     arch["ffn"] = dict(arch["ffn"])
     eps = arch["norm_eps"]
-    x = x + _attention(w, _rms(x, w["ln1/scale"], eps), arch, precision)
-    return x + _ffn(w, _rms(x, w["ln2/scale"], eps), arch, precision)
+    x = x + _attention(w, rms(x, w["ln1/scale"], eps), arch, precision)
+    return x + _ffn(w, rms(x, w["ln2/scale"], eps), arch, precision)
 
 
 def head_weight(arch: Dict, seed: int):
@@ -186,7 +197,7 @@ def final_hidden(arch: Dict, seed: int, seqs: Sequence[np.ndarray],
             del w
     out = []
     for start, x in zip(range(0, len(seqs), rows), groups):
-        x = np.asarray(_rms(x, final, arch["norm_eps"]))
+        x = np.asarray(rms(x, final, arch["norm_eps"]))
         for i, s in enumerate(seqs[start:start + rows]):
             out.append(x[i, :len(s)])
     return out
@@ -195,7 +206,7 @@ def final_hidden(arch: Dict, seed: int, seqs: Sequence[np.ndarray],
 @functools.partial(jax.jit, static_argnames=("precision",))
 def _head_stats(hidden, w, tokens, precision):
     """Per position: the best logit, the logit of ``tokens``, the argmax."""
-    logits = _mm(hidden, w, precision)
+    logits = mm(hidden, w, precision)
     best = jnp.max(logits, axis=-1)
     got = jnp.take_along_axis(logits, tokens[:, None], axis=-1)[:, 0]
     return best, got, jnp.argmax(logits, axis=-1).astype(jnp.int32)

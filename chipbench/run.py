@@ -6,7 +6,11 @@
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a model
 configuration (``chipbench/configs/<config>.json``) under a traffic mix
-(``chipbench/traffic/<traffic>.json``).  One process per run:
+(``chipbench/traffic/<traffic>.json``).  A configuration may bring a
+module beside its file, ``chipbench/configs/<config>.py``, with its own
+``arch_of``, ``program_config``, ``gaps`` and ``model_flops``
+(``Model``); each it leaves out is the default every other configuration
+uses.  One process per run:
 
  1. compile cache at ``.jax_cache/`` in the checkout, whatever
     ``JAX_COMPILATION_CACHE_DIR`` says, so that two checkouts never share
@@ -18,7 +22,8 @@ configuration (``chipbench/configs/<config>.json``) under a traffic mix
  5. the measured window, on the host's clock, then a bounded drain of
     the requests due in it;
  6. the program's state is freed and a sample of the finished requests
-    is checked against the float32 reference (``check.py``).
+    is checked against the float32 reference (the configuration's
+    ``gaps``, by default ``check.gaps``).
 
 ``setup_s`` runs from process start to the start of the lead-in.  With
 ``--trace 1`` the last ``trace_seconds`` of the window run under the
@@ -46,7 +51,7 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 from dataclasses import dataclass, field  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Callable, Dict, List, Optional  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -79,9 +84,11 @@ def load_cell(name: str, root: Path = ROOT) -> Dict:
         raise Refused(f"no workload {name!r} in BENCHMARK.json")
     cell = cells[name]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    cfg_file = root / cfg_entry["file"]
     return {
         "cell": cell,
-        "config": _json(root / cfg_entry["file"]),
+        "config": _json(cfg_file),
+        "module": cfg_file.with_suffix(".py"),
         "mix": _json(root / "chipbench" / "traffic"
                      / f"{cell['traffic']}.json"),
         "end_to_end": [m for m in bench["end_to_end"]
@@ -143,6 +150,48 @@ def program_config(config: Dict):
                                 top_k=f.get("top_k", 0)))
 
 
+def load_module(path: Path, name: str):
+    """The Python file ``path``, imported under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@dataclass(frozen=True)
+class Model:
+    """How a configuration's model is read, built, checked and counted.
+
+    ``arch_of(config)``: the architecture numbers, with at least the
+    keys the readers use (``n_layers``, ``d_model``, ``n_heads``,
+    ``n_kv_heads``, ``head_dim``, ``vocab_size``, ``ffn``).
+    ``program_config(config)``: the program's ``ArchConfig``.
+    ``gaps(arch, seed, finished, control)``: the numbers ``correct``
+    compares, as ``check.gaps`` returns them, from ``finished``
+    entries ``(prompt, served tokens, the program's Request)``.
+    ``model_flops(arch, contexts)``: the FLOPs of tokens received at
+    those context lengths."""
+    arch_of: Callable
+    program_config: Callable
+    gaps: Callable
+    model_flops: Callable
+
+
+def model_of(cell: Dict) -> Model:
+    """The functions of the configuration's module (``cell["module"]``,
+    beside its file) where it has one, and the defaults for the rest."""
+    import check
+    import counts
+    fns = {"arch_of": arch_of, "program_config": program_config,
+           "gaps": check.gaps, "model_flops": counts.model_flops}
+    path = cell["module"]
+    if path.is_file():
+        mod = load_module(path, "chipbench_config_"
+                          + path.stem.replace(".", "_"))
+        fns.update({k: getattr(mod, k) for k in fns if hasattr(mod, k)})
+    return Model(**fns)
+
+
 def enable_cache(root: Path = ROOT) -> None:
     import jax
     jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
@@ -199,12 +248,12 @@ class CompileClock(logging.Handler):
         jax.monitoring.unregister_event_duration_listener(self)
 
 
-def build_engine(config: Dict, seed: int):
+def build_engine(config: Dict, seed: int, model: Model):
     import jax
     from repro.models import init_model
     from repro.serving import DecodeEngine, PagedKVConfig
     import weights
-    cfg = program_config(config)
+    cfg = model.program_config(config)
     shapes = jax.eval_shape(lambda k: init_model(k, cfg),
                             jax.random.PRNGKey(0))
     params = jax.block_until_ready(weights.build(shapes, seed))
@@ -550,12 +599,8 @@ def end_to_end(run: Run, seconds: float, setup_s: float) -> Dict[str, float]:
 
 
 def load_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(HERE / "metrics" / f"{name}.py",
+                       "chipbench_metric_" + name.replace(".", "_")).read
 
 
 @dataclass
@@ -566,15 +611,20 @@ class Context:
     config: Dict
     peaks: object
     trace: object        # trace_reduce.Reduced, or None
+    model: Model
 
 
-def finished_pairs(run: Run, upto: float):
-    """(prompt, served tokens) of requests finished by ``upto``."""
+def finished_requests(run: Run, upto: float):
+    """(prompt, served tokens, the program's Request) of requests
+    finished by ``upto``.  The Request is the program's own record of
+    how it served them; its only device array is the MTP adapter's
+    ``hidden``, a row of its own, so it keeps none of the program's
+    state alive."""
     out = []
     for r in run.recs.values():
         if r.n == r.max_tokens and r.last <= upto:
             out.append((np.asarray(r.req.prompt, np.int64),
-                        np.asarray(r.req.tokens(), np.int64)))
+                        np.asarray(r.req.tokens(), np.int64), r.req))
     return out
 
 
@@ -600,8 +650,9 @@ def run_cell(args, root: Path = ROOT, require_tpu: bool = True,
         d = jax.devices()[0]
         device = {"platform": d.platform, "kind": d.device_kind, "count": 1}
         pk = peaks.TPU_V5E
-    arch = arch_of(config)
-    engine = build_engine(config, args.seed)
+    model = model_of(cell)
+    arch = model.arch_of(config)
+    engine = build_engine(config, args.seed, model)
     warm = warm_up(engine, mix, config, args.seed)
     print(f"warm-up: decode widths {warm['widths']}, "
           f"{warm['prefill_groups']} prefill groups")
@@ -631,14 +682,14 @@ def run_cell(args, root: Path = ROOT, require_tpu: bool = True,
             # every request due in the window is followed to its end
             due = run.due_in_window()
             failed = [r for r in due if r.n < r.max_tokens]
-            pairs = finished_pairs(run, math.inf)
+            done = finished_requests(run, math.inf)
             attempted = len(due)
         else:
             # the window's work is its tokens: requests in flight at the
             # close are left there, not failed
             failed = []
-            pairs = finished_pairs(run, run.w1)
-            attempted = len(pairs)
+            done = finished_requests(run, run.w1)
+            attempted = len(done)
         reduced = None
         if trace_dir is not None:
             reduced = trace_reduce.reduce_file(
@@ -651,11 +702,11 @@ def run_cell(args, root: Path = ROOT, require_tpu: bool = True,
     # free the program's state before the reference runs
     del driver, loop, engine
     gc.collect()
-    picks = check.sample(pairs, args.seed, int(mix["check_requests"]))
-    chosen = [pairs[i] for i in picks]
+    picks = check.sample(done, args.seed, int(mix["check_requests"]))
+    chosen = [done[i] for i in picks]
     got = {}
     if chosen:
-        got = check.gaps(arch, args.seed, chosen, control=control)
+        got = model.gaps(arch, args.seed, chosen, control=control)
         print(f"check: {len(chosen)} requests, {got['positions']} served "
               f"tokens, max_logit_gap={got['max_logit_gap']!r} "
               f"mean_logit_gap={got['mean_logit_gap']!r}")
@@ -668,7 +719,7 @@ def run_cell(args, root: Path = ROOT, require_tpu: bool = True,
               "failed": len(failed), "metrics": {}, "device": dict(device)}
     result["device"]["memory_peak_bytes"] = peak
     if args.trace:
-        ctx = Context(run, arch, config, pk, reduced)
+        ctx = Context(run, arch, config, pk, reduced, model)
         for m in cell["per_layer"]:
             v = load_reader(m["name"])(ctx)
             if v is not None:

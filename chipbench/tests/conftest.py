@@ -21,12 +21,16 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def make_root(tmp: Path, config: str, mix: str, cell: str = "tiny.cell"):
-    """A checkout holding one toy cell: ``config`` and ``mix`` from
-    ``testdata``, the program through a link to ``src``."""
+    """A checkout holding one toy cell: ``config`` (with its module,
+    where ``testdata`` has one) and ``mix`` from ``testdata``, the
+    program through a link to ``src``."""
     (tmp / "chipbench" / "traffic").mkdir(parents=True)
-    (tmp / "chipbench" / "configs").mkdir(parents=True)
-    shutil.copy(BENCH / "testdata" / f"{config}.json",
-                tmp / "chipbench" / "configs" / f"{config}.json")
+    configs = tmp / "chipbench" / "configs"
+    configs.mkdir(parents=True)
+    shutil.copy(BENCH / "testdata" / f"{config}.json", configs)
+    module = BENCH / "testdata" / f"{config}.py"
+    if module.is_file():
+        shutil.copy(module, configs)
     shutil.copy(BENCH / "testdata" / f"{mix}.json",
                 tmp / "chipbench" / "traffic" / f"{mix}.json")
     (tmp / "src").symlink_to(REPO / "src")

@@ -19,6 +19,8 @@ from conftest import BENCH, REPO
 
 SEED = 2**33 + 11
 
+run.import_program()
+
 
 def _run(root, seconds=2.0, seed=SEED):
     args = argparse.Namespace(workload="tiny.cell", seed=seed,
@@ -44,7 +46,7 @@ def test_trace_covers_the_last_seconds_of_the_window(tiny_root, tmp_path):
     cell = run.load_cell("tiny.cell", root)
     config, mix = cell["config"], cell["mix"]
     run.import_program(root)
-    engine = run.build_engine(config, SEED)
+    engine = run.build_engine(config, SEED, run.model_of(cell))
     run.warm_up(engine, mix, config, SEED)
     requests, _ = run.plan(mix, config, SEED, 2.0, engine.cfg.vocab_size)
     driver = run.Driver(run.new_loop(engine, mix, config), mix, requests, 0)
@@ -62,12 +64,19 @@ def _alter_tokens(monkeypatch):
 
 
 def _stale_state(monkeypatch):
-    """A step that returns its state unchanged: the new KV is dropped."""
+    """A step that returns its state unchanged: the new KV is dropped.
+    The forward donates the pool and adopts the one it returns, so the
+    fault keeps a copy of the pool from before the forward and hands
+    that back in its place, for the commit to adopt."""
     from repro.serving.engine import DecodeEngine
-    real = DecodeEngine.commit_slots
-    monkeypatch.setattr(DecodeEngine, "commit_slots",
-                        lambda self, new_cache, adv: real(self, self.cache,
-                                                          adv))
+    real = DecodeEngine.decode_slots
+
+    def decode_slots(self, tokens):
+        before = jax.tree.map(jnp.copy, self.cache)
+        logits, _, hidden = real(self, tokens)
+        self.cache = before
+        return logits, before, hidden
+    monkeypatch.setattr(DecodeEngine, "decode_slots", decode_slots)
 
 
 def _half_batch(monkeypatch):
