@@ -25,6 +25,15 @@ class AttentionSpec:
                 KV cache stores a compressed latent per token.
       - "swa":  sliding-window GQA (Mixtral): effective cache length is
                 min(L, window).
+
+    ``qk_norm_eps`` (GQA/SWA): per-head RMSNorm of q and k over
+    ``head_dim``, with its epsilon, before rotary (Qwen3's block).
+
+    ``diffusion_block`` (GQA/SWA): block-causal attention of a block
+    diffusion model generating ``diffusion_block`` positions at a time,
+    in blocks aligned to absolute positions: position p sees every
+    position below ``(p // diffusion_block + 1) * diffusion_block`` —
+    every earlier block and its whole own block.  None: causal.
     """
 
     kind: str = "gqa"                    # gqa | mla | swa
@@ -32,6 +41,8 @@ class AttentionSpec:
     n_kv_heads: int = 32
     head_dim: int = 128
     window: Optional[int] = None         # swa only
+    qk_norm_eps: Optional[float] = None
+    diffusion_block: Optional[int] = None
     # MLA-only geometry (MiniCPM3-4B defaults).
     q_lora_rank: int = 768
     kv_lora_rank: int = 256
@@ -64,12 +75,23 @@ class AttentionSpec:
 
 @dataclass(frozen=True)
 class FFNSpec:
+    """``n_experts`` is the router's width: top-k is taken over all of
+    them.  A chip of an expert-parallel deployment holds only experts
+    ``first_held .. first_held + held - 1`` and computes their part of
+    the layer (``held`` 0: every expert is held)."""
+
     kind: str = "dense"                  # dense | moe | none
     d_ff: int = 0                        # dense intermediate (or expert d_ff for moe)
     activation: str = "swiglu"           # swiglu | gelu
     n_experts: int = 0                   # moe only
     top_k: int = 0                       # moe only
     n_shared_experts: int = 0            # moe: always-on shared experts
+    held: int = 0                        # moe: experts held here (0: all)
+    first_held: int = 0                  # moe: the first of them
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -203,7 +225,8 @@ class ArchConfig:
         q = d * a.n_heads * a.head_dim
         kv = 2 * d * a.n_kv_heads * a.head_dim
         o = a.n_heads * a.head_dim * d
-        return q + kv + o
+        norms = 2 * a.head_dim if a.qk_norm_eps is not None else 0
+        return q + kv + o + norms
 
     def _ffn_params(self, active_only: bool) -> int:
         d = self.d_model
@@ -214,8 +237,13 @@ class ArchConfig:
         per_expert = mats * d * f.d_ff
         if f.kind == "dense":
             return per_expert
-        n_exp = f.top_k if active_only else f.n_experts
-        n = n_exp * per_expert + f.n_shared_experts * per_expert
+        # experts held here; a token's active experts fall on them in
+        # proportion
+        if active_only:
+            n = f.top_k * f.n_held * per_expert // f.n_experts
+        else:
+            n = f.n_held * per_expert
+        n += f.n_shared_experts * per_expert
         n += d * f.n_experts  # router
         return n
 

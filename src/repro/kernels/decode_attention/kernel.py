@@ -46,11 +46,24 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _visible_end(cache_len, q_end, n_logical: int, block: Optional[int]):
+    """Exclusive end of the kv positions the first ``q_end`` queries of a
+    row see: causal, their last position + 1; block-causal (``block``),
+    the end of the last one's block, but never past the row's written
+    positions (``cache_len + n_logical``).  A width of at most one
+    query tile gives ``cache_len + n_logical`` under either mask."""
+    end = cache_len + q_end
+    if block is None:
+        return end
+    return jnp.minimum(cache_len + n_logical,
+                       ((end - 1) // block + 1) * block)
+
+
 def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
                  m_ref, l_ref, acc_ref, *,
                  q_block: int, k_block: int, g: int, scale: float,
                  window: Optional[int], n_kv_tiles: int, n_logical: int,
-                 kv_lanes: bool = False):
+                 kv_lanes: bool = False, block: Optional[int] = None):
     ib = pl.program_id(0)
     iq = pl.program_id(2)
     ij = pl.program_id(3)
@@ -66,9 +79,12 @@ def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
     # --- per-row kv-tile bounds (the ragged fast path) ---------------------
     # Upper: this row's cache holds cache_len + n_logical committed/new
     # positions, and within this q tile nothing past the tile's last query
-    # (causal diagonal) is visible either; tiles at/after the smaller
-    # boundary hold nothing the mask would keep — skipping them is free.
-    row_kv_end = cache_len + jnp.minimum(n_logical, (iq + 1) * q_block)
+    # (causal diagonal; block-causal, its block's end) is visible either;
+    # tiles at/after the smaller boundary hold nothing the mask would
+    # keep — skipping them is free.
+    row_kv_end = _visible_end(cache_len,
+                              jnp.minimum(n_logical, (iq + 1) * q_block),
+                              n_logical, block)
     useful = ij * k_block < row_kv_end
     if window is not None:
         # Lower: the smallest q position in this q tile is
@@ -94,13 +110,17 @@ def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
             q, k, (((1,), (d_axis,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (rows, kb)
 
-        # --- causal / window / validity mask -------------------------------
+        # --- causal / block-causal / window / validity mask ----------------
         row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, k_block), 0)
         q_off = row_ids % q_block                            # row -> q index
         q_pos = cache_len + iq * q_block + q_off
         kv_pos = (ij * k_block
                   + jax.lax.broadcasted_iota(jnp.int32, (rows, k_block), 1))
-        mask = kv_pos <= q_pos
+        if block is None:
+            mask = kv_pos <= q_pos
+        else:
+            mask = ((kv_pos < (q_pos // block + 1) * block)
+                    & (kv_pos < cache_len + n_logical))
         if window is not None:
             mask &= kv_pos > (q_pos - window)
         scores = jnp.where(mask, scores, NEG_INF)
@@ -130,11 +150,13 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
                             k_block: int, scale: float,
                             window: Optional[int] = None,
                             n_logical: Optional[int] = None,
+                            block: Optional[int] = None,
                             interpret: bool = False):
     """q: (b, kv, g, n_pad, dh); k/v: (b, kv, s_pad, dh); cache_lens: (b,) i32.
 
     ``n_logical`` is the un-padded query count (defaults to n_pad): row b's
     filled kv length is cache_lens[b] + n_logical, the per-row tile bound.
+    ``block``: block-causal masking (``AttentionSpec.diffusion_block``).
     """
     b, kv, g, n_pad, dh = q.shape
     s_pad = k.shape[2]
@@ -145,7 +167,7 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
     n_log = n_pad if n_logical is None else n_logical
     kernel = functools.partial(
         _attn_kernel, q_block=q_block, k_block=k_block, g=g, scale=scale,
-        window=window, n_kv_tiles=n_kv_tiles, n_logical=n_log)
+        window=window, n_kv_tiles=n_kv_tiles, n_logical=n_log, block=block)
 
     def kv_index(ib, ik, iq, ij, lens_ref):
         # Clamp the kv block index to the row's useful-tile range (mirrors
@@ -155,9 +177,9 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
         # skip saves HBM traffic, not just MXU work.  The fetched-but-
         # skipped content is never read (the pl.when guard), so the clamp
         # target is free to choose.
-        last = jnp.maximum(
-            (lens_ref[ib] + jnp.minimum(n_log, (iq + 1) * q_block)
-             + k_block - 1) // k_block - 1, 0)
+        end = _visible_end(lens_ref[ib], jnp.minimum(n_log, (iq + 1) * q_block),
+                           n_log, block)
+        last = jnp.maximum((end + k_block - 1) // k_block - 1, 0)
         idx = jnp.minimum(ij, last)
         if window is not None:
             first = jnp.maximum(
@@ -195,6 +217,7 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
                                   scale: float,
                                   window: Optional[int] = None,
                                   n_logical: Optional[int] = None,
+                                  block: Optional[int] = None,
                                   interpret: bool = False):
     """Block-table-indexed variant: the KV cache is a GLOBAL paged pool.
 
@@ -229,7 +252,7 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
     kernel = functools.partial(
         _attn_kernel, q_block=q_block, k_block=block_size, g=g, scale=scale,
         window=window, n_kv_tiles=n_kv_tiles, n_logical=n_log,
-        kv_lanes=True)
+        kv_lanes=True, block=block)
 
     def paged_kernel(lens_ref, bt_ref, layer_ref, *refs, **kw):
         # the block tables and the layer only steer the index maps; the
@@ -243,9 +266,9 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
         # mapped through the row's block table: logical tile -> physical
         # page.  Entries inside the clamp range are always valid pages
         # (allocated, or the trash page for inactive rows).
-        last = jnp.maximum(
-            (lens_ref[ib] + jnp.minimum(n_log, (iq + 1) * q_block)
-             + block_size - 1) // block_size - 1, 0)
+        end = _visible_end(lens_ref[ib], jnp.minimum(n_log, (iq + 1) * q_block),
+                           n_log, block)
+        last = jnp.maximum((end + block_size - 1) // block_size - 1, 0)
         idx = jnp.minimum(ij, last)
         if window is not None:
             first = jnp.maximum(

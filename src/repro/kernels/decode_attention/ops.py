@@ -42,11 +42,12 @@ K_BLOCK = 128
 
 
 @functools.partial(jax.jit, static_argnames=("window", "q_block_override",
-                                             "k_block", "interpret"))
+                                             "k_block", "block", "interpret"))
 def decode_attention_ragged(q, k_cache, v_cache, cache_lens, *,
                             window: Optional[int] = None,
                             q_block_override: Optional[int] = None,
                             k_block: int = K_BLOCK,
+                            block: Optional[int] = None,
                             interpret: Optional[bool] = None):
     """q: (b, n, h, dh); k/v_cache: (b, s, kv, dh); cache_lens: (b,) i32.
 
@@ -80,15 +81,17 @@ def decode_attention_ragged(q, k_cache, v_cache, cache_lens, *,
 
     o = decode_attention_pallas(qk, kk, vk, lens, q_block=q_block,
                                 k_block=k_block, scale=scale, window=window,
-                                n_logical=n, interpret=interpret)
+                                n_logical=n, block=block,
+                                interpret=interpret)
     return o[:, :, :, :n].transpose(0, 3, 1, 2, 4).reshape(b, n, h, dh)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "q_block_override",
-                                             "interpret"))
+                                             "block", "interpret"))
 def decode_attention_paged(q, k_pool, v_pool, cache_lens, block_tables,
                            layer, *, window: Optional[int] = None,
                            q_block_override: Optional[int] = None,
+                           block: Optional[int] = None,
                            interpret: Optional[bool] = None):
     """Paged-pool kernel entry the scheduler's paged cache serves.
 
@@ -103,7 +106,9 @@ def decode_attention_paged(q, k_pool, v_pool, cache_lens, block_tables,
     Row b's N query positions sit at cache_lens[b] .. cache_lens[b]+N-1
     in LOGICAL positions; their K/V must already be written into the
     pool at the pages the table names.  The pool goes to the kernel
-    untouched: no slice, transpose or copy of it.  Returns (b, n, h, dh).
+    untouched: no slice, transpose or copy of it.  ``block`` makes the
+    mask block-causal (``AttentionSpec.diffusion_block``), reading no
+    position past the n written ones.  Returns (b, n, h, dh).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -123,7 +128,7 @@ def decode_attention_paged(q, k_pool, v_pool, cache_lens, block_tables,
 
     o = decode_attention_paged_pallas(qk, k_pool, v_pool, lens, bt, layer,
                                       q_block=q_block, scale=scale,
-                                      window=window, n_logical=n,
+                                      window=window, n_logical=n, block=block,
                                       interpret=interpret)
     return o[:, :, :, :n].transpose(0, 3, 1, 2, 4).reshape(b, n, h, dh)
 
@@ -177,6 +182,7 @@ def slack_report(n: int, cache_lens, s_max: int, *,
                  q_block: Optional[int] = None,
                  k_block: int = K_BLOCK,
                  window: Optional[int] = None,
+                 block: Optional[int] = None,
                  active=None) -> Dict[str, float]:
     """Model one ragged decode forward's physical work (per kv head).
 
@@ -185,6 +191,8 @@ def slack_report(n: int, cache_lens, s_max: int, *,
         ij*k_block < len_b + min(n, (iq+1)*q_block)              (upper)
         and, with a window, ij*k_block + k_block - 1 >=
             len_b + iq*q_block - window + 1                      (lower)
+    (block-causal, ``block``: the upper end is that of the last query's
+    block, capped at len_b + n — the same for n <= q_block).
 
     Args:
       n:          logical query positions per row this forward.
@@ -217,6 +225,8 @@ def slack_report(n: int, cache_lens, s_max: int, *,
     for bi in range(b):
         for iq in range(n_q_tiles):
             hi = lens[bi] + min(n, (iq + 1) * qb)        # kv end (exclusive)
+            if block is not None:
+                hi = min(lens[bi] + n, cdiv(int(hi), block) * block)
             tiles = min(n_kv_tiles, cdiv(int(hi), k_block))
             lo_tile = 0
             if window is not None:

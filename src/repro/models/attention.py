@@ -48,12 +48,16 @@ def init_attention(key, d_model: int, a: AttentionSpec, dtype=jnp.bfloat16) -> D
                            dtype=dtype),
             "wo": _init(ks[4], (a.n_heads * a.v_head_dim, d_model), dtype=dtype),
         }
-    return {
+    p = {
         "wq": _init(ks[0], (d_model, a.n_heads * a.head_dim), dtype=dtype),
         "wk": _init(ks[1], (d_model, a.n_kv_heads * a.head_dim), dtype=dtype),
         "wv": _init(ks[2], (d_model, a.n_kv_heads * a.head_dim), dtype=dtype),
         "wo": _init(ks[3], (a.n_heads * a.head_dim, d_model), dtype=dtype),
     }
+    if a.qk_norm_eps is not None:
+        p["q_norm"] = init_rmsnorm(a.head_dim, dtype)
+        p["k_norm"] = init_rmsnorm(a.head_dim, dtype)
+    return p
 
 
 def init_kv_cache(batch: int, max_len: int, a: AttentionSpec,
@@ -183,9 +187,15 @@ def _paged_gather(pool: Array, layer, block_tables: Array) -> Array:
 
 def _causal_mask(q_pos: Array, kv_pos: Array,
                  window: Optional[int] = None,
-                 kv_valid: Optional[Array] = None) -> Array:
-    """q_pos: (b,sq) kv_pos: (b,sk) -> (b,sq,sk) bool."""
-    m = kv_pos[:, None, :] <= q_pos[:, :, None]
+                 kv_valid: Optional[Array] = None,
+                 block: Optional[int] = None) -> Array:
+    """q_pos: (b,sq) kv_pos: (b,sk) -> (b,sq,sk) bool.  With ``block``
+    the mask is block-causal: q sees every kv below the end of its own
+    block (``AttentionSpec.diffusion_block``)."""
+    if block is None:
+        m = kv_pos[:, None, :] <= q_pos[:, :, None]
+    else:
+        m = kv_pos[:, None, :] < (q_pos[:, :, None] // block + 1) * block
     if window is not None:
         m &= kv_pos[:, None, :] > (q_pos[:, :, None] - window)
     if kv_valid is not None:
@@ -197,20 +207,45 @@ def _causal_mask(q_pos: Array, kv_pos: Array,
 # GQA / SWA
 # ===========================================================================
 
+def _qkv(params, a: AttentionSpec, x: Array, pos: Array, theta: float
+         ) -> Tuple[Array, Array, Array]:
+    """q (b,s,h,dh), k and v (b,s,kv,dh) of x at positions ``pos``: the
+    projections, the per-head q/k RMSNorm where the block has one, then
+    rotary on q and k."""
+    b, s, _ = x.shape
+    q = (x @ params["wq"]).reshape(b, s, a.n_heads, a.head_dim)
+    k = (x @ params["wk"]).reshape(b, s, a.n_kv_heads, a.head_dim)
+    v = (x @ params["wv"]).reshape(b, s, a.n_kv_heads, a.head_dim)
+    if a.qk_norm_eps is not None:
+        q = rmsnorm(params["q_norm"], q, a.qk_norm_eps)
+        k = rmsnorm(params["k_norm"], k, a.qk_norm_eps)
+    return apply_rope(q, pos, theta), apply_rope(k, pos, theta), v
+
+
+def _decode_mask(a: AttentionSpec, q_pos: Array, kv_pos: Array,
+                 written: Array) -> Array:
+    """The decode forwards' mask; ``written`` (b,) is each row's end of
+    written positions, past which a block-causal row must not read (its
+    block may reach beyond the positions this forward wrote)."""
+    window = a.window if a.kind == "swa" else None
+    if a.diffusion_block is None:
+        return _causal_mask(q_pos, kv_pos, window)
+    return _causal_mask(q_pos, kv_pos, window,
+                        kv_valid=kv_pos < written[:, None],
+                        block=a.diffusion_block)
+
+
 def gqa_full(params, a: AttentionSpec, x: Array, positions: Array,
              theta: float, build_cache: Optional[Dict] = None,
              cache_len: int = 0, causal: bool = True,
              ) -> Tuple[Array, Optional[Dict]]:
     """Self-attention over x (train / prefill).  Optionally fills a cache."""
     b, s, d = x.shape
-    q = (x @ params["wq"]).reshape(b, s, a.n_heads, a.head_dim)
-    k = (x @ params["wk"]).reshape(b, s, a.n_kv_heads, a.head_dim)
-    v = (x @ params["wv"]).reshape(b, s, a.n_kv_heads, a.head_dim)
-    q = apply_rope(q, positions, theta)
-    k = apply_rope(k, positions, theta)
+    q, k, v = _qkv(params, a, x, positions, theta)
     window = a.window if a.kind == "swa" else None
     if causal:
-        mask = _causal_mask(positions, positions, window)
+        mask = _causal_mask(positions, positions, window,
+                            block=a.diffusion_block)
     else:
         mask = jnp.ones((b, s, s), bool)
     scale = 1.0 / (a.head_dim ** 0.5)
@@ -240,11 +275,7 @@ def gqa_decode(params, a: AttentionSpec, x: Array, cache: Dict,
     per_row = jnp.ndim(cache_len) > 0
     offsets = _row_offsets(cache_len, b)
     q_pos = offsets[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]  # (b,n)
-    q = (x @ params["wq"]).reshape(b, n, a.n_heads, a.head_dim)
-    k = (x @ params["wk"]).reshape(b, n, a.n_kv_heads, a.head_dim)
-    v = (x @ params["wv"]).reshape(b, n, a.n_kv_heads, a.head_dim)
-    q = apply_rope(q, q_pos, theta)
-    k = apply_rope(k, q_pos, theta)
+    q, k, v = _qkv(params, a, x, q_pos, theta)
     if per_row:
         k_cache = _update_rows(cache["k"], k, offsets)
         v_cache = _update_rows(cache["v"], v, offsets)
@@ -264,9 +295,10 @@ def gqa_decode(params, a: AttentionSpec, x: Array, cache: Dict,
         # the scalar case is the same kernel with aligned rows.
         from repro.kernels.decode_attention.ops import decode_attention_ragged
         ctx = decode_attention_ragged(q, k_cache, v_cache, offsets,
-                                      window=window)
+                                      window=window,
+                                      block=a.diffusion_block)
     else:
-        mask = _causal_mask(q_pos, kv_pos, window)
+        mask = _decode_mask(a, q_pos, kv_pos, offsets + n)
         ctx = _gqa_core(q, k_cache, v_cache, mask, scale)
     out = ctx.reshape(b, n, -1) @ params["wo"]
     return out, {"k": k_cache, "v": v_cache}
@@ -291,11 +323,7 @@ def gqa_decode_paged(params, a: AttentionSpec, x: Array, pool: Dict,
     n_phys, bs = _pool_geometry(pool["k"])
     offsets = _row_offsets(cache_len, b)
     q_pos = offsets[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
-    q = (x @ params["wq"]).reshape(b, n, a.n_heads, a.head_dim)
-    k = (x @ params["wk"]).reshape(b, n, a.n_kv_heads, a.head_dim)
-    v = (x @ params["wv"]).reshape(b, n, a.n_kv_heads, a.head_dim)
-    q = apply_rope(q, q_pos, theta)
-    k = apply_rope(k, q_pos, theta)
+    q, k, v = _qkv(params, a, x, q_pos, theta)
     bt = jnp.asarray(block_tables, jnp.int32)
     window = a.window if a.kind == "swa" else None
     scale = 1.0 / (a.head_dim ** 0.5)
@@ -320,7 +348,7 @@ def gqa_decode_paged(params, a: AttentionSpec, x: Array, pool: Dict,
                 k[:, c0:c0 + m], v[:, c0:c0 + m],  # analysis: allow-recompile-hazard
                 layer, pages, first % bs)
         ctx = decode_attention_paged(q, k_pool, v_pool, offsets, bt, layer,
-                                     window=window)
+                                     window=window, block=a.diffusion_block)
     else:
         page, off = _paged_slots(bt, q_pos, n_phys, bs)
         k_pool = _paged_write(pool["k"], layer, k, page, off)
@@ -330,7 +358,7 @@ def gqa_decode_paged(params, a: AttentionSpec, x: Array, pool: Dict,
         s_virt = k_virt.shape[1]
         kv_pos = jnp.broadcast_to(
             jnp.arange(s_virt, dtype=jnp.int32)[None, :], (b, s_virt))
-        mask = _causal_mask(q_pos, kv_pos, window)
+        mask = _decode_mask(a, q_pos, kv_pos, offsets + n)
         ctx = _gqa_core(q, k_virt, v_virt, mask, scale)
     out = ctx.reshape(b, n, -1) @ params["wo"]
     return out, {"k": k_pool, "v": v_pool}
@@ -351,11 +379,7 @@ def gqa_decode_ring(params, a: AttentionSpec, x: Array, cache: Dict,
     w_buf = cache["k"].shape[1]
     q_pos = cache_len + jnp.arange(n, dtype=jnp.int32)[None, :]
     q_pos = jnp.broadcast_to(q_pos, (b, n))
-    q = (x @ params["wq"]).reshape(b, n, a.n_heads, a.head_dim)
-    k = (x @ params["wk"]).reshape(b, n, a.n_kv_heads, a.head_dim)
-    v = (x @ params["wv"]).reshape(b, n, a.n_kv_heads, a.head_dim)
-    q = apply_rope(q, q_pos, theta)
-    k = apply_rope(k, q_pos, theta)
+    q, k, v = _qkv(params, a, x, q_pos, theta)
     slots = (cache_len + jnp.arange(n, dtype=jnp.int32)) % w_buf
     k_cache = cache["k"].at[:, slots].set(k)
     v_cache = cache["v"].at[:, slots].set(v)

@@ -13,6 +13,15 @@ combine):
 Controlled routing (paper App. C.3.1) is supported via
 ``routing_override`` so benchmarks can reproduce the load-balanced
 (round-robin, Eq. 25) and load-skewed patterns exactly.
+
+Expert share (``FFNSpec.held`` / ``first_held``): a chip of an
+expert-parallel deployment holds only some experts' weights.  The
+router still spans all ``n_experts`` and the top-k weights are
+normalized over all k picks; the layer then computes its held experts'
+part alone — token-expert pairs routed elsewhere are sorted past the
+held groups and carry zero weight in the combine.  Nothing stands in
+for the absent experts: summing every share's output gives the uncut
+layer.
 """
 from __future__ import annotations
 
@@ -29,9 +38,10 @@ Array = jax.Array
 
 def init_moe(key, d_model: int, f: FFNSpec, dtype=jnp.bfloat16) -> Dict:
     ks = jax.random.split(key, 5)
-    e, dff = f.n_experts, f.d_ff
+    e, dff = f.n_held, f.d_ff
     p = {
-        "router": _init(ks[0], (d_model, e), scale=0.02, dtype=jnp.float32),
+        "router": _init(ks[0], (d_model, f.n_experts), scale=0.02,
+                        dtype=jnp.float32),
         "w_up": _init(ks[1], (e, d_model, dff), dtype=dtype),
         "w_down": _init(ks[2], (e, dff, d_model), dtype=dtype),
     }
@@ -97,32 +107,51 @@ def moe_ffn(params, f: FFNSpec, x: Array,
         imp = jnp.mean(probs, axis=0)
         aux = e * jnp.sum(frac * imp)
 
-    # --- dispatch: sort token-expert pairs by expert ----------------------
-    flat_idx = top_idx.reshape(-1)                    # (T*k,)
-    flat_w = weights.reshape(-1)
-    order = jnp.argsort(flat_idx)                     # stable
-    token_of_pair = order // k
-    x_sorted = xt[token_of_pair]                      # (T*k, d)
-    group_sizes = jnp.bincount(flat_idx, length=e).astype(jnp.int32)
+    # the held experts' part of the layer: dispatch, grouped GEMM over the
+    # held groups, weighted combine
+    with jax.named_scope("experts"):
+        # --- dispatch: sort token-expert pairs by held expert -------------
+        # pairs routed to experts held elsewhere take key ``held``: they
+        # sort past every held group, and no group covers them
+        held = f.n_held
+        local = top_idx.reshape(-1) - f.first_held    # (T*k,)
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held)
+        flat_w = weights.reshape(-1)
+        order = jnp.argsort(key)                      # stable
+        token_of_pair = order // k
+        x_sorted = xt[token_of_pair]                  # (T*k, d)
+        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(
+            jnp.int32)
 
-    # --- expert FFN: grouped GEMM -----------------------------------------
-    if use_kernel:
-        from repro.kernels.moe_ffn.ops import grouped_ffn
-        h_out = grouped_ffn(x_sorted, params, group_sizes, f.activation,
-                            n_tokens=t)
-    else:
-        up = jax.lax.ragged_dot(x_sorted, params["w_up"], group_sizes)
-        if f.activation == "swiglu":
-            gate = jax.lax.ragged_dot(x_sorted, params["w_gate"], group_sizes)
-            h = (jax.nn.silu(gate.astype(jnp.float32))
-                 * up.astype(jnp.float32)).astype(x.dtype)
+        # --- expert FFN: grouped GEMM -------------------------------------
+        if use_kernel:
+            if held < e:
+                # the kernel gives every sorted row an expert: the pairs
+                # past the held groups would land in the last group's
+                raise ValueError("the grouped moe_ffn kernel serves layers "
+                                 "that hold every expert")
+            from repro.kernels.moe_ffn.ops import grouped_ffn
+            h_out = grouped_ffn(x_sorted, params, group_sizes, f.activation,
+                                n_tokens=t)
         else:
-            h = jax.nn.gelu(up.astype(jnp.float32)).astype(x.dtype)
-        h_out = jax.lax.ragged_dot(h, params["w_down"], group_sizes)
+            up = jax.lax.ragged_dot(x_sorted, params["w_up"], group_sizes)
+            if f.activation == "swiglu":
+                gate = jax.lax.ragged_dot(x_sorted, params["w_gate"],
+                                          group_sizes)
+                h = (jax.nn.silu(gate.astype(jnp.float32))
+                     * up.astype(jnp.float32)).astype(x.dtype)
+            else:
+                h = jax.nn.gelu(up.astype(jnp.float32)).astype(x.dtype)
+            h_out = jax.lax.ragged_dot(h, params["w_down"], group_sizes)
 
-    # --- combine: weighted scatter-add (eta = 2 accesses, Eq. 17) ---------
-    contrib = h_out.astype(jnp.float32) * flat_w[order][:, None]
-    out = jnp.zeros((t, d), jnp.float32).at[token_of_pair].add(contrib)
+        # --- combine: weighted scatter-add (eta = 2 accesses, Eq. 17) -----
+        # rows past the held groups hold whatever the GEMM left there:
+        # select, never multiply, them away
+        contrib = jnp.where(
+            is_held[order][:, None],
+            h_out.astype(jnp.float32) * flat_w[order][:, None], 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[token_of_pair].add(contrib)
     out = out.astype(x.dtype)
 
     if f.n_shared_experts:
@@ -130,3 +159,4 @@ def moe_ffn(params, f: FFNSpec, x: Array,
         out = out + (sh.astype(x.dtype) @ params["shared_down"])
 
     return out.reshape(orig_shape), aux
+

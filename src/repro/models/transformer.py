@@ -295,8 +295,13 @@ def forward(params, cfg: ArchConfig, inputs: Dict, *, mode: str = "train",
             cache: Optional[Dict] = None, cache_len=0,
             use_kernel: bool = False, routing_override=None,
             remat=False, swa_ring: bool = False, block_tables=None,
+            last: Optional[Array] = None,
             ) -> Tuple[Array, Optional[Dict], Array, Array]:
     """Returns (logits, new_cache, moe_aux_loss, hidden).
+
+    ``last`` (b,) i32 keeps one position of each row: logits and hidden
+    come back (b, 1, ...) at those positions, and the output head runs
+    on them alone (an admission needs only each prompt's last logits).
 
     ``block_tables`` (b, max_blocks) i32 switches decode-mode attention
     onto the PAGED cache path: ``cache`` must then be an
@@ -419,6 +424,8 @@ def forward(params, cfg: ArchConfig, inputs: Dict, *, mode: str = "train",
         aux_total = aux_total + jnp.sum(auxs)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    if last is not None:
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)
     if cfg.tie_embeddings:
         logits = unembed_tied(params["embed"], x)
     else:

@@ -190,9 +190,13 @@ class SlotAdapter:
                                the whole verify/commit drive; override
                                when verification needs several shared
                                forwards (diffusion refinement).
+      first_token              whether admission emits each fresh
+                               request's first token from its prefill
+                               (block diffusion generates it in a block).
     """
 
     mode = "greedy"
+    first_token = True
 
     def __init__(self, loop):
         self.loop = loop
@@ -222,6 +226,14 @@ class SlotAdapter:
     def observe(self, req, k: int, hidden) -> None:
         pass
 
+    @staticmethod
+    def readback(*chosen):
+        """The step's sanctioned device->host transfer: the small
+        per-position choices of a forward, computed on device (token ids,
+        and for diffusion their probabilities), never its logits."""
+        out = [np.asarray(c) for c in chosen]  # analysis: allow-host-sync
+        return out[0] if len(out) == 1 else out
+
     # -- default drive: propose / ONE shared forward / greedy accept ---
     def run_step(self, slots: List[int], width: int, budget: int) -> None:
         loop = self.loop
@@ -249,7 +261,7 @@ class SlotAdapter:
         # device->host transfer is this (batch, width) i32 block — the
         # token stream emission every serving loop fundamentally needs
         with TraceAnnotation("repro.adapter.readback"):
-            preds = np.asarray(greedy_tokens(logits))  # analysis: allow-host-sync
+            preds = self.readback(greedy_tokens(logits))
         advances = np.zeros((eng.batch,), np.int32)
         with TraceAnnotation("repro.adapter.accept"):
             for s in slots:

@@ -1,5 +1,7 @@
-"""Diffusion-style block decoding (WeDLM-like: causal attention + masked
-iterative refinement) — the DLLM side of the paper's validation.
+"""Diffusion-style block decoding — the DLLM side of the paper's
+validation.  Two samplers: WeDLM-like (causal attention + masked
+iterative refinement, any model) and SDAR's block diffusion (a model
+whose attention is block-causal, ``AttentionSpec.diffusion_block``).
 
 A block of N positions (N = the NFP budget) starts as [MASK] tokens and
 is refined over ``refine_steps`` decode forwards; each iteration commits
@@ -22,6 +24,17 @@ iterative refinement loop; ``DiffusionSlotAdapter`` runs the same
 refinement over MANY requests at once — each refinement iteration is
 ONE shared multi-position forward whose width still fits the NFP budget
 split across the active rows.
+
+Block diffusion (``BlockDiffusionSlotAdapter``, SDAR): blocks of the
+model's own length L are aligned to absolute positions; a masked
+position's own logits predict it, with no pending token.  Prefill keeps
+the prompt's whole blocks (``DecodeEngine.committed_len``); its last
+``p mod L`` tokens are fixed positions of the first block.  Each block
+is refined by shared width-L forwards under a static low-confidence
+schedule, then one forward over the resolved block commits its KV.
+
+Every refinement reads only each position's argmax and its probability,
+computed on device (``token_choice``): the logits never leave it.
 """
 from __future__ import annotations
 
@@ -39,20 +52,28 @@ from repro.serving.engine import DecodeEngine
 Array = jax.Array
 
 
-def refine_block(block: np.ndarray, resolved: np.ndarray, lg: np.ndarray,
-                 per_iter: int) -> None:
+@jax.jit
+def token_choice(logits):
+    """Each position's argmax (int32) and its softmax probability
+    (float32), ON DEVICE: a refinement transfers these two small arrays,
+    never the (..., vocab) logits."""
+    lg = logits.astype(jnp.float32)
+    top = jnp.max(lg, axis=-1, keepdims=True)
+    conf = 1.0 / jnp.sum(jnp.exp(lg - top), axis=-1)
+    return jnp.argmax(lg, axis=-1).astype(jnp.int32), conf
+
+
+def refine_block(block: np.ndarray, resolved: np.ndarray, preds: np.ndarray,
+                 conf: np.ndarray, per_iter: int) -> None:
     """One refinement update in place: freeze the ``per_iter`` most
-    confident still-masked positions of ``block`` given the float32
-    logits ``lg`` ((>=n+1, vocab); row i predicts block position i)."""
+    confident still-masked positions of ``block`` to their predictions
+    (``preds``/``conf`` from ``token_choice``, entry i for block
+    position i)."""
     n = len(block)
-    probs = np.exp(lg - lg.max(-1, keepdims=True))
-    probs /= probs.sum(-1, keepdims=True)
-    conf = probs.max(-1)[:n]
-    preds = probs.argmax(-1)[:n]
     cand = np.where(~resolved)[0]
-    order = cand[np.argsort(-conf[cand])]
+    order = cand[np.argsort(-conf[:n][cand])]
     pick = order[:per_iter]
-    block[pick] = preds[pick]
+    block[pick] = preds[:n][pick]
     resolved[pick] = True
 
 
@@ -96,18 +117,17 @@ class DiffusionBlockDecoder(ParallelDecodeAlgorithm):
         block = np.asarray(drafts, np.int64).copy()
         resolved = np.zeros((n,), bool)
         per_iter = max(1, int(np.ceil(n / self.refine_steps)))
-        step_logits = None
+        preds = None
         for _ in range(self.refine_steps):
             if resolved.all():
                 break
             step_logits, _, _ = self.forward_block(
                 np.concatenate([[pending], block]))
-            refine_block(block, resolved,
-                         np.asarray(step_logits[0].astype(jnp.float32)),
-                         per_iter)
+            preds, conf = (np.asarray(a)
+                           for a in token_choice(step_logits[0]))
+            refine_block(block, resolved, preds, conf, per_iter)
         if not resolved.all():
-            block[~resolved] = np.asarray(
-                jnp.argmax(step_logits[0], axis=-1))[:n][~resolved]
+            block[~resolved] = preds[:n][~resolved]
         # commit forward: KV for [pending] + block[:-1] computed from the
         # RESOLVED tokens (byte-identical to prefilling them)
         _, new_cache, _ = self.forward_block(
@@ -177,23 +197,21 @@ class DiffusionSlotAdapter(SlotAdapter):
                 tokens[s, 1:1 + n[s]] = blocks[s]
             return tokens
 
-        last_lg: Dict[int, np.ndarray] = {}
+        preds = None
         for _ in range(self.refine_steps):
             if all(resolved[s].all() for s in slots):
                 break
             logits, _, _ = loop.shared_forward(block_tokens(), budget)
             todo = [s for s in slots if not resolved[s].all()]
             with TraceAnnotation("repro.adapter.readback"):
-                for s in todo:
-                    last_lg[s] = np.asarray(logits[s].astype(jnp.float32))
+                preds, conf = self.readback(*token_choice(logits))
             with TraceAnnotation("repro.adapter.accept"):
                 for s in todo:
-                    refine_block(blocks[s], resolved[s], last_lg[s], max(
-                        1, int(np.ceil(n[s] / self.refine_steps))))
+                    refine_block(blocks[s], resolved[s], preds[s], conf[s],
+                                 max(1, int(np.ceil(n[s] / self.refine_steps))))
         for s in slots:
             if not resolved[s].all():
-                blocks[s][~resolved[s]] = (
-                    last_lg[s].argmax(-1)[:n[s]][~resolved[s]])
+                blocks[s][~resolved[s]] = preds[s, :n[s]][~resolved[s]]
         # shared commit forward over the fully-resolved blocks — the only
         # cache that reaches the engine
         _, new_cache, _ = loop.shared_forward(block_tokens(), budget)
@@ -204,3 +222,88 @@ class DiffusionSlotAdapter(SlotAdapter):
             advances[s] = n[s]                   # pending + block[:-1]
             req.pending = int(blocks[s][-1])
         eng.commit_slots(new_cache, advances)
+
+
+class BlockDiffusionSlotAdapter(SlotAdapter):
+    """SDAR's block-diffusion sampler over the slotted paged path, for a
+    model whose attention is block-causal with block length L
+    (``AttentionSpec.diffusion_block``).
+
+    A step generates one block per active row, in lock step: the block
+    starts at the row's committed length (a multiple of L), its first
+    positions are the tokens already known past it (the prompt's last
+    ``p mod L`` on a row's first block), the rest MASK.  Every
+    refinement is one shared width-L forward; each row then fixes
+    ``ceil(masked / refine_steps)`` of its most confident still-masked
+    positions, counted when its block starts (the static low-confidence
+    schedule, temperature 0).  Rows done early ride along.  A last
+    shared forward over the resolved blocks writes their clean KV and
+    advances each row by L.  A position is masked by flag, never by
+    comparing token ids: a prompt may hold the mask id.
+
+    Each ``Request`` records, per generated position, the refinement
+    step that fixed it (``fixed_at``).  The final block is refined
+    whole, past ``max_tokens``; ``Request.tokens`` cuts it there.
+    """
+
+    mode = "diffusion"
+    first_token = False
+
+    def __init__(self, loop, refine_steps: int = 4,
+                 mask_id: Optional[int] = None):
+        super().__init__(loop)
+        if refine_steps < 1:
+            raise ValueError(f"refine_steps must be >= 1, "
+                             f"got {refine_steps}")
+        cfg = loop.engine.cfg
+        self.block = cfg.attention.diffusion_block
+        self.refine_steps = refine_steps
+        self.mask_id = cfg.vocab_size - 1 if mask_id is None else mask_id
+
+    def width(self, n_active: int, budget: int) -> int:
+        # the trained block length, whatever the budget: not a knob
+        return self.block
+
+    def headroom(self) -> int:
+        return self.block
+
+    def run_step(self, slots: List[int], width: int, budget: int) -> None:
+        loop, eng, L = self.loop, self.loop.engine, self.block
+        block = np.zeros((eng.batch, L), np.int64)
+        masked = np.zeros((eng.batch, L), bool)
+        step_of = np.zeros((eng.batch, L), np.int64)
+        known: Dict[int, int] = {}
+        for s in slots:
+            req = loop.active[s]
+            tail = np.concatenate(
+                [req.prompt, np.asarray(req.generated, np.int64)]
+            )[int(eng.slot_lens_host[s]):]
+            known[s] = len(tail)
+            block[s, :known[s]] = tail
+            block[s, known[s]:] = self.mask_id
+            masked[s, known[s]:] = True
+        quota = -(-masked.sum(1) // self.refine_steps)       # (batch,)
+        step = 0
+        while masked.any():
+            with TraceAnnotation("repro.adapter.refine",
+                                 rows=int(masked.any(1).sum()),
+                                 masked=int(masked.sum())):
+                logits, _, _ = loop.shared_forward(block, budget)
+                preds, conf = self.readback(*token_choice(logits))
+                order = np.argsort(np.where(masked, -conf, np.inf), axis=1,
+                                   kind="stable")
+                rank = np.argsort(order, axis=1)
+                fix = masked & (rank < quota[:, None])
+                block[fix] = preds[fix]
+                step_of[fix] = step
+                masked &= ~fix
+            step += 1
+        with TraceAnnotation("repro.adapter.commit_block"):
+            _, cache, _ = loop.shared_forward(block, budget)
+            advances = np.zeros((eng.batch,), np.int64)
+            for s in slots:
+                req = loop.active[s]
+                req.generated.extend(int(t) for t in block[s, known[s]:])
+                req.fixed_at.extend(int(t) for t in step_of[s, known[s]:])
+                advances[s] = L
+            eng.commit_slots(cache, advances)

@@ -32,10 +32,15 @@ Array = jax.Array
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "use_kernel"))
-def _prefill_fn(params, cfg: ArchConfig, tokens, cache, use_kernel=False):
+def _prefill_fn(params, cfg: ArchConfig, tokens, cache, last,
+                use_kernel=False):
+    """Prefill ``tokens`` (b, s) into ``cache``; logits and hidden at
+    every position, or (b, 1, ...) at each row's position ``last`` (b,)
+    where given."""
     logits, cache, _, hidden = forward(params, cfg, {"tokens": tokens},
                                        mode="prefill", cache=cache,
-                                       cache_len=0, use_kernel=use_kernel)
+                                       cache_len=0, use_kernel=use_kernel,
+                                       last=last)
     return logits, cache, hidden
 
 
@@ -219,7 +224,7 @@ class DecodeEngine:
         propose from."""
         self._require_dense("prefill")
         logits, self.cache, hidden = _prefill_fn(self.params, self.cfg,
-                                                 tokens, self.cache,
+                                                 tokens, self.cache, None,
                                                  self.use_kernel)
         self.cache_len = int(tokens.shape[1])
         self.last_hidden = hidden[:, -1]
@@ -280,6 +285,24 @@ class DecodeEngine:
             b *= 2
         return min(b, self.max_len)
 
+    def _last_positions(self, lens: Dict[int, int]) -> Array:
+        """(batch,) each prefilled row's last prompt position (0 for the
+        rows a prefill does not admit)."""
+        last = np.zeros((self.batch,), np.int32)
+        for s, p in lens.items():
+            last[s] = p - 1
+        return jnp.asarray(last)
+
+    def committed_len(self, p: int) -> int:
+        """Positions of a ``p``-token prompt whose prefilled KV a slot
+        keeps.  A block-diffusion model (``diffusion_block`` L) keeps the
+        whole blocks only: the last ``p % L`` tokens are fixed positions
+        of the first block it generates, and their KV is written again,
+        beside the block's other positions, by its forwards."""
+        a = self.cfg.attention
+        block = a.diffusion_block if a is not None else None
+        return p - p % block if block else p
+
     def _needs_exact_prefill(self) -> bool:
         """SSM / hybrid segments carry a recurrent state that would
         absorb the bucket's tail padding — those archs prefill at exact
@@ -306,6 +329,9 @@ class DecodeEngine:
         prefix is prefix-cache resident skip the prefill compute for the
         shared blocks — only the divergent suffix runs, as a per-row
         offset decode-shape forward (see ``_prefill_slots_paged``).
+
+        Each slot keeps ``committed_len`` of its prompt's positions: all
+        of them, but for a block-diffusion model only the whole blocks.
 
         Returns {slot: (last-prompt-position logits, hidden)}.
         """
@@ -340,14 +366,15 @@ class DecodeEngine:
                     toks[s, :lens[s]] = np.asarray(prompts[s], np.int64)
                 logits, new_cache, hidden = _prefill_fn(
                     self.params, self.cfg, jnp.asarray(toks), self.cache,
+                    self._last_positions({s: lens[s] for s in rows}),
                     self.use_kernel)
                 self.cache = jax.tree.map(
                     lambda old, new: jnp.where(self._row_mask(rows, old),
                                                new, old),
                     self.cache, new_cache)
                 for s in rows:
-                    self._set_slot_len(s, lens[s])
-                    out[s] = (logits[s, lens[s] - 1], hidden[s, lens[s] - 1])
+                    self._set_slot_len(s, self.committed_len(lens[s]))
+                    out[s] = (logits[s, 0], hidden[s, 0])
             self.prefill_log.append(entry)
         return out
 
@@ -405,6 +432,7 @@ class DecodeEngine:
                 scratch = init_cache(self.cfg, self.batch, width)
                 logits, scratch, hidden = _prefill_fn(
                     self.params, self.cfg, jnp.asarray(toks), scratch,
+                    self._last_positions({s: lens[s] for s in full}),
                     self.use_kernel)
                 rows, blocks, pages = [], [], []
                 for s in full:
@@ -429,8 +457,8 @@ class DecodeEngine:
                     jnp.asarray(rows, jnp.int32),
                     jnp.asarray(blocks, jnp.int32))
                 for s in full:
-                    self._set_slot_len(s, lens[s])
-                    out[s] = (logits[s, lens[s] - 1], hidden[s, lens[s] - 1])
+                    self._set_slot_len(s, self.committed_len(lens[s]))
+                    out[s] = (logits[s, 0], hidden[s, 0])
             self.prefill_log.append(entry)
         if hits:
             suf = {s: lens[s] - plans[s].cached_len for s in hits}
@@ -452,7 +480,7 @@ class DecodeEngine:
                 # past their own committed length (or into the trash
                 # page), which no mask ever reads back
                 for s in hits:
-                    self._set_slot_len(s, lens[s])
+                    self._set_slot_len(s, self.committed_len(lens[s]))
                     out[s] = (logits[s, suf[s] - 1], hidden[s, suf[s] - 1])
             self.prefill_log.append(entry)
         for s in sorted(prompts):

@@ -78,7 +78,8 @@ from jax.profiler import TraceAnnotation
 
 from repro.kernels.decode_attention.ops import slack_report
 from repro.serving.algorithm import SlotAdapter
-from repro.serving.diffusion import DiffusionSlotAdapter
+from repro.serving.diffusion import (BlockDiffusionSlotAdapter,
+                                     DiffusionSlotAdapter)
 from repro.serving.engine import DecodeEngine, greedy_tokens
 from repro.serving.mtp import MTPSlotAdapter
 from repro.serving.speculative import SpeculativeSlotAdapter
@@ -159,11 +160,16 @@ class Request:
     preemptions: int = 0                   # times evicted + requeued
     t_submit: float = 0.0                  # time.perf_counter() at submit
     t_admit: Optional[float] = None        #   and at the first admission
+    forwards: int = 0                      # decode forwards its row was in
+    # block diffusion: per generated position, the refinement step of
+    # its block that fixed it
+    fixed_at: List[int] = field(default_factory=list)
 
     @property
     def context(self) -> np.ndarray:
-        """Tokens whose KV is committed in the request's cache slot."""
-        n_cached = len(self.generated) - 1      # all but the pending token
+        """Tokens whose KV is committed in the request's cache slot: all
+        but the pending token (block diffusion has none pending)."""
+        n_cached = len(self.generated) - (self.pending is not None)
         return np.concatenate(
             [self.prompt, self.generated[:n_cached]]).astype(np.int64)
 
@@ -178,7 +184,10 @@ class ServingLoop:
     docstring); a custom ``SlotAdapter`` subclass instance can be
     plugged in directly via ``adapter=`` (it receives this loop).
     ``mtp_heads`` feeds the mtp adapter; ``block_size`` /
-    ``refine_steps`` / ``mask_id`` feed the diffusion adapter.
+    ``refine_steps`` / ``mask_id`` feed the diffusion adapter, which is
+    SDAR's block-diffusion sampler for a model with block-causal
+    attention (``AttentionSpec.diffusion_block``, then the only block
+    size) and the WeDLM-like one otherwise.
 
     ``controller`` (an ``autotune.BudgetController``) replaces the raw
     analytic ``engine.nfp_budget`` as the per-step position budget: the
@@ -220,9 +229,18 @@ class ServingLoop:
             elif mode == "mtp":
                 adapter = MTPSlotAdapter(self, mtp_heads)
             else:
-                adapter = DiffusionSlotAdapter(
-                    self, block_size=block_size, refine_steps=refine_steps,
-                    mask_id=mask_id)
+                a = engine.cfg.attention
+                trained = a.diffusion_block if a is not None else None
+                if trained is None:
+                    adapter = DiffusionSlotAdapter(
+                        self, block_size=block_size,
+                        refine_steps=refine_steps, mask_id=mask_id)
+                elif block_size in (None, trained):
+                    adapter = BlockDiffusionSlotAdapter(
+                        self, refine_steps=refine_steps, mask_id=mask_id)
+                else:
+                    raise ValueError(f"{engine.cfg.name} generates blocks "
+                                     f"of {trained}, not {block_size}")
         self.adapter = adapter
         self.mode = adapter.mode
         if controller is not None:
@@ -436,7 +454,7 @@ class ServingLoop:
                 reserve={s: self._reserve_len(r)
                          for s, r in admitted.items()})
             fresh = sorted(s for s, r in admitted.items() if not r.generated)
-            if fresh:
+            if fresh and self.adapter.first_token:
                 # first token of every fresh request in ONE device argmax +
                 # one small (k,) readback — the admission-boundary transfer
                 with TraceAnnotation("repro.scheduler.first_token"):
@@ -507,7 +525,7 @@ class ServingLoop:
             width, self.engine.slot_lens_host, self.engine.max_len,
             head_dim=a.head_dim,
             window=a.window if a.kind == "swa" else None,
-            active=active, **extra)
+            block=a.diffusion_block, active=active, **extra)
 
     def shared_forward(self, tokens: np.ndarray, budget: int
                        ) -> Tuple[Array, Dict, Array]:
@@ -524,6 +542,8 @@ class ServingLoop:
             }
             if "calibrated" in self._budget_info:
                 entry["budget_calibrated"] = self._budget_info["calibrated"]
+            for req in self.active.values():
+                req.forwards += 1
             if self.engine.manager is not None:
                 entry["kv_blocks_used"] = self.engine.manager.blocks_used()
             slack = self._attn_slack(width)

@@ -12,7 +12,7 @@ from repro.configs import get_config
 from repro.models import init_model
 from repro.serving import (DecodeEngine, DiffusionBlockDecoder, MTPDecoder,
                            PagedKVConfig, ServingLoop, init_mtp_heads)
-from repro.serving.diffusion import refine_block
+from repro.serving.diffusion import refine_block, token_choice
 from repro.serving.engine import _prefill_fn
 
 KEY = jax.random.PRNGKey(0)
@@ -88,9 +88,9 @@ class _PoisonedCommit(DiffusionBlockDecoder):
                 break
             step_logits, new_cache, _ = self.forward_block(
                 np.concatenate([[pending], block]))
-            refine_block(block, resolved,
-                         np.asarray(step_logits[0].astype(jnp.float32)),
-                         per_iter)
+            preds, conf = (np.asarray(a)
+                           for a in token_choice(step_logits[0]))
+            refine_block(block, resolved, preds, conf, per_iter)
         if not resolved.all():
             block[~resolved] = np.asarray(
                 jnp.argmax(step_logits[0], axis=-1))[:n][~resolved]

@@ -10,6 +10,7 @@ entry compiled for a described chip cannot be read back without one.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import os
@@ -64,6 +65,7 @@ def _spec(one_chip, shape, dtype):
 ATTN = {
     "stablelm_3b": get_config("stablelm_3b").attention,
     "granite_moe_3b_a800m": get_config("granite_moe_3b_a800m").attention,
+    "sdar_30b_a3b": get_config("sdar_30b_a3b").attention,
 }
 
 
@@ -76,8 +78,8 @@ def test_ragged_attention_compiles(one_chip, arch, n):
             _spec(one_chip, (b, s, a.n_kv_heads, a.head_dim), jnp.bfloat16),
             _spec(one_chip, (b, s, a.n_kv_heads, a.head_dim), jnp.bfloat16),
             _spec(one_chip, (b,), jnp.int32))
-    jax.jit(lambda *x: decode_attention_ragged(*x, interpret=False)
-            ).lower(*args).compile()
+    jax.jit(lambda *x: decode_attention_ragged(
+        *x, block=a.diffusion_block, interpret=False)).lower(*args).compile()
 
 
 @pytest.mark.parametrize("arch", sorted(ATTN))
@@ -94,8 +96,8 @@ def test_paged_attention_compiles(one_chip, arch, block):
             _spec(one_chip, (b,), jnp.int32),
             _spec(one_chip, (b, s // block), jnp.int32),
             _spec(one_chip, (), jnp.int32))
-    jax.jit(lambda *x: decode_attention_paged(*x, interpret=False)
-            ).lower(*args).compile()
+    jax.jit(lambda *x: decode_attention_paged(
+        *x, block=a.diffusion_block, interpret=False)).lower(*args).compile()
 
 
 @pytest.mark.parametrize("tokens", [4, 32])
@@ -163,7 +165,18 @@ def test_decode_step_fits_one_chip(one_chip, monkeypatch):
 
 # the benchmark's paged engines: slots, decode width; 1024 positions in
 # pages of 128
-BENCH_ENGINES = {"stablelm_3b": (8, 3), "granite_moe_3b_a800m": (16, 2)}
+BENCH_ENGINES = {"stablelm_3b": (8, 3), "granite_moe_3b_a800m": (16, 2),
+                 "sdar_30b_a3b": (32, 4)}
+
+
+def _bench_config(arch):
+    """The benchmark's program config: SDAR at its cut, 24 of 48 layers
+    and one chip's 16 of 128 experts."""
+    cfg = get_config(arch)
+    if arch == "sdar_30b_a3b":
+        cfg = cfg.replace(n_layers=24,
+                          ffn=dataclasses.replace(cfg.ffn, held=16))
+    return cfg
 
 
 def _instructions(hlo: str):
@@ -185,7 +198,7 @@ def test_paged_decode_keeps_pool_in_place(one_chip, monkeypatch, arch):
     dynamic-slice or dynamic-update-slice moves a whole layer's pages
     (an in-place dynamic-update-slice moves only its update)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config(arch)
+    cfg = _bench_config(arch)
     (slots, width), max_len, block = BENCH_ENGINES[arch], 1024, 128
     n_phys = slots * max_len // block + 1
     params = jax.tree.map(
