@@ -5,8 +5,9 @@ NFP principle: q rows are padded to ``q_block`` (selected by
 ``core.granularity.select_q_block``) before launch, so physical work is
 quantized exactly like FlashAttention's kBlockM / FlashInfer's CTA_TILE_Q
 (paper App. F) — re-derived for the TPU memory hierarchy: the q tile and
-one (k_block, head_dim) KV tile live in VMEM, accumulation runs in f32
-VREGs, and the scores matmul maps onto the MXU with M = g*q_block.
+one (k_block, head_dim) KV tile of each of ``hps`` KV heads live in
+VMEM, accumulation runs in f32 VREGs, and the scores matmul maps onto
+the MXU with M = g*q_block, batched over the heads.
 
 RAGGED PER-SLOT DECODE: ``cache_lens`` is a (b,) scalar-prefetch vector —
 one committed-cache length per batch row.  This is the layout the
@@ -22,6 +23,14 @@ slack becomes observable per row (``ops.slack_report`` models exactly
 this skip rule).  Aligned rows (a scalar broadcast to (b,)) reduce to the
 old single-length behaviour bit-for-bit.
 
+HEADS PER STEP: a grid step carries ``hps`` KV heads (``ops.heads_per_step``
+picks the largest divisor of kv_heads whose blocks fit VMEM), so one
+step moves a kv tile of every head it carries and the per-step overhead
+is paid kv_heads/hps times a tile, not kv_heads times.  The heads axis
+is a batch axis of both matmuls; the mask and the tile-skip rule depend
+on the row, the q tile and the kv tile alone, so every head of a step
+shares them, and each head's arithmetic is that of a one-head step.
+
 Layout (prepared by ops.py):
   q: (b, kv_heads, g, n_pad, dh)   g = query heads per KV head (GQA)
   k: (b, kv_heads, s_pad, dh)
@@ -30,8 +39,10 @@ Layout (prepared by ops.py):
               per batch row; the n new positions sit at len_b .. len_b+n-1)
 Output:
   o: (b, kv_heads, g, n_pad, dh)
-Grid: (b, kv_heads, n_q_tiles, n_kv_tiles) — kv tiles innermost, online
-softmax state in VMEM scratch persists across kv tiles.
+Grid: (b, kv_heads/hps, n_q_tiles, n_kv_tiles) — kv tiles innermost,
+online softmax state of the step's hps heads in VMEM scratch persists
+across kv tiles.  Blocks: q and o (1, hps, g, q_block, dh), k and v
+(1, hps, k_block, dh).
 """
 from __future__ import annotations
 
@@ -57,6 +68,27 @@ def _visible_end(cache_len, q_end, n_logical: int, block: Optional[int]):
         return end
     return jnp.minimum(cache_len + n_logical,
                        ((end - 1) // block + 1) * block)
+
+
+def _useful_tile(cache_len, iq, ij, *, q_block: int, k_block: int,
+                 n_logical: int, window: Optional[int],
+                 block: Optional[int]):
+    """The kv tile grid step (row, ``iq``, ``ij``) reads: ``ij`` clamped
+    to the row's useful-tile range (the kernel's ``useful`` bounds, upper
+    AND window lower).  Skipped grid steps then revisit an already-
+    resident block, and Pallas elides the DMA when the block index is
+    unchanged — so the ragged skip saves HBM traffic, not just MXU work.
+    The fetched-but-skipped content is never read (the pl.when guard),
+    so the clamp target is free to choose."""
+    end = _visible_end(cache_len, jnp.minimum(n_logical, (iq + 1) * q_block),
+                       n_logical, block)
+    last = jnp.maximum((end + k_block - 1) // k_block - 1, 0)
+    idx = jnp.minimum(ij, last)
+    if window is not None:
+        first = jnp.maximum(
+            (cache_len + iq * q_block - window + 1) // k_block, 0)
+        idx = jnp.maximum(idx, jnp.minimum(first, last))
+    return idx
 
 
 def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -96,21 +128,23 @@ def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(useful)
     def _compute():
         rows = g * q_block
-        dh = q_ref.shape[-1]
-        q = q_ref[0, 0].reshape(rows, dh).astype(jnp.float32)
-        # dense layout blocks are (1, 1, kb, dh); paged pool pages hold
-        # their positions on the lanes, (1, 1, 1, dh, kb): flatten either
-        # to its 2-D tile and contract over dh wherever it sits
-        tile = (dh, k_block) if kv_lanes else (k_block, dh)
-        d_axis = 0 if kv_lanes else 1
+        hps, dh = q_ref.shape[1], q_ref.shape[-1]
+        q = q_ref[0].reshape(hps, rows, dh).astype(jnp.float32)
+        # dense layout blocks are (1, hps, kb, dh); paged pool pages hold
+        # their positions on the lanes, (1, hps, 1, dh, kb): flatten either
+        # to its (hps, 2-D tile) and contract over dh wherever it sits,
+        # the heads a batch axis of both matmuls
+        tile = (hps, dh, k_block) if kv_lanes else (hps, k_block, dh)
+        d_axis = 1 if kv_lanes else 2
         k = k_ref[...].reshape(tile).astype(jnp.float32)
         v = v_ref[...].reshape(tile).astype(jnp.float32)
 
         scores = jax.lax.dot_general(
-            q, k, (((1,), (d_axis,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (rows, kb)
+            q, k, (((2,), (d_axis,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # (hps, rows, kb)
 
         # --- causal / block-causal / window / validity mask ----------------
+        # one (rows, kb) mask, the same for every head of the step
         row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, k_block), 0)
         q_off = row_ids % q_block                            # row -> q index
         q_pos = cache_len + iq * q_block + q_off
@@ -123,18 +157,18 @@ def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
                     & (kv_pos < cache_len + n_logical))
         if window is not None:
             mask &= kv_pos > (q_pos - window)
-        scores = jnp.where(mask, scores, NEG_INF)
+        scores = jnp.where(mask[None], scores, NEG_INF)
 
         # --- online softmax ------------------------------------------------
         m_prev = m_ref[...]
-        m_cur = jnp.max(scores, axis=1, keepdims=True)
+        m_cur = jnp.max(scores, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(scores - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
         acc_ref[...] = (alpha * acc_ref[...]
                         + jax.lax.dot_general(
-                            p, v, (((1,), (1 - d_axis,)), ((), ())),
+                            p, v, (((2,), (3 - d_axis,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
@@ -142,12 +176,19 @@ def _attn_kernel(cache_lens_ref, q_ref, k_ref, v_ref, o_ref,
     def _finish():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        out = (acc_ref[...] / l).reshape(g, q_block, acc_ref.shape[-1])
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        out = (acc_ref[...] / l).reshape(o_ref.shape[1:])
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _scratch(hps: int, rows: int, dh: int):
+    """Online-softmax state of one grid step's ``hps`` heads."""
+    return [pltpu.VMEM((hps, rows, 1), jnp.float32),     # running max
+            pltpu.VMEM((hps, rows, 1), jnp.float32),     # running sum
+            pltpu.VMEM((hps, rows, dh), jnp.float32)]    # accumulator
 
 
 def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
-                            k_block: int, scale: float,
+                            k_block: int, scale: float, heads_per_step: int,
                             window: Optional[int] = None,
                             n_logical: Optional[int] = None,
                             block: Optional[int] = None,
@@ -157,12 +198,15 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
     ``n_logical`` is the un-padded query count (defaults to n_pad): row b's
     filled kv length is cache_lens[b] + n_logical, the per-row tile bound.
     ``block``: block-causal masking (``AttentionSpec.diffusion_block``).
+    ``heads_per_step`` (a divisor of kv, ``ops.heads_per_step``): the KV
+    heads one grid step carries; grid (b, kv/hps, q tiles, kv tiles).
     """
     b, kv, g, n_pad, dh = q.shape
+    hps = heads_per_step
     s_pad = k.shape[2]
     n_q_tiles = n_pad // q_block
     n_kv_tiles = s_pad // k_block
-    grid = (b, kv, n_q_tiles, n_kv_tiles)
+    grid = (b, kv // hps, n_q_tiles, n_kv_tiles)
 
     n_log = n_pad if n_logical is None else n_logical
     kernel = functools.partial(
@@ -170,41 +214,21 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
         window=window, n_kv_tiles=n_kv_tiles, n_logical=n_log, block=block)
 
     def kv_index(ib, ik, iq, ij, lens_ref):
-        # Clamp the kv block index to the row's useful-tile range (mirrors
-        # the kernel's `useful` bounds, upper AND window lower): skipped
-        # grid steps then revisit an already-resident block, and Pallas
-        # elides the DMA when the block index is unchanged — so the ragged
-        # skip saves HBM traffic, not just MXU work.  The fetched-but-
-        # skipped content is never read (the pl.when guard), so the clamp
-        # target is free to choose.
-        end = _visible_end(lens_ref[ib], jnp.minimum(n_log, (iq + 1) * q_block),
-                           n_log, block)
-        last = jnp.maximum((end + k_block - 1) // k_block - 1, 0)
-        idx = jnp.minimum(ij, last)
-        if window is not None:
-            first = jnp.maximum(
-                (lens_ref[ib] + iq * q_block - window + 1) // k_block, 0)
-            idx = jnp.maximum(idx, jnp.minimum(first, last))
-        return (ib, ik, idx, 0)
+        return (ib, ik, _useful_tile(lens_ref[ib], iq, ij, q_block=q_block,
+                                     k_block=k_block, n_logical=n_log,
+                                     window=window, block=block), 0)
 
+    qo_spec = pl.BlockSpec((1, hps, g, q_block, dh),
+                           lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0))
+    kv_spec = pl.BlockSpec((1, hps, k_block, dh), kv_index)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, g, q_block, dh),
-                             lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0)),
-                pl.BlockSpec((1, 1, k_block, dh), kv_index),
-                pl.BlockSpec((1, 1, k_block, dh), kv_index),
-            ],
-            out_specs=pl.BlockSpec((1, 1, g, q_block, dh),
-                                   lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((g * q_block, 1), jnp.float32),   # running max
-                pltpu.VMEM((g * q_block, 1), jnp.float32),   # running sum
-                pltpu.VMEM((g * q_block, dh), jnp.float32),  # accumulator
-            ],
+            in_specs=[qo_spec, kv_spec, kv_spec],
+            out_specs=qo_spec,
+            scratch_shapes=_scratch(hps, g * q_block, dh),
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, n_pad, dh), q.dtype),
         interpret=interpret,
@@ -214,7 +238,7 @@ def decode_attention_pallas(q, k, v, cache_lens, *, q_block: int,
 
 def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
                                   block_tables, layer, *, q_block: int,
-                                  scale: float,
+                                  scale: float, heads_per_step: int,
                                   window: Optional[int] = None,
                                   n_logical: Optional[int] = None,
                                   block: Optional[int] = None,
@@ -227,7 +251,16 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
     holding its positions on the lanes;
     cache_lens: (b,) i32; block_tables: (b, max_blocks) i32 mapping row
     b's LOGICAL kv tile ij to a physical page; layer: (1,) i32, the
-    layer whose pages this launch reads.
+    layer whose pages this launch reads; ``heads_per_step`` (hps, a
+    divisor of kv, ``ops.heads_per_step``): the KV heads one step carries.
+
+    Grid (b, kv/hps, n_q_tiles, max_blocks).  Step (ib, ik, iq, ij)
+    reads q and writes o as (1, hps, g, q_block, dh) blocks — the query
+    tile iq of KV heads ik*hps .. ik*hps+hps-1 — and reads K and V as
+    (1, hps, 1, dh, block_size) blocks: one page of each of those heads,
+    a strided copy of hps contiguous (dh, block_size) chunks, as
+    ``paged_kv_write`` reads them.  With hps = kv one step reads a page
+    of every head.
 
     This is the (b,) ``cache_lens`` scalar-prefetch machinery
     generalized: further prefetch operands carry the block tables and
@@ -239,14 +272,17 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
     positions.  The tile-skip rule (and therefore ``ops.slack_report``)
     is unchanged: a skipped grid step revisits the row's last useful
     page, and Pallas elides the copy when the page index is unchanged.
-    Rows whose table entries point at the trailing trash page (inactive
-    slots) read junk that the causal mask zeroes out exactly.
+    Each executed (row, head, page) tile is still read once and each
+    (row, head, q tile) query and output once.  Rows whose table entries
+    point at the trailing trash page (inactive slots) read junk that the
+    causal mask zeroes out exactly.
     """
     b, kv, g, n_pad, dh = q.shape
+    hps = heads_per_step
     block_size = k_pool.shape[4]
     n_q_tiles = n_pad // q_block
     n_kv_tiles = block_tables.shape[1]
-    grid = (b, kv, n_q_tiles, n_kv_tiles)
+    grid = (b, kv // hps, n_q_tiles, n_kv_tiles)
 
     n_log = n_pad if n_logical is None else n_logical
     kernel = functools.partial(
@@ -262,38 +298,26 @@ def decode_attention_paged_pallas(q, k_pool, v_pool, cache_lens,
         return kernel(lens_ref, *refs, **kw)
 
     def kv_index(ib, ik, iq, ij, lens_ref, bt_ref, layer_ref):
-        # identical useful-range clamp to the dense ragged kernel, then
-        # mapped through the row's block table: logical tile -> physical
-        # page.  Entries inside the clamp range are always valid pages
-        # (allocated, or the trash page for inactive rows).
-        end = _visible_end(lens_ref[ib], jnp.minimum(n_log, (iq + 1) * q_block),
-                           n_log, block)
-        last = jnp.maximum((end + block_size - 1) // block_size - 1, 0)
-        idx = jnp.minimum(ij, last)
-        if window is not None:
-            first = jnp.maximum(
-                (lens_ref[ib] + iq * q_block - window + 1) // block_size, 0)
-            idx = jnp.maximum(idx, jnp.minimum(first, last))
+        # the dense kernel's useful-range clamp, then mapped through the
+        # row's block table: logical tile -> physical page.  Entries
+        # inside the clamp range are always valid pages (allocated, or
+        # the trash page for inactive rows).
+        idx = _useful_tile(lens_ref[ib], iq, ij, q_block=q_block,
+                           k_block=block_size, n_logical=n_log,
+                           window=window, block=block)
         return (layer_ref[0], ik, bt_ref[ib, idx], 0, 0)
 
+    qo_spec = pl.BlockSpec((1, hps, g, q_block, dh),
+                           lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0))
+    kv_spec = pl.BlockSpec((1, hps, 1, dh, block_size), kv_index)
     return pl.pallas_call(
         paged_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, g, q_block, dh),
-                             lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0)),
-                pl.BlockSpec((1, 1, 1, dh, block_size), kv_index),
-                pl.BlockSpec((1, 1, 1, dh, block_size), kv_index),
-            ],
-            out_specs=pl.BlockSpec((1, 1, g, q_block, dh),
-                                   lambda ib, ik, iq, ij, *_: (ib, ik, 0, iq, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((g * q_block, 1), jnp.float32),   # running max
-                pltpu.VMEM((g * q_block, 1), jnp.float32),   # running sum
-                pltpu.VMEM((g * q_block, dh), jnp.float32),  # accumulator
-            ],
+            in_specs=[qo_spec, kv_spec, kv_spec],
+            out_specs=qo_spec,
+            scratch_shapes=_scratch(hps, g * q_block, dh),
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, n_pad, dh), q.dtype),
         interpret=interpret,

@@ -16,10 +16,15 @@ physical page — the page size is that launch's k_block, so paging slots
 straight into the same tile-skip machinery.  ``paged_kv_write`` writes
 a forward's new K/V into that pool in place, a page at a time.
 
+``heads_per_step`` picks, from shapes alone, how many KV heads one grid
+step of either launch carries: the largest divisor of kv_heads whose
+blocks and scratch fit ``VMEM_BUDGET``.
+
 ``slack_report`` models the kernel's physical work for one forward in
-plain numpy — useful vs padded query rows, and executed vs grid kv tiles
-under the kernel's per-row skip rule — so serving telemetry can place
-MEASURED per-step granularity slack next to the ``core.nfp`` prediction.
+plain numpy — useful vs padded query rows, executed vs grid kv tiles
+under the kernel's per-row skip rule, and the grid steps a launch runs
+— so serving telemetry can place MEASURED per-step granularity slack
+next to the ``core.nfp`` prediction.
 The same rule covers the paged launch (pass ``k_block=block_size`` and
 the block-table-covered ``s_max``): tile skipping is decided in logical
 positions, independent of which physical page a tile maps to.
@@ -39,6 +44,38 @@ from repro.kernels.decode_attention.kernel import (
     paged_kv_write_pallas)
 
 K_BLOCK = 128
+# VMEM a grid step's blocks and scratch may take: the v5e's scoped
+# default is 16 MiB, and the rest is the compiler's (f32 copies of the
+# q, K and V tiles, the mask)
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _vmem_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM a (rows, cols) array takes in (sublane, 128-lane) tiles."""
+    sublanes = 8 * max(1, 4 // itemsize)
+    return round_up(rows, sublanes) * round_up(cols, 128) * itemsize
+
+
+def heads_per_step(kv_heads: int, g: int, q_block: int, head_dim: int,
+                   k_block: int, itemsize: int) -> int:
+    """KV heads one grid step of the decode-attention launches carries:
+    the largest divisor of ``kv_heads`` whose per-step working set fits
+    ``VMEM_BUDGET``.  A head's share: its double-buffered q and output
+    blocks (g*q_block x head_dim) and K and V tiles (k_block x head_dim,
+    either orientation), at ``itemsize`` bytes, plus in float32 the
+    running max and sum, the accumulator, and the scores and
+    probabilities (g*q_block x k_block)."""
+    rows = g * q_block
+    tile = max(_vmem_bytes(k_block, head_dim, itemsize),
+               _vmem_bytes(head_dim, k_block, itemsize))
+    per_head = (2 * 2 * _vmem_bytes(rows, head_dim, itemsize)   # q, out
+                + 2 * 2 * tile                                   # K, V
+                + 2 * _vmem_bytes(rows, 1, 4)                    # max, sum
+                + _vmem_bytes(rows, head_dim, 4)                 # acc
+                + 2 * _vmem_bytes(rows, k_block, 4))             # s, p
+    fits = [d for d in range(1, kv_heads + 1)
+            if kv_heads % d == 0 and d * per_head <= VMEM_BUDGET]
+    return max(fits, default=1)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "q_block_override",
@@ -79,8 +116,11 @@ def decode_attention_ragged(q, k_cache, v_cache, cache_lens, *,
     lens = jnp.broadcast_to(
         jnp.asarray(cache_lens, jnp.int32).reshape(-1), (b,))
 
+    hps = heads_per_step(kv, g, q_block, dh, k_block,
+                         max(q.dtype.itemsize, k_cache.dtype.itemsize))
     o = decode_attention_pallas(qk, kk, vk, lens, q_block=q_block,
-                                k_block=k_block, scale=scale, window=window,
+                                k_block=k_block, scale=scale,
+                                heads_per_step=hps, window=window,
                                 n_logical=n, block=block,
                                 interpret=interpret)
     return o[:, :, :, :n].transpose(0, 3, 1, 2, 4).reshape(b, n, h, dh)
@@ -126,9 +166,12 @@ def decode_attention_paged(q, k_pool, v_pool, cache_lens, block_tables,
     bt = jnp.asarray(block_tables, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
+    hps = heads_per_step(kv, g, q_block, dh, k_pool.shape[4],
+                         max(q.dtype.itemsize, k_pool.dtype.itemsize))
     o = decode_attention_paged_pallas(qk, k_pool, v_pool, lens, bt, layer,
                                       q_block=q_block, scale=scale,
-                                      window=window, n_logical=n, block=block,
+                                      heads_per_step=hps, window=window,
+                                      n_logical=n, block=block,
                                       interpret=interpret)
     return o[:, :, :, :n].transpose(0, 3, 1, 2, 4).reshape(b, n, h, dh)
 
@@ -183,8 +226,12 @@ def slack_report(n: int, cache_lens, s_max: int, *,
                  k_block: int = K_BLOCK,
                  window: Optional[int] = None,
                  block: Optional[int] = None,
-                 active=None) -> Dict[str, float]:
-    """Model one ragged decode forward's physical work (per kv head).
+                 active=None,
+                 n_heads: int = 1,
+                 kv_heads: int = 1,
+                 itemsize: int = 2) -> Dict[str, float]:
+    """Model one ragged decode forward's physical work (tiles per kv head,
+    grid steps per launch).
 
     Mirrors the kernel's skip rule exactly: for batch row b and q tile iq,
     kv tile ij executes iff
@@ -201,6 +248,8 @@ def slack_report(n: int, cache_lens, s_max: int, *,
       active:     optional (b,) bool — rows carrying real requests.  Rows
                   outside it still execute (the kernel runs the whole
                   batch) but count as pure slack.
+      n_heads, kv_heads, itemsize: the launch's head layout and the bytes
+                  of its q/K/V elements, which set ``heads_per_step``.
 
     Returns a dict:
       rows_logical / rows_physical / row_utilization   — query-row padding
@@ -209,6 +258,9 @@ def slack_report(n: int, cache_lens, s_max: int, *,
       kv_tiles_grid      — tiles a non-ragged scalar-length kernel runs
       kv_tile_utilization = useful / executed
       kv_tiles_skipped    = grid - executed (the ragged win)
+      heads_per_step     — KV heads one grid step carries (hps)
+      grid_steps         — steps one launch (one layer) runs:
+                           b x q_tiles x kv_tiles x kv_heads / hps
     """
     lens = np.asarray(cache_lens, np.int64).ravel()
     b = lens.size
@@ -242,6 +294,8 @@ def slack_report(n: int, cache_lens, s_max: int, *,
     rows_logical = int(act.sum()) * n
     rows_physical = b * n_pad
     grid = b * n_q_tiles * n_kv_tiles
+    hps = heads_per_step(kv_heads, n_heads // kv_heads, qb, head_dim,
+                         k_block, itemsize)
     return {
         "n": n, "q_block": qb, "k_block": k_block,
         "rows_logical": rows_logical,
@@ -252,4 +306,6 @@ def slack_report(n: int, cache_lens, s_max: int, *,
         "kv_tiles_grid": grid,
         "kv_tile_utilization": useful / max(executed, 1),
         "kv_tiles_skipped": grid - executed,
+        "heads_per_step": hps,
+        "grid_steps": grid * (kv_heads // hps),
     }

@@ -262,11 +262,21 @@ class ServingLoop:
         self._prefill_log_start = len(engine.prefill_log)
         # per-forward telemetry: active/width/positions/budget plus, when
         # serving through the kernel path, its measured granularity slack
-        # (attn_row_util, kv_tiles_executed/grid/skipped, kv_tile_util) —
+        # (attn_row_util, kv_tiles_executed/grid/skipped, kv_tile_util,
+        # attn_heads_per_step, attn_grid_steps of one layer's launch) —
         # the measured counterpart of the core.nfp M_attn prediction.
         # Diffusion logs one entry per refinement/commit forward, so
         # ``len(step_log)`` counts FORWARDS in every mode.
         self.step_log: List[Dict] = []
+        # bytes of the widest element the attention launch reads, which
+        # sizes its grid step: the activations' (the embedding's) or the
+        # K cache's
+        self._attn_itemsize = max(jax.tree.leaves((
+            jax.tree.map(lambda x: x.dtype.itemsize, engine.params["embed"]),
+            jax.tree_util.tree_map_with_path(
+                lambda path, x: (x.dtype.itemsize
+                                 if getattr(path[-1], "key", None) == "k"
+                                 else 0), engine.cache))))
 
     # ------------------------------------------------------------------
     def submit(self, prompt, max_tokens: int,
@@ -525,7 +535,8 @@ class ServingLoop:
             width, self.engine.slot_lens_host, self.engine.max_len,
             head_dim=a.head_dim,
             window=a.window if a.kind == "swa" else None,
-            block=a.diffusion_block, active=active, **extra)
+            block=a.diffusion_block, active=active, n_heads=a.n_heads,
+            kv_heads=a.n_kv_heads, itemsize=self._attn_itemsize, **extra)
 
     def shared_forward(self, tokens: np.ndarray, budget: int
                        ) -> Tuple[Array, Dict, Array]:
@@ -555,6 +566,8 @@ class ServingLoop:
                     "kv_tiles_grid": slack["kv_tiles_grid"],
                     "kv_tiles_skipped": slack["kv_tiles_skipped"],
                     "kv_tile_util": slack["kv_tile_utilization"],
+                    "attn_heads_per_step": slack["heads_per_step"],
+                    "attn_grid_steps": slack["grid_steps"],
                 })
             self.step_log.append(entry)
         return self.engine.decode_slots(jnp.asarray(tokens, jnp.int32))

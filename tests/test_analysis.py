@@ -7,10 +7,12 @@ Fast lane: fixture projects are tiny tmp_path packages parsed by the
 AST index directly; the Pallas capture harness runs the real kernels'
 ops entries eagerly on CPU in ~2s (module-scoped)."""
 import json
+import math
 import textwrap
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -22,9 +24,11 @@ from repro.analysis.cli import find_repo_root, main
 from repro.analysis.findings import Finding
 from repro.analysis.granularity_drift import (check_drift, declared_tiles,
                                               launched_tiles)
-from repro.analysis.pallas_contracts import (CapturedLaunch,
+from repro.analysis.pallas_contracts import (KERNEL_PATHS, CapturedLaunch,
+                                             CaptureTarget,
                                              capture_launches, check_launch)
 from repro.configs import get_config
+from repro.kernels.decode_attention import ops as attn_ops
 from repro.models import init_model
 from repro.serving import DecodeEngine, ServingLoop
 from repro.serving.engine import _decode_fn
@@ -472,6 +476,45 @@ def test_real_kernel_launches_satisfy_contracts(captures):
     assert len(captures) >= 6
     findings = pallas_contracts.check(captures=captures)
     assert findings == [], "\n".join(f.render() for f in findings)
+
+
+def _paged_below_all_heads():
+    """A paged launch whose shapes give fewer heads a step than KV heads:
+    64 query heads over 8 KV heads of 128, bf16, width 65."""
+    n_phys, bs, kv, dh = 5, 128, 8, 128
+    q = jnp.zeros((1, 65, 64, dh), jnp.bfloat16)
+    pool = jnp.zeros((2, kv, n_phys, dh, bs), jnp.bfloat16)
+    attn_ops.decode_attention_paged(
+        q, pool, pool, jnp.asarray([100], jnp.int32),
+        jnp.asarray([[0, 1, 2, 4]], jnp.int32), 1)
+
+
+@pytest.mark.parametrize("case", ["harness", "below_all_heads"])
+def test_slack_report_matches_captured_paged_launch(captures, case):
+    """``ops.slack_report``'s ``heads_per_step`` and ``grid_steps`` are
+    the head block and the grid the paged launch is captured with: the
+    capture harness's paged example (float32, every head a step), and
+    one whose shapes give half the KV heads a step."""
+    if case == "harness":
+        launch = {c.label: c for c in captures}["decode_attention_paged/n1"]
+        n, itemsize = 1, 4
+    else:
+        launch, = capture_launches([CaptureTarget(
+            "decode_attention_paged/n65", KERNEL_PATHS["decode_attention"],
+            _paged_below_all_heads)])
+        assert check_launch(launch) == []
+        n, itemsize = 65, 2
+    lens, tables, _ = launch.prefetch_values
+    _, kv, g, _, dh = launch.in_shapes[0]
+    bs = launch.in_shapes[1][-1]
+    rep = attn_ops.slack_report(n, lens, tables.shape[1] * bs, head_dim=dh,
+                                k_block=bs, n_heads=kv * g, kv_heads=kv,
+                                itemsize=itemsize)
+    hps = launch.in_specs[0].block_shape[1]
+    assert launch.in_specs[1].block_shape[1] == hps
+    assert rep["heads_per_step"] == hps
+    assert rep["grid_steps"] == math.prod(launch.grid)
+    assert (hps == kv) == (case == "harness")
 
 
 def test_launched_tiles_match_granularity_registry(captures):

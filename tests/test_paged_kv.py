@@ -11,6 +11,8 @@ positions contribute exact zeros and equality is again structural.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,7 +22,11 @@ from hypothesis import strategies as st
 
 from repro.configs import get_config
 from repro.core.arch import AttentionSpec
-from repro.kernels.decode_attention.ops import decode_attention_paged
+from repro.core.granularity import round_up, select_q_block
+from repro.kernels.decode_attention.kernel import (
+    decode_attention_paged_pallas)
+from repro.kernels.decode_attention.ops import (decode_attention_paged,
+                                                heads_per_step)
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.models import init_model
 from repro.models.attention import gqa_decode_paged, init_attention
@@ -79,8 +85,14 @@ def test_paged_matches_dense_kernel_path(model, mode):
     assert dense.keys() == paged.keys()
     for rid in dense:
         assert np.array_equal(dense[rid], paged[rid]), f"req {rid} diverged"
-    # the kernel slack telemetry stays on under paging
-    assert any("kv_tile_util" in e for e in loop.step_log)
+    # the kernel slack telemetry stays on under paging: a step carries
+    # every KV head, so a launch runs a step per (row, q tile, page)
+    logged = [e for e in loop.step_log if "kv_tile_util" in e]
+    assert logged
+    kv_tiles = MAX_LEN // 128
+    for e in logged:
+        assert e["attn_heads_per_step"] == cfg.attention.n_kv_heads
+        assert e["attn_grid_steps"] == 2 * -(-e["width"] // 64) * kv_tiles
 
 
 @pytest.mark.parametrize("mode", ["greedy", "speculative", "mtp",
@@ -444,15 +456,38 @@ def _pool_from_dense(k_dense, v_dense, lens, n, bs, layout, layers=3,
     return jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(tables)
 
 
+@functools.partial(jax.jit, static_argnames=("hps", "window", "block"))
+def _paged_launch(q, k_pool, v_pool, lens, tables, *, hps, window=None,
+                  block=None):
+    """The paged launch over layer 1 of the pool with ``hps`` KV heads a
+    grid step, behind ``ops.decode_attention_paged``'s layout prep:
+    q (b, n, h, dh) in, (b, n, h, dh) out."""
+    b, n, h, dh = q.shape
+    kv = k_pool.shape[1]
+    qb = select_q_block(n, dh)
+    n_pad = round_up(n, qb)
+    qk = q.reshape(b, n, kv, h // kv, dh).transpose(0, 2, 3, 1, 4)
+    qk = jnp.pad(qk, ((0, 0), (0, 0), (0, 0), (0, n_pad - n), (0, 0)))
+    o = decode_attention_paged_pallas(
+        qk, k_pool, v_pool, lens, tables, jnp.asarray([1], jnp.int32),
+        q_block=qb, scale=1.0 / dh ** 0.5, heads_per_step=hps,
+        window=window, n_logical=n, block=block, interpret=True)
+    return o[:, :, :, :n].transpose(0, 3, 1, 2, 4).reshape(b, n, h, dh)
+
+
+@pytest.mark.parametrize("hps", [1, 2, 4])
 @pytest.mark.parametrize("layout", ["fragmented", "reversed", "identity"])
 @pytest.mark.parametrize("window", [None, 24])
-def test_paged_kernel_adversarial_layouts(layout, window):
+def test_paged_kernel_adversarial_layouts(layout, window, hps):
     """Kernel-vs-oracle parity under hostile tables: scattered and
     reversed physical pages, a len-0 row, a single-block slot, and a
     full-cache row — junk in unattached pages, and in the other layers
-    of the stacked pool, must never leak through."""
+    of the stacked pool, must never leak through, whether a grid step
+    carries one of the 4 KV heads, two, or all (equal to one a step
+    bit for bit; the ops entry launches the ``heads_per_step`` its
+    shapes give)."""
     rng = np.random.default_rng(1)
-    b, n, h, kv, dh = 4, 4, 8, 2, 64
+    b, n, h, kv, dh = 4, 4, 8, 4, 64
     bs, s = 16, 96
     lens = np.array([0, 5, 16 - n, s - n], np.int32)   # len-0 / single-block
     q = jnp.asarray(rng.standard_normal((b, n, h, dh)), jnp.float32)
@@ -460,12 +495,62 @@ def test_paged_kernel_adversarial_layouts(layout, window):
     v_dense = jnp.asarray(rng.standard_normal((b, s, kv, dh)), jnp.float32)
     k_pool, v_pool, tables = _pool_from_dense(k_dense, v_dense, lens, n,
                                               bs, layout)
-    out = decode_attention_paged(q, k_pool, v_pool, jnp.asarray(lens),
-                                 tables, 1, window=window)
+    args = (q, k_pool, v_pool, jnp.asarray(lens), tables)
+    out = _paged_launch(*args, hps=hps, window=window)
     ref = decode_attention_ref(q, k_dense, v_dense, jnp.asarray(lens),
                                window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+    if hps > 1:
+        np.testing.assert_array_equal(
+            out, _paged_launch(*args, hps=1, window=window))
+    if hps == heads_per_step(kv, h // kv, select_q_block(n, dh), dh, bs, 4):
+        np.testing.assert_array_equal(
+            out, decode_attention_paged(*args, 1, window=window))
+
+
+# (n_heads, kv_heads, window, block): MHA, GQA with 3 and 8 query heads a
+# KV head, 8 under a block-causal mask of blocks of 4, a sliding window
+HEAD_LAYOUTS = {"mha": (4, 4, None, None), "gqa3": (12, 4, None, None),
+                "gqa8_block4": (32, 4, None, 4), "window": (8, 4, 24, None)}
+
+
+@functools.lru_cache(maxsize=None)
+def _grouping_case(layout: str, n: int):
+    """Inputs of one head-grouping case, the one-head-a-step launch's
+    output and the reference's, all as numpy arrays."""
+    h, kv, window, block = HEAD_LAYOUTS[layout]
+    rng = np.random.default_rng(n)
+    b, dh, bs, s = 3, 16, 16, 96
+    lens = np.array([0, 8 + n % 4, s - n], np.int32)
+    q = rng.standard_normal((b, n, h, dh)).astype(np.float32)
+    k_dense = rng.standard_normal((b, s, kv, dh)).astype(np.float32)
+    v_dense = rng.standard_normal((b, s, kv, dh)).astype(np.float32)
+    k_pool, v_pool, tables = _pool_from_dense(k_dense, v_dense, lens, n,
+                                              bs, "fragmented", seed=n)
+    args = (jnp.asarray(q), k_pool, v_pool, jnp.asarray(lens), tables)
+    one = _paged_launch(*args, hps=1, window=window, block=block)
+    ref = decode_attention_ref(jnp.asarray(q), jnp.asarray(k_dense),
+                               jnp.asarray(v_dense), jnp.asarray(lens),
+                               window=window, block=block)
+    return args, np.asarray(one), np.asarray(ref)
+
+
+@pytest.mark.parametrize("hps", [1, 2, 4])
+@pytest.mark.parametrize("n", [1, 4, 16])
+@pytest.mark.parametrize("layout", sorted(HEAD_LAYOUTS))
+def test_paged_kernel_head_groupings(layout, n, hps):
+    """A grid step carrying ``hps`` of the 4 KV heads (one, a proper
+    divisor, all) computes what one head a step does, bit for bit, and
+    the reference's attention within float32 rounding: the heads are a
+    batch axis of the step's matmuls, and the mask and the tile-skip
+    rule are the row's alone."""
+    _, _, window, block = HEAD_LAYOUTS[layout]
+    args, one, ref = _grouping_case(layout, n)
+    out = np.asarray(_paged_launch(*args, hps=hps, window=window,
+                                   block=block))
+    np.testing.assert_array_equal(out, one)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("n", [1, 3, 20])

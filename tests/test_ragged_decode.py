@@ -165,7 +165,8 @@ def test_serving_kernel_path_golden(serving_setup, mode):
         assert np.array_equal(refs[i], out[i]), i
     for e in loop.step_log:
         for k in ("attn_row_util", "kv_tiles_executed", "kv_tiles_grid",
-                  "kv_tiles_skipped", "kv_tile_util"):
+                  "kv_tiles_skipped", "kv_tile_util", "attn_heads_per_step",
+                  "attn_grid_steps"):
             assert k in e
         assert 0 < e["kv_tiles_executed"] <= e["kv_tiles_grid"]
     assert "mean_kv_tile_util" in loop.stats()
@@ -258,3 +259,7 @@ def test_slack_report_matches_kernel_tiling():
         qb = select_q_block(n, 64)
         assert rep["q_block"] == qb
         assert rep["rows_physical"] == 2 * round_up(n, qb)
+        # one KV head a step by default: a step per (row, q tile, kv tile)
+        assert rep["heads_per_step"] == 1
+        assert rep["grid_steps"] == rep["kv_tiles_grid"]
+        assert rep["grid_steps"] == 2 * round_up(n, qb) // qb * 2

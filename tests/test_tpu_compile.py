@@ -22,8 +22,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.arch import AttentionSpec
 from repro.kernels.decode_attention.ops import (decode_attention_paged,
-                                                decode_attention_ragged)
+                                                decode_attention_ragged,
+                                                heads_per_step)
 from repro.kernels.mamba_scan.ops import selective_scan
 from repro.kernels.moe_ffn.ops import grouped_ffn
 from repro.models import init_model
@@ -82,11 +84,8 @@ def test_ragged_attention_compiles(one_chip, arch, n):
         *x, block=a.diffusion_block, interpret=False)).lower(*args).compile()
 
 
-@pytest.mark.parametrize("arch", sorted(ATTN))
-@pytest.mark.parametrize("block", [16, 128])
-def test_paged_attention_compiles(one_chip, arch, block):
-    a = ATTN[arch]
-    b, s, n = 4, 4096, 8
+def _compile_paged(one_chip, a, n, block):
+    b, s = 4, 4096
     n_phys = b * s // block + 1
     args = (_spec(one_chip, (b, n, a.n_heads, a.head_dim), jnp.bfloat16),
             _spec(one_chip, (2, a.n_kv_heads, n_phys, a.head_dim, block),
@@ -98,6 +97,33 @@ def test_paged_attention_compiles(one_chip, arch, block):
             _spec(one_chip, (), jnp.int32))
     jax.jit(lambda *x: decode_attention_paged(
         *x, block=a.diffusion_block, interpret=False)).lower(*args).compile()
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN))
+@pytest.mark.parametrize("block", [16, 128])
+@pytest.mark.parametrize("n", [1, 16, 65])
+def test_paged_attention_compiles(one_chip, arch, block, n):
+    """Every KV head of a page in one grid step fits scoped VMEM at the
+    three configurations' layouts, up to two query tiles a row."""
+    _compile_paged(one_chip, ATTN[arch], n, block)
+
+
+def test_paged_attention_compiles_below_all_heads(one_chip):
+    """64 query heads over 8 KV heads of 128: all 8 in one step would
+    overflow scoped VMEM, so the shapes give fewer heads a step, and
+    that program compiles."""
+    a = AttentionSpec(kind="gqa", n_heads=64, n_kv_heads=8, head_dim=128)
+    assert heads_per_step(8, 8, 64, 128, 128, 2) < 8
+    _compile_paged(one_chip, a, 65, 128)
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN))
+def test_heads_per_step_takes_every_kv_head(arch):
+    """At the three configurations' layouts (bf16, query tile 64, pages
+    of 128) one grid step carries every KV head."""
+    a = ATTN[arch]
+    assert heads_per_step(a.n_kv_heads, a.n_heads // a.n_kv_heads, 64,
+                          a.head_dim, 128, 2) == a.n_kv_heads
 
 
 @pytest.mark.parametrize("tokens", [4, 32])
