@@ -8,7 +8,6 @@
 Run: PYTHONPATH=src python examples/quickstart.py
 """
 import jax
-import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.core import (GranularitySpec, TPU_V5E, H20, LatencyCurve,
@@ -50,13 +49,13 @@ def main():
     small = get_config("llada_mini_like", reduced=True)
     params = init_model(jax.random.PRNGKey(0), small)
     eng = DecodeEngine(small, params, batch=1, max_len=128)
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 8), 0,
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (8,), 0,
                                 small.vocab_size)
-    eng.prefill(prompt)
-    budget = eng.nfp_budget()
+    eng.prefill_slots({0: prompt})                 # one request, slot 0
+    budget = eng.nfp_budget(ell=len(prompt))
     n = min(budget, 16)
-    logits = eng.decode_step(jax.random.randint(jax.random.PRNGKey(2),
-                                                (1, n), 0, small.vocab_size))
+    logits, _, _ = eng.decode_slots(jax.random.randint(
+        jax.random.PRNGKey(2), (1, n), 0, small.vocab_size))
     print(f"  tiny-model engine: budget={budget}, ran one decode forward "
           f"with N={n}, logits {logits.shape}")
 

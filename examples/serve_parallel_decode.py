@@ -3,19 +3,18 @@ speculative decoding vs diffusion-style block decoding on one model.
 
 Demonstrates the paper's capacity-normalized evaluation (Sec. J.2.3):
 the same system-side budget, different algorithm-side utilization.
+Each method serves the prompt through a ServingLoop with one slot.
 
 Run: PYTHONPATH=src python examples/serve_parallel_decode.py
 """
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
 from repro.models import init_model
-from repro.serving import (DecodeEngine, DiffusionBlockDecoder,
-                           SpeculativeDecoder)
+from repro.serving import DecodeEngine, ServingLoop
 
 TOKENS = 48
 
@@ -23,45 +22,40 @@ TOKENS = 48
 def main():
     cfg = get_config("stablelm_3b", reduced=True)
     params = init_model(jax.random.PRNGKey(0), cfg)
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 12), 0,
-                                cfg.vocab_size)
+    prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (12,), 0,
+                                           cfg.vocab_size))
 
     def fresh():
         return DecodeEngine(cfg, params, batch=1, max_len=512)
 
+    def serve(name, **loop_kw):
+        loop = ServingLoop(fresh(), **loop_kw)
+        loop.submit(prompt, TOKENS)
+        t0 = time.perf_counter()
+        toks = loop.run()[0]
+        dt = time.perf_counter() - t0
+        s = loop.stats()
+        print(f"{name:<16} {s['tokens']} tokens, {s['forwards']} forwards "
+              f"({s['tokens_per_forward']:.2f} tok/fwd, "
+              f"utilization {s['position_utilization']:.2f}), {dt:.2f}s")
+        return toks
+
     # --- AR baseline (N=1 per forward) ------------------------------------
-    eng = fresh()
     t0 = time.perf_counter()
-    ar = np.asarray(eng.greedy_generate(prompt, TOKENS)[0])
-    t_ar = time.perf_counter() - t0
+    ar = np.asarray(fresh().greedy_generate(prompt[None], TOKENS)[0])
     print(f"AR greedy:       {TOKENS} tokens, {TOKENS} forwards, "
-          f"{t_ar:.2f}s")
+          f"{time.perf_counter() - t0:.2f}s")
 
     # --- speculative, verification length from the NFP budget -------------
-    eng = fresh()
-    budget = eng.nfp_budget()
-    spec = SpeculativeDecoder(eng, gamma=min(budget - 1, 8))
-    t0 = time.perf_counter()
-    toks, stats = spec.generate(prompt, TOKENS)
-    t_spec = time.perf_counter() - t0
-    print(f"speculative:     {stats['tokens']} tokens, "
-          f"{stats['forwards']} forwards "
-          f"({stats['tokens_per_forward']:.2f} tok/fwd, "
-          f"utilization {stats['position_utilization']:.2f}), {t_spec:.2f}s")
-    print(f"  lossless vs AR: {bool(np.array_equal(ar, toks[:TOKENS]))}  "
+    budget = fresh().nfp_budget(ell=len(prompt))
+    toks = serve("speculative:", mode="speculative",
+                 max_width=min(budget, 9))
+    print(f"  lossless vs AR: {bool(np.array_equal(ar, toks))}  "
           f"(NFP budget={budget})")
 
     # --- diffusion-style block decode --------------------------------------
-    eng = fresh()
-    diff = DiffusionBlockDecoder(eng, block_size=min(budget - 1, 12),
-                                 refine_steps=3)
-    t0 = time.perf_counter()
-    dtoks, dstats = diff.generate(prompt, TOKENS)
-    t_diff = time.perf_counter() - t0
-    print(f"diffusion-block: {dstats['tokens']} tokens, "
-          f"{dstats['forwards']} forwards "
-          f"({dstats['tokens_per_forward']:.2f} tok/fwd, "
-          f"utilization {dstats['position_utilization']:.2f}), {t_diff:.2f}s")
+    serve("diffusion-block:", mode="diffusion",
+          block_size=min(budget - 1, 12), refine_steps=3)
     print("\ncapacity-normalized view: all methods spend positions from the"
           "\nsame near-free budget; tokens/forward is the algorithm-side"
           "\nutilization the paper separates from system capacity.")

@@ -1,10 +1,10 @@
 """Production serving launcher: multi-position decode with the NFP budget.
 
-Single-request (algorithm drivers):
+One request (a loop with one slot):
   PYTHONPATH=src python -m repro.launch.serve --arch stablelm_3b --tiny \
-      --algorithm speculative --tokens 48
+      --requests 1 --slots 1 --serve-mode speculative --tokens 48
 
-Multi-request (budget-aware continuous batching):
+Many requests (budget-aware continuous batching):
   PYTHONPATH=src python -m repro.launch.serve --arch stablelm_3b --tiny \
       --requests 8 --slots 4 --serve-mode speculative --tokens 32
 
@@ -14,9 +14,8 @@ Trace replay (production-shaped traffic on the simulated clock):
 
 Loads (or random-inits) a model, builds the decode engine, selects the
 parallelism level from the NFP principle for the current hardware +
-batch + context, and serves generation — one request through a
-parallel-decoding driver, or many through the ServingLoop scheduler
-that splits the budget across concurrent requests.
+batch + context, and serves generation through the ServingLoop
+scheduler, which splits the budget across concurrent requests.
 """
 from __future__ import annotations
 
@@ -33,37 +32,8 @@ from repro.configs import get_config
 from repro.core import get_hardware
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_model
-from repro.serving import (DecodeEngine, DiffusionBlockDecoder,
-                           MTPDecoder, PagedKVConfig, ServingLoop,
-                           SpeculativeDecoder, init_mtp_heads)
-
-
-def _single_request(args, cfg, params) -> None:
-    eng = DecodeEngine(cfg, params, batch=args.batch, max_len=args.max_len,
-                       hardware=get_hardware(args.hardware),
-                       use_kernel=args.use_kernel)
-    prompt = jax.random.randint(jax.random.PRNGKey(1),
-                                (args.batch, args.prompt_len), 0,
-                                cfg.vocab_size)
-    t0 = time.time()
-    if args.algorithm == "greedy":
-        out = np.asarray(eng.greedy_generate(prompt, args.tokens)[0])
-        stats = {"tokens": args.tokens, "forwards": args.tokens}
-    elif args.algorithm == "speculative":
-        out, stats = SpeculativeDecoder(eng).generate(prompt, args.tokens)
-    elif args.algorithm == "mtp":
-        heads = init_mtp_heads(jax.random.PRNGKey(5), cfg.d_model,
-                               cfg.vocab_size, n_heads=4)
-        out, stats = MTPDecoder(eng, heads).generate(prompt, args.tokens)
-    else:
-        out, stats = DiffusionBlockDecoder(eng).generate(prompt, args.tokens)
-    dt = time.time() - t0
-    print(f"arch={cfg.name} algo={args.algorithm} "
-          f"nfp_budget={eng.nfp_budget()}")
-    print(f"generated {stats['tokens']} tokens in {dt:.2f}s "
-          f"({stats.get('forwards', '?')} forwards, "
-          f"{stats.get('tokens_per_forward', 1):.2f} tok/fwd)")
-    print("tokens:", out[:32], "...")
+from repro.serving import (DecodeEngine, PagedKVConfig, ServingLoop,
+                           init_mtp_heads)
 
 
 def _calibration_controller(args, eng):
@@ -108,7 +78,8 @@ def _multi_request(args, cfg, params) -> None:
         controller = _calibration_controller(args, eng)
     loop = ServingLoop(eng, mode=args.serve_mode, controller=controller,
                        **kwargs)
-    for i in range(args.requests):
+    n_requests = max(args.requests, 1)
+    for i in range(n_requests):
         prompt = jax.random.randint(jax.random.PRNGKey(100 + i),
                                     (args.prompt_len,), 0, cfg.vocab_size)
         loop.submit(np.asarray(prompt), args.tokens)
@@ -120,7 +91,7 @@ def _multi_request(args, cfg, params) -> None:
     # step log rather than recomputing at an empty cache
     budgets = [e["budget"] for e in loop.step_log] or [loop.budget()]
     print(f"arch={cfg.name} mode={args.serve_mode} slots={args.slots} "
-          f"requests={args.requests} "
+          f"requests={n_requests} "
           f"nfp_budget={min(budgets)}..{max(budgets)}")
     print(f"served {s['requests']} requests / {s['tokens']} tokens in "
           f"{dt:.2f}s  ({s['forwards']} forwards, "
@@ -220,10 +191,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm_3b")
     ap.add_argument("--tiny", action="store_true")
-    ap.add_argument("--algorithm", default="speculative",
-                    choices=["greedy", "speculative", "diffusion", "mtp"])
     ap.add_argument("--tokens", type=int, default=48)
-    ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--max-len", type=int, default=512)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--hardware", default=None,
@@ -235,17 +203,18 @@ def main() -> None:
                     help="Pallas kernels: compiled on a TPU, run in the "
                          "Pallas interpreter elsewhere (CPU tests)")
     ap.add_argument("--requests", type=int, default=0,
-                    help="multi-request mode: serve N concurrent requests "
-                         "through the budget-aware scheduler")
+                    help="requests to serve through the budget-aware "
+                         "scheduler (0 serves one; trace mode reads 0 "
+                         "as 32)")
     ap.add_argument("--slots", type=int, default=4,
                     help="cache slots (max concurrent requests)")
     ap.add_argument("--serve-mode", default="greedy",
                     choices=["greedy", "speculative", "diffusion", "mtp"],
-                    help="scheduler mode for --requests")
+                    help="serving mode, for one request as for many")
     ap.add_argument("--kv-block-size", type=int, default=0,
                     help="paged KV cache block size in positions "
                          "(0 = dense per-slot cache); must divide "
-                         "--max-len; multi-request mode only")
+                         "--max-len")
     ap.add_argument("--kv-blocks", type=int, default=0,
                     help="paged KV pool size in blocks (0 = dense-"
                          "parity default: slots * max_len / block)")
@@ -272,14 +241,8 @@ def main() -> None:
         params = init_model(jax.random.PRNGKey(0), cfg)
         _trace_replay(args, cfg, params)
         return
-    if args.kv_block_size > 0 and args.requests <= 0:
-        ap.error("--kv-block-size serves the multi-request scheduler; "
-                 "add --requests N")
     if args.kv_blocks > 0 and args.kv_block_size <= 0:
         ap.error("--kv-blocks sizes the paged pool; add --kv-block-size")
-    if args.calibration != "off" and args.requests <= 0:
-        ap.error("--calibration tunes the multi-request scheduler; "
-                 "add --requests N")
 
     cfg = get_config(args.arch, reduced=args.tiny)
     params = init_model(jax.random.PRNGKey(0), cfg)
@@ -288,10 +251,7 @@ def main() -> None:
         params = restored["params"]
         print(f"loaded checkpoint from {args.ckpt_dir}")
 
-    if args.requests > 0:
-        _multi_request(args, cfg, params)
-    else:
-        _single_request(args, cfg, params)
+    _multi_request(args, cfg, params)
 
 
 if __name__ == "__main__":

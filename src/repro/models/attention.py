@@ -10,8 +10,8 @@ The decode path can route the attention core through the Pallas
 query-tiled kernel (``repro.kernels.decode_attention``) whose q-block IS
 the M_attn granularity of the NFP principle; the default XLA path is the
 semantically identical reference.  The kernel serves BOTH cache layouts:
-a scalar ``cache_len`` (single-request drivers, aligned rows) and a (b,)
-vector (the scheduler's slotted cache) go through the same ragged
+a scalar ``cache_len`` (aligned rows: the ``greedy_generate`` reference)
+and a (b,) vector (the scheduler's slotted cache) go through the same ragged
 entry — per-row lengths ride the kernel's scalar-prefetch lane, so
 mixed-length slots share one quantized launch.
 """
@@ -121,7 +121,7 @@ def _gqa_core(q: Array, k: Array, v: Array, mask: Array, scale: float) -> Array:
 
 def _row_offsets(cache_len, batch: int) -> Array:
     """Per-row cache lengths: a scalar ``cache_len`` (every row at the
-    same position — the single-request drivers) or a (b,) vector (the
+    same position — the ``greedy_generate`` reference) or a (b,) vector (the
     scheduler's slotted cache, each slot at its own length)."""
     cl = jnp.asarray(cache_len, jnp.int32)
     if cl.ndim == 0:

@@ -1,24 +1,19 @@
 """repro.serving — multi-position decode engine, the common parallel-
-decoding protocol (solo drivers + scheduler-side slot adapters), and the
-multi-request scheduler."""
-from repro.serving.algorithm import (DecodeStats, ParallelDecodeAlgorithm,
-                                     SlotAdapter)
-from repro.serving.diffusion import DiffusionBlockDecoder, DiffusionSlotAdapter
+decoding protocol (slot adapters), and the multi-request scheduler."""
+from repro.serving.algorithm import SlotAdapter
+from repro.serving.diffusion import DiffusionSlotAdapter
 from repro.serving.engine import DecodeEngine
-from repro.serving.mtp import (MTPDecoder, MTPSlotAdapter, init_mtp_heads,
-                               mtp_loss)
+from repro.serving.mtp import MTPSlotAdapter, init_mtp_heads, mtp_loss
 from repro.serving.paged import (BlockAllocator, BlockManager, PagedKVConfig,
                                  PrefixCache)
 from repro.serving.scheduler import (DEFAULT_SLO_CLASSES, AdmissionConfig,
                                      AdmissionRejected, Request, SLOClass,
                                      ServingLoop)
-from repro.serving.speculative import (SpeculativeDecoder,
-                                       SpeculativeSlotAdapter, ngram_draft)
+from repro.serving.speculative import SpeculativeSlotAdapter, ngram_draft
 
 __all__ = ["AdmissionConfig", "AdmissionRejected", "BlockAllocator",
-           "BlockManager", "DecodeEngine", "DecodeStats",
-           "DEFAULT_SLO_CLASSES", "ParallelDecodeAlgorithm", "PagedKVConfig",
-           "PrefixCache", "SLOClass", "SlotAdapter", "SpeculativeDecoder",
-           "SpeculativeSlotAdapter", "DiffusionBlockDecoder",
-           "DiffusionSlotAdapter", "MTPDecoder", "MTPSlotAdapter", "Request",
-           "ServingLoop", "init_mtp_heads", "mtp_loss", "ngram_draft"]
+           "BlockManager", "DecodeEngine", "DEFAULT_SLO_CLASSES",
+           "PagedKVConfig", "PrefixCache", "SLOClass", "SlotAdapter",
+           "SpeculativeSlotAdapter", "DiffusionSlotAdapter",
+           "MTPSlotAdapter", "Request", "ServingLoop", "init_mtp_heads",
+           "mtp_loss", "ngram_draft"]

@@ -6,160 +6,27 @@ loop: PROPOSE a block of candidate positions, VERIFY it with one (or a
 few) multi-position decode forwards (Eq. 2), COMMIT the accepted prefix
 to the KV cache.  The NFP budget caps the block width in every case
 (paper Sec. 6), so the driver machinery is algorithm-independent and
-lives here once, at BOTH serving granularities:
+lives here once, in ``SlotAdapter``: ``ServingLoop`` drives the protocol
+ROW-WISE — every active request fills its slot's row of ONE shared
+multi-position forward per step, and the NFP budget is split across the
+rows.  A single request is a loop with one slot, which gets the whole
+budget.
 
-  ``ParallelDecodeAlgorithm``  the batch=1 driver: one request owns the
-                               whole engine (and the whole budget).
-  ``SlotAdapter``              the scheduler-side adapter: the same
-                               propose → verify → commit protocol driven
-                               ROW-WISE by ``ServingLoop`` — every active
-                               request fills its slot's row of ONE shared
-                               multi-position forward per step, and the
-                               NFP budget is split across the rows.
-
-A new algorithm implements ``propose`` (and optionally ``resolve`` /
-``run_step`` when verification is not single-forward greedy acceptance,
-e.g. diffusion refinement) and inherits the rest; see
-``speculative.py`` / ``mtp.py`` / ``diffusion.py`` for the paired
-instantiations.
+A new algorithm implements ``propose`` (and optionally ``run_step``
+when verification is not single-forward greedy acceptance, e.g.
+diffusion refinement) and inherits the rest; see ``speculative.py`` /
+``mtp.py`` / ``diffusion.py``.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Dict, List, Tuple
+from typing import Dict, List
 
-import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.serving.engine import DecodeEngine, greedy_tokens
+from repro.serving.engine import greedy_tokens
 
-__all__ = ["DecodeStats", "ParallelDecodeAlgorithm", "SlotAdapter"]
-
-
-@dataclass
-class DecodeStats:
-    """Position/forward accounting — the quantities NFP normalizes
-    (paper Sec. J.2.3)."""
-
-    tokens: int = 0
-    forwards: int = 0
-    positions: int = 0
-
-    @property
-    def tokens_per_forward(self) -> float:
-        return self.tokens / max(self.forwards, 1)
-
-    @property
-    def position_utilization(self) -> float:
-        return self.tokens / max(self.positions, 1)
-
-    def as_dict(self) -> Dict:
-        return {
-            "tokens": self.tokens,
-            "forwards": self.forwards,
-            "positions": self.positions,
-            "tokens_per_forward": self.tokens_per_forward,
-            "position_utilization": self.position_utilization,
-        }
-
-
-@dataclass
-class ParallelDecodeAlgorithm:
-    """Propose -> verify -> commit driver over one DecodeEngine.
-
-    Subclass protocol:
-      parallel_width()        block width for the next step; the default
-                              spends the engine's NFP budget (reserving
-                              one position for the pending token).
-      propose(ctx, pending, n) length-n candidate block (np.int64).
-      resolve(pending, drafts) verify + commit; returns (committed
-                              tokens — now in the cache after
-                              ``pending`` — and the next pending token).
-                              Default: one multi-position forward with
-                              greedy prefix acceptance, which keeps the
-                              output stream identical to AR greedy.
-      begin(prompt, pending)  optional hook after target prefill
-                              (draft-model setup and the like).
-      observe(hidden, k)      optional hook: the verify forward's
-                              final-norm hidden states (1, n, d) plus
-                              the accepted index k whose logits produced
-                              the next pending token (MTP proposes from
-                              hidden[0, k]).
-    """
-
-    engine: DecodeEngine
-
-    def __post_init__(self):
-        self.stats = DecodeStats()
-
-    # ------------------------------------------------------------------
-    # protocol (overridable)
-    # ------------------------------------------------------------------
-    def parallel_width(self) -> int:
-        return max(1, self.engine.nfp_budget() - 1)
-
-    def begin(self, prompt: np.ndarray, pending: int) -> None:
-        pass
-
-    def observe(self, hidden, k: int) -> None:
-        pass
-
-    def propose(self, context: np.ndarray, pending: int,
-                n: int) -> np.ndarray:
-        raise NotImplementedError
-
-    def resolve(self, pending: int, drafts: np.ndarray
-                ) -> Tuple[List[int], int]:
-        """Greedy verification: accept the longest draft prefix the
-        target model reproduces, plus the model's own next token."""
-        block = np.concatenate([[pending], drafts]).astype(np.int64)
-        logits, new_cache, hidden = self.forward_block(block)
-        # argmax runs jitted on device; only the (n,) i32 winners cross
-        # to the host (the accept loop below is inherently host-side)
-        preds = np.asarray(greedy_tokens(logits[0]))  # analysis: allow-host-sync
-        k = 0
-        while k < len(drafts) and preds[k] == drafts[k]:
-            k += 1
-        self.engine.commit(new_cache, 1 + k)
-        self.observe(hidden, k)
-        return list(drafts[:k]), int(preds[k])
-
-    # ------------------------------------------------------------------
-    # shared machinery
-    # ------------------------------------------------------------------
-    def forward_block(self, block: np.ndarray):
-        """One multi-position decode forward over ``block`` WITHOUT
-        committing; tracks forward/position stats.  Returns
-        (logits, new_cache, hidden)."""
-        eng = self.engine
-        toks = jnp.broadcast_to(jnp.asarray(block[None], jnp.int32),
-                                (eng.batch, len(block)))
-        logits, new_cache, hidden = eng.peek_step(toks)
-        self.stats.forwards += 1
-        self.stats.positions += len(block)
-        return logits, new_cache, hidden
-
-    def generate(self, prompt, max_tokens: int
-                 ) -> Tuple[np.ndarray, Dict]:
-        """Greedy generation (batch=1 driver).  Returns (tokens, stats)."""
-        eng = self.engine
-        self.stats = DecodeStats()
-        logits = eng.prefill(prompt)
-        pending = int(jnp.argmax(logits[0]))
-        context = np.asarray(prompt[0]).astype(np.int64)
-        generated: List[int] = [pending]
-        self.begin(np.asarray(prompt), pending)
-        while len(generated) < max_tokens:
-            n = min(self.parallel_width(), max_tokens - len(generated))
-            drafts = self.propose(context, pending, n)
-            committed, next_pending = self.resolve(pending, drafts)
-            context = np.concatenate(
-                [context, [pending], committed]).astype(np.int64)
-            generated.extend(list(committed) + [next_pending])
-            pending = next_pending
-        self.stats.tokens = len(generated)
-        return np.asarray(generated[:max_tokens]), self.stats.as_dict()
+__all__ = ["SlotAdapter"]
 
 
 class SlotAdapter:
@@ -171,7 +38,7 @@ class SlotAdapter:
     pending token (plus optional per-row drafts from ``propose``) rides
     ONE shared multi-position forward, and each row greedily accepts its
     longest reproduced draft prefix, which keeps every stream
-    byte-identical to solo greedy decoding.
+    byte-identical to AR greedy decoding (``greedy_generate``).
 
     Subclass protocol:
       width(n_active, budget)  per-request block width for this step —

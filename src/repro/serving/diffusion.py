@@ -13,17 +13,16 @@ size is the parallelism knob the NFP budget governs (paper Sec. 6:
 KV-commit discipline: refinement forwards see MASK tokens at unresolved
 positions, so their cache is POISON — a position resolved during (or
 after) the final iteration would commit KV computed from a mask-token
-input.  Both drivers therefore run one extra forward over the fully
-resolved block and commit THAT cache, making the committed KV
+input.  Both adapters therefore run one extra forward over the fully
+resolved blocks and commit THAT cache, making the committed KV
 byte-identical to prefilling the resolved tokens
 (``tests/test_serving_modes.py::test_diffusion_committed_kv_matches_prefill``).
 
-Under the common protocol: ``propose`` emits the mask block and
-``resolve`` replaces the single-forward greedy verification with the
-iterative refinement loop; ``DiffusionSlotAdapter`` runs the same
-refinement over MANY requests at once — each refinement iteration is
-ONE shared multi-position forward whose width still fits the NFP budget
-split across the active rows.
+Under the common protocol, ``DiffusionSlotAdapter`` replaces the
+single-forward greedy verification (``run_step``) with the iterative
+refinement loop over every active request at once — each refinement
+iteration is ONE shared multi-position forward whose width still fits
+the NFP budget split across the active rows.
 
 Block diffusion (``BlockDiffusionSlotAdapter``, SDAR): blocks of the
 model's own length L are aligned to absolute positions; a masked
@@ -38,18 +37,14 @@ computed on device (``token_choice``): the logits never leave it.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.serving.algorithm import ParallelDecodeAlgorithm, SlotAdapter
-from repro.serving.engine import DecodeEngine
-
-Array = jax.Array
+from repro.serving.algorithm import SlotAdapter
 
 
 @jax.jit
@@ -75,65 +70,6 @@ def refine_block(block: np.ndarray, resolved: np.ndarray, preds: np.ndarray,
     pick = order[:per_iter]
     block[pick] = preds[:n][pick]
     resolved[pick] = True
-
-
-@dataclass
-class DiffusionBlockDecoder(ParallelDecodeAlgorithm):
-    engine: DecodeEngine
-    block_size: Optional[int] = None     # None -> NFP budget
-    refine_steps: int = 4
-    mask_id: Optional[int] = None        # None -> vocab_size - 1
-
-    def __post_init__(self):
-        super().__post_init__()
-        if self.refine_steps < 1:
-            raise ValueError(f"refine_steps must be >= 1, "
-                             f"got {self.refine_steps}")
-
-    def _block(self) -> int:
-        if self.block_size is not None:
-            return self.block_size
-        return max(1, self.engine.nfp_budget() - 1)
-
-    parallel_width = _block
-
-    def _mask_id(self) -> int:
-        if self.mask_id is not None:
-            return self.mask_id
-        return self.engine.cfg.vocab_size - 1
-
-    def propose(self, context: np.ndarray, pending: int,
-                n: int) -> np.ndarray:
-        return np.full((n,), self._mask_id(), np.int64)
-
-    def resolve(self, pending: int, drafts: np.ndarray
-                ) -> Tuple[List[int], int]:
-        """Iterative refinement: each forward re-predicts the block and
-        the most confident still-masked positions freeze.  A FINAL
-        forward over the fully-resolved block produces the cache that
-        commits — the refinement forwards' caches hold KV computed from
-        mask-token inputs and must never reach the engine."""
-        n = len(drafts)
-        block = np.asarray(drafts, np.int64).copy()
-        resolved = np.zeros((n,), bool)
-        per_iter = max(1, int(np.ceil(n / self.refine_steps)))
-        preds = None
-        for _ in range(self.refine_steps):
-            if resolved.all():
-                break
-            step_logits, _, _ = self.forward_block(
-                np.concatenate([[pending], block]))
-            preds, conf = (np.asarray(a)
-                           for a in token_choice(step_logits[0]))
-            refine_block(block, resolved, preds, conf, per_iter)
-        if not resolved.all():
-            block[~resolved] = preds[:n][~resolved]
-        # commit forward: KV for [pending] + block[:-1] computed from the
-        # RESOLVED tokens (byte-identical to prefilling them)
-        _, new_cache, _ = self.forward_block(
-            np.concatenate([[pending], block]))
-        self.engine.commit(new_cache, n)
-        return list(block[:-1]), int(block[-1])
 
 
 class DiffusionSlotAdapter(SlotAdapter):

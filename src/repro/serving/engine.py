@@ -2,12 +2,13 @@
 
 The engine executes the paper's abstraction directly: a decode forward
 that processes N positions (Eq. 2) over a pre-allocated cache.  One
-compiled executable serves every step at a given N (cache_len is a traced
-scalar), matching the bucketed-compile discipline of TPU serving stacks.
+compiled executable serves every step at a given N (the per-slot cache
+lengths are traced), matching the bucketed-compile discipline of TPU
+serving stacks.
 
-The NFP budget (core.parallelism_budget) tells algorithm drivers
-(speculative verification, diffusion block decode) how many positions are
-near-free for the current arch x hardware x batch x context.
+The NFP budget (core.parallelism_budget) tells the scheduler and its
+slot adapters (speculative verification, diffusion block decode) how many
+positions are near-free for the current arch x hardware x batch x context.
 """
 from __future__ import annotations
 
@@ -127,9 +128,10 @@ class DecodeEngine:
     pool (``init_paged_cache``) shared by all slots through the
     ``BlockManager``'s per-slot block tables, and admissions whose
     prompt prefix is already resident skip prefill for the shared
-    blocks.  Paged mode serves attention-only archs via the slotted API
-    (``prefill_slots``/``decode_slots``/``commit_slots``); the
-    single-request scalar-``cache_len`` drivers stay dense."""
+    blocks.  Paged mode serves attention-only archs.  Either way the
+    engine is driven through its slotted API
+    (``prefill_slots``/``decode_slots``/``commit_slots``); one request
+    is one slot."""
 
     cfg: ArchConfig
     params: Dict
@@ -139,12 +141,6 @@ class DecodeEngine:
     hardware: Optional[HardwareSpec] = None
     use_kernel: bool = False
     cache: Optional[Dict] = None
-    # committed positions of the single-request drivers.  A HOST int on
-    # purpose: every step's budget/width decision reads it, and a device
-    # scalar here cost one blocking device->host sync per decode step
-    # (it is re-uploaded as a traced scalar by the jitted forwards, which
-    # is cheap and non-blocking in the other direction).
-    cache_len: int = 0
     paged: Optional[PagedKVConfig] = None
 
     def __post_init__(self):
@@ -172,27 +168,17 @@ class DecodeEngine:
             head_dim=(self.cfg.attention.head_dim if self.cfg.attention
                       else 128),
             kv_page=(self.paged.block_size if self.paged else 0))
-        # per-slot cache lengths for the scheduler's slotted mode; the
-        # single-request drivers keep using the scalar ``cache_len``.
-        # ``slot_lens`` rides the jitted decode forwards (per-row ragged
-        # lengths), ``slot_lens_host`` is its host-side mirror: every
-        # update comes from host values (prompt lengths, accepted
-        # counts), so the scheduler's budget/admission math never has to
-        # block on a device read mid-decode.
+        # per-slot cache lengths.  ``slot_lens`` rides the jitted decode
+        # forwards (per-row ragged lengths), ``slot_lens_host`` is its
+        # host-side mirror: every update comes from host values (prompt
+        # lengths, accepted counts), so the scheduler's budget/admission
+        # math never has to block on a device read mid-decode.
         self.slot_lens = jnp.zeros((self.batch,), jnp.int32)
         self.slot_lens_host = np.zeros((self.batch,), np.int64)
         self._bt_device: Optional[Array] = None
-        # (b, d) final-norm hidden of the last prefilled position (MTP
-        # proposals read it); one entry per bucketed prefill forward
-        self.last_hidden: Optional[Array] = None
+        # one entry per bucketed prefill forward
         self.prefill_log: List[Dict] = []
         self.preempted_slots = 0               # preempt_slot() evictions
-
-    def _require_dense(self, what: str) -> None:
-        if self.manager is not None:
-            raise RuntimeError(
-                f"{what} drives the aligned dense cache; a paged engine "
-                "serves through prefill_slots/decode_slots/commit_slots")
 
     def _device_tables(self) -> Array:
         """Device copy of the block tables, cached between admissions —
@@ -203,67 +189,21 @@ class DecodeEngine:
         return self._bt_device
 
     # ------------------------------------------------------------------
-    def nfp_budget(self, eps: float = 0.2, routing: str = "balanced",
-                   ell: Optional[int] = None) -> int:
-        """Near-free position budget for the CURRENT state (Sec. 6).
+    def nfp_budget(self, eps: float = 0.2, routing: str = "balanced", *,
+                   ell: int) -> int:
+        """Near-free position budget at context length ``ell`` (Sec. 6).
 
-        Pure host math: ``cache_len`` is the host-side committed length,
-        so a per-step budget query costs no device synchronization."""
-        if ell is None:
-            ell = self.cache_len
-        ell = max(int(ell), 1)
+        Pure host math: callers pass a host-side length (the scheduler
+        reads ``slot_lens_host``), so a per-step budget query costs no
+        device synchronization."""
         return parallelism_budget(self.cfg, self.hardware, self.gran,
-                                  self.batch, ell, eps, routing)
+                                  self.batch, max(int(ell), 1), eps, routing)
 
     # ------------------------------------------------------------------
-    def prefill(self, tokens: Array) -> Array:
-        """tokens: (b, prompt_len).  Returns last-position logits.
-
-        ``self.last_hidden`` holds the (b, d) final-norm hidden state of
-        the last prompt position — the state auxiliary head banks (MTP)
-        propose from."""
-        self._require_dense("prefill")
-        logits, self.cache, hidden = _prefill_fn(self.params, self.cfg,
-                                                 tokens, self.cache, None,
-                                                 self.use_kernel)
-        self.cache_len = int(tokens.shape[1])
-        self.last_hidden = hidden[:, -1]
-        return logits[:, -1]
-
-    def decode_step(self, tokens: Array, advance: Optional[int] = None
-                    ) -> Array:
-        """One multi-position decode forward over N = tokens.shape[1]
-        positions.  ``advance`` = how many of the N positions to commit to
-        the cache (speculative decoding commits only accepted tokens);
-        default commits all N."""
-        self._require_dense("decode_step")
-        logits, new_cache, _ = _decode_fn(self.params, self.cfg, tokens,
-                                          self.cache, self.cache_len,
-                                          self.use_kernel)
-        n = tokens.shape[1]
-        adv = n if advance is None else advance
-        if adv > 0:
-            self.cache = new_cache
-            self.cache_len = self.cache_len + int(adv)
-        return logits
-
-    def peek_step(self, tokens: Array) -> Tuple[Array, Dict, Array]:
-        """Decode forward WITHOUT committing (verification forwards).
-        Returns (logits, new_cache, hidden)."""
-        self._require_dense("peek_step")
-        return _decode_fn(self.params, self.cfg, tokens, self.cache,
-                          self.cache_len, self.use_kernel)
-
-    def commit(self, new_cache: Dict, n_accepted) -> None:
-        self._require_dense("commit")
-        self.cache = new_cache
-        self.cache_len = self.cache_len + int(n_accepted)
-
-    # ------------------------------------------------------------------
-    # Slotted multi-request mode (repro.serving.scheduler).  Each batch
-    # row is an independent cache slot at its own sequence length; the
-    # scheduler multiplexes requests over slots and the NFP budget over
-    # the per-forward positions.
+    # Slotted mode (repro.serving.scheduler).  Each batch row is an
+    # independent cache slot at its own sequence length; the scheduler
+    # multiplexes requests over slots and the NFP budget over the
+    # per-forward positions.
     # ------------------------------------------------------------------
     def _row_mask(self, rows, like: Array) -> Array:
         m = jnp.zeros((self.batch,), bool).at[jnp.asarray(rows)].set(True)
@@ -487,11 +427,6 @@ class DecodeEngine:
             mgr.register_prompt(s, tok_host[s].tolist())
         return out
 
-    def prefill_slot(self, slot: int, prompt: Array) -> Array:
-        """Prefill ONE cache slot; thin wrapper over ``prefill_slots``."""
-        (logits, _hidden) = self.prefill_slots({slot: prompt})[slot]
-        return logits
-
     def decode_slots(self, tokens: Array) -> Tuple[Array, Dict, Array]:
         """Multi-position decode forward over ALL slots at their own
         cache lengths, WITHOUT committing.  tokens: (batch, n).
@@ -571,12 +506,18 @@ class DecodeEngine:
 
     # ------------------------------------------------------------------
     def greedy_generate(self, prompt: Array, steps: int) -> Array:
-        """Plain autoregressive baseline (N=1 per forward)."""
-        logits = self.prefill(prompt)
-        last = jnp.argmax(logits, axis=-1)[:, None]
+        """Plain autoregressive reference (N=1 per forward): ``steps``
+        greedy tokens after each row of ``prompt`` (b, p).  It runs on a
+        dense cache of its own and reads or writes no engine state."""
+        b, p = prompt.shape
+        cache = init_cache(self.cfg, b, self.max_len)
+        logits, cache, _ = _prefill_fn(self.params, self.cfg, prompt, cache,
+                                       None, self.use_kernel)
+        last = jnp.argmax(logits[:, -1], axis=-1)[:, None]
         out = [last]
-        for _ in range(steps - 1):
-            logits = self.decode_step(last)
+        for i in range(steps - 1):
+            logits, cache, _ = _decode_fn(self.params, self.cfg, last, cache,
+                                          p + i, self.use_kernel)
             last = jnp.argmax(logits[:, -1], axis=-1)[:, None]
             out.append(last)
         return jnp.concatenate(out, axis=1)

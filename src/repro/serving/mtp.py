@@ -6,30 +6,28 @@ future offset, trained against shifted targets) proposes the next
 ``n_heads`` tokens from the LAST hidden state; the base model then
 verifies them with ONE multi-position decode forward — identical system
 structure to speculative decoding (the inherited propose -> verify ->
-commit driver), but the draft is a model component rather than a
-separate model, so the NFP budget directly caps the useful number of
-MTP heads (paper Sec. 6: "MTP prediction length").
+commit drive of ``SlotAdapter``), but the draft is a model component
+rather than a separate model, so the NFP budget directly caps the useful
+number of MTP heads (paper Sec. 6: "MTP prediction length").
 
 The proposal input is the REAL final-norm hidden state threaded out of
-``models.transformer.forward``: the prefill hands over the last prompt
-position's state, and every verify forward hands over the state at the
-accepted index whose logits produced the new pending token — exactly
-the state the heads were trained against (``mtp_loss``).
+``models.transformer.forward``: a slot's prefill hands over its last
+prompt position's state, and every verify forward hands over the state
+at the accepted index whose logits produced the new pending token —
+exactly the state the heads were trained against (``mtp_loss``).
 
 Greedy acceptance keeps output identical to AR greedy decoding.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers import _init
-from repro.serving.algorithm import ParallelDecodeAlgorithm, SlotAdapter
-from repro.serving.engine import DecodeEngine
+from repro.serving.algorithm import SlotAdapter
 
 Array = jax.Array
 
@@ -71,40 +69,6 @@ def mtp_loss(heads: Dict, hidden: Array, tokens: Array) -> Array:
         gold = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
         total = total + jnp.mean(lse - gold)
     return total / n_heads
-
-
-@dataclass
-class MTPDecoder(ParallelDecodeAlgorithm):
-    """MTP generation: propose with the head bank from the real last
-    hidden state, verify with one multi-position forward, accept
-    greedily (lossless vs AR greedy)."""
-
-    engine: DecodeEngine
-    heads: Dict
-    n_predict: Optional[int] = None      # None -> min(n_heads, NFP budget-1)
-
-    def _n(self) -> int:
-        bank = self.heads["heads"].shape[0]
-        if self.n_predict is not None:
-            return min(self.n_predict, bank)
-        return max(1, min(bank, self.engine.nfp_budget() - 1))
-
-    parallel_width = _n
-
-    def begin(self, prompt: np.ndarray, pending: int) -> None:
-        # the engine's prefill just produced ``pending`` from the last
-        # prompt position's hidden state — propose offsets +2.. from it
-        self._hidden = self.engine.last_hidden[0]
-
-    def observe(self, hidden, k: int) -> None:
-        # logits row k of the verify forward produced the new pending
-        # token, so hidden row k is the state to propose from next
-        self._hidden = hidden[0, k]
-
-    def propose(self, context: np.ndarray, pending: int,
-                n: int) -> np.ndarray:
-        return np.asarray(mtp_propose(self.heads, self._hidden[None])
-                          )[0][:n].astype(np.int64)
 
 
 class MTPSlotAdapter(SlotAdapter):

@@ -1,14 +1,15 @@
 """Budget-aware continuous-batching scheduler over one DecodeEngine.
 
 The paper's Sec. 6 reads N_max(eps) as a deployment knob: how many
-decode positions one forward can carry near-free.  A single-request
-driver spends that budget on ONE request's verification length / block
-size; the scheduler spends it across MANY concurrent requests — the
-"system-side parallelism selection" the NFP principle enables:
+decode positions one forward can carry near-free.  The scheduler spends
+that budget across the concurrent requests — the "system-side
+parallelism selection" the NFP principle enables.  A lone request (a
+loop with one slot, or the only active one) gets all of it as its
+verification length / block size:
 
   - each request owns a SLOT (one batch row) of the engine's
-    pre-allocated cache, at its own sequence length (per-slot
-    ``cache_len`` threading through the decode forward),
+    pre-allocated cache, at its own sequence length (per-slot cache
+    lengths threading through the decode forward),
   - admission keeps the active set small enough that every request gets
     at least one position inside the budget; the rest queue.  Newly
     admitted requests are prefilled TOGETHER: prompts are padded to a
@@ -32,12 +33,12 @@ propose → verify → commit protocol (``serving.algorithm``):
                last hidden state, one shared verify forward (lossless),
   diffusion    per-request mask-block refinement where every refinement
                iteration is one shared forward and a final shared
-               forward commits clean KV (matches the solo driver's
-               token stream per request).
+               forward commits clean KV.
 
 Greedy/speculative/mtp streams are identical to running each request
-alone through ``DecodeEngine.greedy_generate``; diffusion streams are
-identical to the solo ``DiffusionBlockDecoder`` at the same block size.
+alone through ``DecodeEngine.greedy_generate``; a diffusion stream is
+identical to the one its request gets alone in a one-slot loop at the
+same block size.
 
 Load-pressure policies (``repro.loadgen`` drives them under traced
 traffic):
@@ -182,7 +183,7 @@ class ServingLoop:
 
     ``mode`` selects the per-slot algorithm adapter (see module
     docstring); a custom ``SlotAdapter`` subclass instance can be
-    plugged in directly via ``adapter=`` (it receives this loop).
+    plugged in directly via ``adapter=`` (it is bound to this loop).
     ``mtp_heads`` feeds the mtp adapter; ``block_size`` /
     ``refine_steps`` / ``mask_id`` feed the diffusion adapter, which is
     SDAR's block-diffusion sampler for a model with block-causal
@@ -241,6 +242,7 @@ class ServingLoop:
                 else:
                     raise ValueError(f"{engine.cfg.name} generates blocks "
                                      f"of {trained}, not {block_size}")
+        adapter.loop = self
         self.adapter = adapter
         self.mode = adapter.mode
         if controller is not None:

@@ -1,4 +1,4 @@
-"""Speculative decoding driver — consumes the NFP position budget.
+"""Speculative decoding — consumes the NFP position budget.
 
 The verification forward IS a multi-position decode forward (paper
 Sec. G.1: "the verification forward in speculative decoding ... shares
@@ -6,27 +6,15 @@ the same multi-position decode paradigm").  The NFP principle supplies
 the system-side budget for the verification length gamma: pushing gamma
 past N_max(eps) buys tokens at super-linear latency cost.
 
-Two draft sources:
-  - ngram: suffix-match lookup in the already-generated context (free),
-  - draft engine: a second (smaller) DecodeEngine, kept cache-coherent
-    with the committed stream by rolling accepted tokens forward (the
-    catch-up tokens ride in the same decode forward that starts the
-    next draft, so resync costs no extra forwards).
-Greedy acceptance keeps the output identical to AR greedy decoding.
+Drafts come from ``ngram_draft``: a suffix-match lookup in the request's
+already-generated context (free).  Greedy acceptance keeps the output
+identical to AR greedy decoding.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import List, Optional
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.serving.algorithm import ParallelDecodeAlgorithm, SlotAdapter
-from repro.serving.engine import DecodeEngine
-
-Array = jax.Array
+from repro.serving.algorithm import SlotAdapter
 
 
 def ngram_draft(context: np.ndarray, gamma: int, max_order: int = 3,
@@ -50,69 +38,6 @@ def ngram_draft(context: np.ndarray, gamma: int, max_order: int = 3,
         out.append(int(prop) % vocab_size)
         ctx.append(out[-1])
     return np.asarray(out, np.int64)
-
-
-@dataclass
-class SpeculativeDecoder(ParallelDecodeAlgorithm):
-    engine: DecodeEngine
-    draft_engine: Optional[DecodeEngine] = None
-    gamma: Optional[int] = None        # verification length; None -> NFP
-
-    def _gamma(self) -> int:
-        if self.gamma is not None:
-            return self.gamma
-        # NFP budget covers the whole forward: gamma drafts + 1 pending
-        return max(1, self.engine.nfp_budget() - 1)
-
-    parallel_width = _gamma
-
-    # ------------------------------------------------------------------
-    def begin(self, prompt: np.ndarray, pending: int) -> None:
-        if self.draft_engine is not None:
-            self.draft_engine.prefill(jnp.asarray(prompt, jnp.int32))
-            # tokens whose KV the draft cache holds, in stream order
-            self._draft_tokens: List[int] = [int(t) for t in prompt[0]]
-
-    def _draft_propose(self, full: np.ndarray, gamma: int) -> np.ndarray:
-        """Draft gamma tokens, first resyncing the draft KV cache.
-
-        ``full`` is the canonical stream (committed context + pending).
-        The draft cache holds KV for ``self._draft_tokens``; the shared
-        prefix stays, the divergent tail (rejected drafts) is dropped by
-        truncating cache_len, and the missing tokens — at minimum the
-        pending token, plus any accepted-but-unseen drafts — are fed in
-        ONE multi-position catch-up forward whose last logits already
-        give the first draft."""
-        draft = self.draft_engine
-        sync = 0
-        for a, b in zip(self._draft_tokens, full):
-            if a != int(b):
-                break
-            sync += 1
-        draft.cache_len = sync
-        self._draft_tokens = self._draft_tokens[:sync]
-        chunk = np.asarray(full[sync:], np.int64)       # >= 1: pending is new
-        toks = jnp.broadcast_to(jnp.asarray(chunk[None], jnp.int32),
-                                (draft.batch, len(chunk)))
-        logits = draft.decode_step(toks)
-        self._draft_tokens.extend(int(t) for t in chunk)
-        out = []
-        last = jnp.argmax(logits[:, -1], axis=-1)[:, None]
-        for _ in range(gamma):
-            out.append(int(last[0, 0]))
-            if len(out) == gamma:
-                break
-            logits = draft.decode_step(last.astype(jnp.int32))
-            self._draft_tokens.append(out[-1])
-            last = jnp.argmax(logits[:, -1], axis=-1)[:, None]
-        return np.asarray(out, np.int64)
-
-    def propose(self, context: np.ndarray, pending: int,
-                n: int) -> np.ndarray:
-        full = np.append(context, pending)
-        if self.draft_engine is not None:
-            return self._draft_propose(full, n)
-        return ngram_draft(full, n, vocab_size=self.engine.cfg.vocab_size)
 
 
 class SpeculativeSlotAdapter(SlotAdapter):

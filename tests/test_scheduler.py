@@ -1,7 +1,6 @@
 """Budget-aware multi-request scheduler: losslessness vs solo greedy
 decoding, NFP position-budget enforcement, continuous batching, and the
-unified ParallelDecodeAlgorithm protocol (incl. the draft-cache resync
-fix in the speculative driver)."""
+unified SlotAdapter protocol behind every serving mode."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,9 +8,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import init_model
-from repro.serving import (DecodeEngine, DiffusionBlockDecoder, MTPDecoder,
-                           ParallelDecodeAlgorithm, ServingLoop,
-                           SpeculativeDecoder, init_mtp_heads)
+from repro.serving import (DecodeEngine, ServingLoop, SlotAdapter,
+                           init_mtp_heads)
 
 KEY = jax.random.PRNGKey(0)
 TOKENS = 16
@@ -109,30 +107,14 @@ def test_slot_isolation_prefill_does_not_clobber(setup):
     assert lens_after[2] == len(prompts[2]) + 1
 
 
-def test_all_drivers_implement_protocol(setup):
-    cfg, params, _, _ = setup
+@pytest.mark.parametrize("mode", ServingLoop.MODES)
+def test_all_drivers_implement_protocol(setup, mode):
+    cfg, params, prompts, _ = setup
     eng = DecodeEngine(cfg, params, batch=1, max_len=256)
     heads = init_mtp_heads(jax.random.PRNGKey(5), cfg.d_model,
                            cfg.vocab_size, n_heads=4)
-    drivers = [SpeculativeDecoder(eng), DiffusionBlockDecoder(eng),
-               MTPDecoder(eng, heads)]
-    for d in drivers:
-        assert isinstance(d, ParallelDecodeAlgorithm)
-        assert d.parallel_width() >= 1
-
-
-def test_draft_engine_cache_stays_synced(setup):
-    """The draft-cache desync fix: with the draft sharing the target's
-    weights, a coherent draft cache makes every draft token the AR
-    continuation — full acceptance, gamma+1 tokens per forward."""
-    cfg, params, prompts, refs = setup
-    prompt = jnp.asarray(prompts[0])[None]
-    gamma = 4
-    eng = DecodeEngine(cfg, params, batch=1, max_len=256)
-    draft = DecodeEngine(cfg, params, batch=1, max_len=256)
-    dec = SpeculativeDecoder(eng, draft_engine=draft, gamma=gamma)
-    toks, stats = dec.generate(prompt, TOKENS)
-    assert np.array_equal(refs[0], toks[:TOKENS])     # lossless
-    # full acceptance every round (a desynced draft cache collapses this
-    # to ~1-2 tokens/forward); the last round may get a smaller gamma
-    assert stats["tokens_per_forward"] >= gamma
+    loop = ServingLoop(eng, mode=mode, mtp_heads=heads)
+    assert isinstance(loop.adapter, SlotAdapter)
+    assert loop.adapter.mode == mode
+    budget = eng.nfp_budget(loop.eps, ell=len(prompts[0]))
+    assert loop.adapter.width(1, budget) >= 1

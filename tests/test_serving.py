@@ -7,8 +7,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import init_model
-from repro.serving import (DecodeEngine, DiffusionBlockDecoder,
-                           SpeculativeDecoder, ngram_draft)
+from repro.serving import DecodeEngine, ServingLoop, ngram_draft
 
 KEY = jax.random.PRNGKey(0)
 
@@ -25,44 +24,54 @@ def dense_setup():
     return cfg, params, prompt
 
 
+def _solo(cfg, params, prompt, tokens, **loop_kw):
+    """``prompt`` (1, p) served alone: a loop with one slot."""
+    eng = DecodeEngine(cfg, params, batch=1, max_len=256)
+    loop = ServingLoop(eng, **loop_kw)
+    loop.submit(np.asarray(prompt[0]), tokens)
+    return loop.run()[0], loop
+
+
 def test_engine_multi_position_step(dense_setup):
     cfg, params, prompt = dense_setup
     eng = DecodeEngine(cfg, params, batch=1, max_len=128)
-    eng.prefill(prompt)
-    logits = eng.decode_step(jax.random.randint(KEY, (1, 4), 0,
-                                                cfg.vocab_size))
+    eng.prefill_slots({0: prompt[0]})
+    logits, new_cache, _ = eng.decode_slots(
+        jax.random.randint(KEY, (1, 4), 0, cfg.vocab_size))
+    eng.commit_slots(new_cache, np.asarray([4]))
     assert logits.shape == (1, 4, cfg.vocab_size)
-    assert int(eng.cache_len) == prompt.shape[1] + 4
+    assert eng.slot_lens_host[0] == prompt.shape[1] + 4
 
 
-def test_speculative_matches_ar_greedy(dense_setup):
+@pytest.mark.parametrize("gamma", [2, 4, 7])
+def test_speculative_matches_ar_greedy(dense_setup, gamma):
     """Greedy speculative decoding is LOSSLESS: identical to AR greedy."""
     cfg, params, prompt = dense_setup
     eng = DecodeEngine(cfg, params, batch=1, max_len=256)
     ar = np.asarray(eng.greedy_generate(prompt, 24)[0])
-    for gamma in (2, 4, 7):
-        eng2 = DecodeEngine(cfg, params, batch=1, max_len=256)
-        toks, stats = SpeculativeDecoder(eng2, gamma=gamma).generate(
-            prompt, 24)
-        assert np.array_equal(ar, toks[:24]), gamma
-        assert stats["tokens_per_forward"] >= 1.0
+    toks, loop = _solo(cfg, params, prompt, 24, mode="speculative",
+                       max_width=gamma + 1)
+    assert np.array_equal(ar, toks[:24]), gamma
+    assert loop.stats()["tokens_per_forward"] >= 1.0
 
 
 def test_speculative_uses_nfp_budget(dense_setup):
     cfg, params, prompt = dense_setup
     eng = DecodeEngine(cfg, params, batch=1, max_len=256)
-    spec = SpeculativeDecoder(eng)          # gamma=None -> NFP budget
-    budget = eng.nfp_budget()
-    assert spec._gamma() == max(1, budget - 1)
+    loop = ServingLoop(eng, mode="speculative")
+    budget = eng.nfp_budget(ell=prompt.shape[1])
+    # a lone request's verification forward spends the whole NFP budget
+    # (pending + drafts), up to the loop's width cap
+    assert loop.adapter.width(1, budget) == max(1, min(budget,
+                                                       loop.max_width))
 
 
 def test_diffusion_block_decode(dense_setup):
     cfg, params, prompt = dense_setup
-    eng = DecodeEngine(cfg, params, batch=1, max_len=256)
-    dec = DiffusionBlockDecoder(eng, block_size=8, refine_steps=2)
-    toks, stats = dec.generate(prompt, 16)
+    toks, loop = _solo(cfg, params, prompt, 16, mode="diffusion",
+                       block_size=8, refine_steps=2)
     assert len(toks) == 16
-    assert stats["tokens_per_forward"] > 1.5   # block parallelism realized
+    assert loop.stats()["tokens_per_forward"] > 1.5   # block parallelism
     mask_id = cfg.vocab_size - 1
     assert not np.any(toks == mask_id)         # everything resolved
 
@@ -79,8 +88,7 @@ def test_nfp_budget_tracks_batch(dense_setup):
     budgets = []
     for b in (1, 4):
         eng = DecodeEngine(cfg, params, batch=b, max_len=64)
-        eng.cache_len = jnp.asarray(32, jnp.int32)
-        budgets.append(eng.nfp_budget())
+        budgets.append(eng.nfp_budget(ell=32))
     assert budgets[0] >= budgets[1]
 
 
@@ -91,9 +99,8 @@ def test_moe_engine_budget_routing_cases():
     cfg = get_config("llada_mini_like", reduced=True)
     params = init_model(KEY, cfg)
     eng = DecodeEngine(cfg, params, batch=1, max_len=64)
-    eng.cache_len = jnp.asarray(16, jnp.int32)
-    bal = eng.nfp_budget(routing="balanced")
-    skew = eng.nfp_budget(routing="skewed")
+    bal = eng.nfp_budget(routing="balanced", ell=16)
+    skew = eng.nfp_budget(routing="skewed", ell=16)
     assert skew <= bal                          # paper: skew = lower bound
     # full-size MoE (E=256, k=8): strict separation, paper Sec. 5.2
     from repro.core import GranularitySpec, TPU_V5E, predict_model
@@ -107,18 +114,17 @@ def test_moe_engine_budget_routing_cases():
 def test_mtp_decoder_lossless_and_budgeted(dense_setup):
     """MTP verification forward = multi-position decode; greedy acceptance
     keeps the stream identical to AR greedy."""
-    from repro.serving import MTPDecoder, init_mtp_heads
+    from repro.serving import init_mtp_heads
     cfg, params, prompt = dense_setup
     eng = DecodeEngine(cfg, params, batch=1, max_len=256)
     ar = np.asarray(eng.greedy_generate(prompt, 20)[0])
     heads = init_mtp_heads(jax.random.PRNGKey(5), cfg.d_model,
                            cfg.vocab_size, n_heads=4)
-    eng2 = DecodeEngine(cfg, params, batch=1, max_len=256)
-    dec = MTPDecoder(eng2, heads)
-    assert dec._n() <= max(1, eng2.nfp_budget() - 1)   # budget respected
-    toks, stats = dec.generate(prompt, 20)
+    toks, loop = _solo(cfg, params, prompt, 20, mode="mtp", mtp_heads=heads)
+    assert all(e["positions"] <= e["budget"]             # budget respected
+               for e in loop.step_log)
     assert np.array_equal(ar, toks[:20])
-    assert stats["tokens_per_forward"] >= 1.0
+    assert loop.stats()["tokens_per_forward"] >= 1.0
 
 
 def test_mtp_loss_trains_heads():

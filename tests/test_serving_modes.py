@@ -10,8 +10,9 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import init_model
-from repro.serving import (DecodeEngine, DiffusionBlockDecoder, MTPDecoder,
-                           PagedKVConfig, ServingLoop, init_mtp_heads)
+from repro.models.transformer import init_cache
+from repro.serving import (DecodeEngine, DiffusionSlotAdapter, PagedKVConfig,
+                           ServingLoop, init_mtp_heads)
 from repro.serving.diffusion import refine_block, token_choice
 from repro.serving.engine import _prefill_fn
 
@@ -39,14 +40,41 @@ def moe_setup():
     return cfg, params, prompts
 
 
-def _cache_kv(engine, length):
+def _cache_kv(cache, length):
     """Every attention-segment cache leaf, truncated to ``length``."""
     out = []
-    for seg in engine.cache["segments"]:
+    for seg in cache["segments"]:
         for key in sorted(seg):
             out.append(np.asarray(seg[key][:, :, :length]
                                   .astype(jnp.float32)))
     return out
+
+
+def _prefilled(cfg, params, stream, max_len=96):
+    """Dense one-row cache holding the KV of prefilling ``stream``."""
+    _, cache, _ = _prefill_fn(params, cfg, jnp.asarray(stream[None],
+                                                       jnp.int32),
+                              init_cache(cfg, 1, max_len), None)
+    return cache
+
+
+def _solo(cfg, params, prompt, tokens, max_len=96, use_kernel=False,
+          **loop_kw):
+    """``prompt`` served alone through a loop with one slot.  Returns the
+    stream, the engine, and the slot's committed length when it was
+    released."""
+    eng = DecodeEngine(cfg, params, batch=1, max_len=max_len,
+                       use_kernel=use_kernel)
+    released = {}
+    release = eng.release_slot
+
+    def spy(slot):
+        released[slot] = int(eng.slot_lens_host[slot])
+        release(slot)
+    eng.release_slot = spy
+    loop = ServingLoop(eng, **loop_kw)
+    loop.submit(prompt, tokens)
+    return loop.run()[0], eng, released.get(0)
 
 
 # ===========================================================================
@@ -54,48 +82,50 @@ def _cache_kv(engine, length):
 # ===========================================================================
 
 def test_diffusion_committed_kv_matches_prefill(dense_setup):
-    """After a diffusion generation, the engine cache must be
-    byte-identical to PREFILLING the resolved stream — the final
+    """After a one-slot diffusion generation, the slot's committed cache
+    must be byte-identical to PREFILLING the resolved stream — the final
     refinement iteration's cache saw mask-token inputs and must never
     have been committed."""
     cfg, params, prompts = dense_setup
-    prompt = jnp.asarray(prompts[2])[None]
-    eng = DecodeEngine(cfg, params, batch=1, max_len=96)
-    dec = DiffusionBlockDecoder(eng, block_size=5, refine_steps=2)
-    toks, _ = dec.generate(prompt, TOKENS)
-    stream = np.concatenate([np.asarray(prompt[0]), toks[:-1]])
-    assert int(eng.cache_len) == len(stream)
-    ref = DecodeEngine(cfg, params, batch=1, max_len=96)
-    ref.prefill(jnp.asarray(stream[None], jnp.int32))
-    for got, want in zip(_cache_kv(eng, len(stream)),
-                         _cache_kv(ref, len(stream))):
+    toks, eng, committed = _solo(cfg, params, prompts[2], TOKENS,
+                                 mode="diffusion", block_size=5,
+                                 refine_steps=2)
+    stream = np.concatenate([prompts[2], toks[:-1]])
+    assert committed == len(stream)
+    for got, want in zip(_cache_kv(eng.cache, len(stream)),
+                         _cache_kv(_prefilled(cfg, params, stream),
+                                   len(stream))):
         assert np.array_equal(got, want)
 
 
-class _PoisonedCommit(DiffusionBlockDecoder):
-    """The pre-fix resolve: commits the LAST REFINEMENT forward's cache,
-    in which positions resolved during/after the final iteration were
-    still mask_id inputs."""
+class _PoisonedCommit(DiffusionSlotAdapter):
+    """The pre-fix commit, for one slot: the LAST REFINEMENT forward's
+    cache, in which positions resolved during/after the final iteration
+    were still mask_id inputs."""
 
-    def resolve(self, pending, drafts):
-        n = len(drafts)
-        block = np.asarray(drafts, np.int64).copy()
+    def run_step(self, slots, width, budget):
+        loop, eng = self.loop, self.loop.engine
+        (s,) = slots
+        req = loop.active[s]
+        n = max(1, min(width, req.max_tokens - len(req.generated)))
+        block = np.full((n,), self._mask_id(), np.int64)
         resolved = np.zeros((n,), bool)
         per_iter = max(1, int(np.ceil(n / self.refine_steps)))
-        step_logits, new_cache = None, None
+        tokens = np.zeros((eng.batch, n + 1), np.int64)
         for _ in range(self.refine_steps):
             if resolved.all():
                 break
-            step_logits, new_cache, _ = self.forward_block(
-                np.concatenate([[pending], block]))
-            preds, conf = (np.asarray(a)
-                           for a in token_choice(step_logits[0]))
-            refine_block(block, resolved, preds, conf, per_iter)
-        if not resolved.all():
-            block[~resolved] = np.asarray(
-                jnp.argmax(step_logits[0], axis=-1))[:n][~resolved]
-        self.engine.commit(new_cache, n)
-        return list(block[:-1]), int(block[-1])
+            tokens[s, 0] = req.pending
+            tokens[s, 1:] = block
+            logits, new_cache, _ = loop.shared_forward(tokens, budget)
+            preds, conf = self.readback(*token_choice(logits))
+            refine_block(block, resolved, preds[s], conf[s], per_iter)
+        block[~resolved] = preds[s, :n][~resolved]
+        req.generated.extend(int(t) for t in block)
+        req.pending = int(block[-1])
+        advances = np.zeros((eng.batch,), np.int32)
+        advances[s] = n
+        eng.commit_slots(new_cache, advances)
 
 
 def test_diffusion_kv_regression_has_teeth(dense_setup):
@@ -103,34 +133,27 @@ def test_diffusion_kv_regression_has_teeth(dense_setup):
     forward that still saw MASK inputs) must FAIL the byte comparison —
     i.e. the regression test above genuinely catches the bug."""
     cfg, params, prompts = dense_setup
-    prompt = jnp.asarray(prompts[2])[None]
-    eng = DecodeEngine(cfg, params, batch=1, max_len=96)
-    dec = _PoisonedCommit(eng, block_size=5, refine_steps=2)
-    toks, _ = dec.generate(prompt, TOKENS)
-    stream = np.concatenate([np.asarray(prompt[0]), toks[:-1]])
-    ref = DecodeEngine(cfg, params, batch=1, max_len=96)
-    ref.prefill(jnp.asarray(stream[None], jnp.int32))
+    toks, eng, _ = _solo(cfg, params, prompts[2], TOKENS, adapter=
+                         _PoisonedCommit(None, block_size=5, refine_steps=2))
+    stream = np.concatenate([prompts[2], toks[:-1]])
     assert any(not np.array_equal(got, want)
-               for got, want in zip(_cache_kv(eng, len(stream)),
-                                    _cache_kv(ref, len(stream))))
+               for got, want in zip(_cache_kv(eng.cache, len(stream)),
+                                    _cache_kv(_prefilled(cfg, params, stream),
+                                              len(stream))))
 
 
 # ===========================================================================
-# Golden byte-equivalence: batched scheduler modes vs solo drivers
+# Golden byte-equivalence: batched scheduler modes vs one-slot runs
 # ===========================================================================
 
 def test_serving_diffusion_matches_solo(dense_setup):
     """ServingLoop(mode='diffusion') over a mixed-length batch: every
-    request's token stream is byte-identical to the solo
-    DiffusionBlockDecoder at the same block size, including through a
-    queue deeper than the slot pool."""
+    request's token stream is byte-identical to the same prompt alone in
+    a one-slot loop at the same block size, including through a queue
+    deeper than the slot pool."""
     cfg, params, prompts = dense_setup
-    solo = []
-    for p in prompts:
-        eng = DecodeEngine(cfg, params, batch=1, max_len=96)
-        dec = DiffusionBlockDecoder(eng, block_size=4, refine_steps=2)
-        toks, _ = dec.generate(jnp.asarray(p)[None], TOKENS)
-        solo.append(np.asarray(toks))
+    solo = [_solo(cfg, params, p, TOKENS, mode="diffusion", block_size=4,
+                  refine_steps=2)[0] for p in prompts]
     eng = DecodeEngine(cfg, params, batch=3, max_len=96)
     loop = ServingLoop(eng, mode="diffusion", block_size=4, refine_steps=2)
     for p in prompts:
@@ -145,7 +168,7 @@ def test_serving_diffusion_matches_solo(dense_setup):
 
 def test_serving_mtp_matches_solo(dense_setup):
     """ServingLoop(mode='mtp') is lossless: byte-identical to solo AR
-    greedy AND to the solo MTPDecoder (greedy acceptance)."""
+    greedy (greedy acceptance)."""
     cfg, params, prompts = dense_setup
     heads = init_mtp_heads(jax.random.PRNGKey(5), cfg.d_model,
                            cfg.vocab_size, n_heads=4)
@@ -154,10 +177,6 @@ def test_serving_mtp_matches_solo(dense_setup):
         eng = DecodeEngine(cfg, params, batch=1, max_len=96)
         refs.append(np.asarray(
             eng.greedy_generate(jnp.asarray(p)[None], TOKENS)[0]))
-    eng = DecodeEngine(cfg, params, batch=1, max_len=96)
-    solo_mtp, _ = MTPDecoder(eng, heads).generate(
-        jnp.asarray(prompts[0])[None], TOKENS)
-    assert np.array_equal(refs[0], solo_mtp[:TOKENS])
     eng = DecodeEngine(cfg, params, batch=3, max_len=96)
     loop = ServingLoop(eng, mode="mtp", mtp_heads=heads, max_width=5)
     for p in prompts:
@@ -170,18 +189,16 @@ def test_serving_mtp_matches_solo(dense_setup):
 def test_serving_modes_moe_kernel_golden(moe_setup):
     """MoE config through the Pallas ragged decode-attention path
     (use_kernel=True, interpret on CPU): batched diffusion + mtp streams
-    stay byte-identical to their solo drivers."""
+    stay byte-identical to their one-slot and AR greedy references."""
     cfg, params, prompts = moe_setup
     t = 6
     heads = init_mtp_heads(jax.random.PRNGKey(5), cfg.d_model,
                            cfg.vocab_size, n_heads=3)
     solo_diff, refs = [], []
     for p in prompts:
-        eng = DecodeEngine(cfg, params, batch=1, max_len=64,
-                           use_kernel=True)
-        dec = DiffusionBlockDecoder(eng, block_size=3, refine_steps=2)
-        toks, _ = dec.generate(jnp.asarray(p)[None], t)
-        solo_diff.append(np.asarray(toks))
+        solo_diff.append(_solo(cfg, params, p, t, max_len=64,
+                               use_kernel=True, mode="diffusion",
+                               block_size=3, refine_steps=2)[0])
         eng = DecodeEngine(cfg, params, batch=1, max_len=64,
                            use_kernel=True)
         refs.append(np.asarray(
@@ -253,11 +270,11 @@ def test_commit_slots_row_mask_on_device(dense_setup):
     cfg, params, prompts = dense_setup
     eng = DecodeEngine(cfg, params, batch=2, max_len=96)
     eng.prefill_slots({0: prompts[0], 1: prompts[1]})
-    before = _cache_kv(eng, 32)
+    before = _cache_kv(eng.cache, 32)
     toks = jnp.asarray(np.zeros((2, 2), np.int64), jnp.int32)
     _, new_cache, _ = eng.decode_slots(toks)
     eng.commit_slots(new_cache, jnp.asarray([2, 0], jnp.int32))
-    after = _cache_kv(eng, 32)
+    after = _cache_kv(eng.cache, 32)
     lens = np.asarray(eng.slot_lens)
     assert lens[0] == len(prompts[0]) + 2 and lens[1] == len(prompts[1])
     for b, a in zip(before, after):
